@@ -12,6 +12,21 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 
+def with_package_on_pythonpath(env: Dict[str, str]) -> Dict[str, str]:
+    """``env`` with the directory holding this ``dlrover_tpu`` on
+    PYTHONPATH, so every child the agent starts (workers, warm spares,
+    the node-check probe) resolves the package the agent runs."""
+    import dlrover_tpu
+
+    pkg_root = os.path.dirname(os.path.dirname(dlrover_tpu.__file__))
+    pythonpath = env.get("PYTHONPATH", "")
+    if pkg_root not in pythonpath.split(os.pathsep):
+        env["PYTHONPATH"] = (
+            pkg_root + (os.pathsep + pythonpath if pythonpath else "")
+        )
+    return env
+
+
 @dataclass
 class ElasticLaunchConfig:
     min_nodes: int = 1
@@ -63,6 +78,12 @@ class ElasticLaunchConfig:
     # after the persistent compilation cache (agent/warm_spawn.py). Any
     # pool failure falls back to a cold spawn.
     warm_spawn: bool = True
+
+    def base_worker_env(self) -> Dict[str, str]:
+        """Job-static environment of every child that may touch the
+        device: the agent's own (``JAX_COMPILATION_CACHE_DIR``,
+        ``JAX_PLATFORMS``, ... pass through) plus ``worker_env``."""
+        return with_package_on_pythonpath({**os.environ, **self.worker_env})
 
     def auto_configure_params(self) -> None:
         """Fill topology-dependent defaults from the environment

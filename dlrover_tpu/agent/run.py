@@ -240,7 +240,7 @@ def run(config: ElasticLaunchConfig) -> int:
             # would initialize a different backend than a cold spawn
             warm_pool = WarmWorkerPool(
                 size=config.nproc_per_node,
-                base_env={**os.environ, **config.worker_env},
+                base_env=config.base_worker_env(),
             )
             warm_pool.prewarm()
         wait_pre_check(client)

@@ -15,7 +15,6 @@ membership change is a process restart — made cheap by the persistent JAX
 compilation cache, SURVEY.md §7 hard-part (b)).
 """
 
-import os
 import signal
 import subprocess
 import sys
@@ -208,7 +207,7 @@ class ElasticTrainingAgent:
 
             self._warm_pool = WarmWorkerPool(
                 size=config.nproc_per_node,
-                base_env=self._base_worker_env(),
+                base_env=config.base_worker_env(),
             )
         self._last_global_step = 0
         self._last_step_ts = 0.0
@@ -329,27 +328,11 @@ class ElasticTrainingAgent:
             )
         return coordinator, base_rank, world_size
 
-    def _base_worker_env(self) -> Dict[str, str]:
-        """Job-static worker environment (also what warm spares inherit —
-        per-incarnation keys are merged at release, ``warm_spawn.py``)."""
-        env = dict(os.environ)
-        # make sure workers resolve the same dlrover_tpu the agent runs
-        import dlrover_tpu
-
-        pkg_root = os.path.dirname(os.path.dirname(dlrover_tpu.__file__))
-        pythonpath = env.get("PYTHONPATH", "")
-        if pkg_root not in pythonpath.split(os.pathsep):
-            env["PYTHONPATH"] = (
-                pkg_root + (os.pathsep + pythonpath if pythonpath else "")
-            )
-        env.update(self._config.worker_env)
-        return env
-
     def _worker_env(
         self, local_rank: int, global_rank: int, world_size: int,
         coordinator: str,
     ) -> Dict[str, str]:
-        env = self._base_worker_env()
+        env = self._config.base_worker_env()
         env.update({
             EnvKey.JOB_NAME: self._config.job_name,
             EnvKey.MASTER_ADDR: self._client.master_addr,

@@ -37,6 +37,7 @@ import time
 import uuid
 from typing import Dict, List, Optional, Sequence
 
+from dlrover_tpu.agent.config import with_package_on_pythonpath
 from dlrover_tpu.common.log import logger
 
 # what a warm spare imports before parking on stdin. jax pulls numpy; the
@@ -85,17 +86,10 @@ class WarmWorkerPool:
     def __init__(self, size: int, base_env: Optional[Dict[str, str]] = None,
                  preimports: Optional[str] = None):
         self._size = max(1, size)
-        self._base_env = dict(base_env if base_env is not None else os.environ)
-        # spares must resolve the same dlrover_tpu the agent runs (the
-        # training agent's _base_worker_env does this for workers)
-        import dlrover_tpu
-
-        pkg_root = os.path.dirname(os.path.dirname(dlrover_tpu.__file__))
-        pythonpath = self._base_env.get("PYTHONPATH", "")
-        if pkg_root not in pythonpath.split(os.pathsep):
-            self._base_env["PYTHONPATH"] = (
-                pkg_root + (os.pathsep + pythonpath if pythonpath else "")
-            )
+        # spares must resolve the same dlrover_tpu the agent runs
+        self._base_env = with_package_on_pythonpath(
+            dict(base_env if base_env is not None else os.environ)
+        )
         self._preimports = (
             preimports
             if preimports is not None

@@ -295,6 +295,9 @@ class ConfigKey:
     WARM_WAIT_S = "DLROVER_TPU_WARM_WAIT_S"
     WARM_PREIMPORT = "DLROVER_TPU_WARM_PREIMPORT"
     COMPILE_CACHE = "DLROVER_TPU_COMPILE_CACHE"
+    # jax's own variable, named here only so worker.py reads it through
+    # the registry: where it is set, nothing in this package overrides it
+    JAX_COMPILATION_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
     DIST_SHUTDOWN_S = "DLROVER_TPU_DIST_SHUTDOWN_S"
     DIST_HEARTBEAT_S = "DLROVER_TPU_DIST_HEARTBEAT_S"
     TRACE_FUNCS = "DLROVER_TPU_TRACE_FUNCS"

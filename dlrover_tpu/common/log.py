@@ -26,3 +26,15 @@ def _build_logger() -> logging.Logger:
 
 default_logger = _build_logger()
 logger = default_logger
+
+
+_logged_once = set()
+
+
+def log_once(msg: str, *args) -> None:
+    """INFO-log a (message, args) pair the first time this process sees
+    it — for choices made at trace time (kernel vs fallback), which would
+    otherwise repeat on every retrace."""
+    if (msg, args) not in _logged_once:
+        _logged_once.add((msg, args))
+        logger.info(msg, *args)

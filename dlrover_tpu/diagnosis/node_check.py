@@ -6,10 +6,13 @@ collective benchmark each node runs under the node-check rendezvous.
 
 TPU translation (SURVEY.md §7 stage 5): the compute probe is a bf16 matmul
 on the local chip(s) — it catches a wedged PJRT runtime or a bad chip by
-timing MXU work; the network probe is a **host-to-host TCP transfer over
-DCN** between pair-group members. DCN (not ICI) is deliberate: when a bad
-chip wedges a slice's ICI, per-host DCN checks still localize the fault
-(SURVEY.md §7 hard-part (d)). Fault injection via the
+timing MXU work. A chip belongs to one process at a time, so the probe
+runs in a short-lived child (``python -m dlrover_tpu.diagnosis.node_check``)
+that has exited, and given the chip back, before the agent forks its first
+worker; the agent itself never initializes a backend. The network probe is
+a **host-to-host TCP transfer over DCN** between pair-group members. DCN
+(not ICI) is deliberate: when a bad chip wedges a slice's ICI, per-host DCN
+checks still localize the fault (SURVEY.md §7 hard-part (d)). Fault injection via the
 ``DLROVER_TPU_MOCK_ERR_RANK`` env var mirrors the reference's
 ``MOCK_ERR_RANK``.
 """
@@ -17,8 +20,10 @@ chip wedges a slice's ICI, per-host DCN checks still localize the fault
 import os
 import socket
 import struct
+import subprocess
+import sys
 import time
-from typing import Dict, List
+from typing import Dict, Optional
 
 from dlrover_tpu.common.comm import NodeMeta
 from dlrover_tpu.common.constants import (
@@ -37,11 +42,22 @@ def mock_error(node_rank: int) -> None:
         raise RuntimeError(f"mock error on node {node_rank}")
 
 
+# a cold TPU backend start plus one small compile is ~20-30 s; a wedged
+# runtime hangs in backend init forever — this bounds how long the agent
+# waits before reporting the node faulty. The partners wait for the
+# verdict longer than this (node_check_agent._VERDICT_WAIT_S), so the
+# fault is on the books before they stop listening.
+DEVICE_CHECK_TIMEOUT_S = 90.0
+_RESULT_TAG = "MATMUL_SECONDS"
+
+
 def matmul_benchmark(size: int = 1024, rounds: int = 4) -> float:
     """Time bf16 matmuls on the local device(s); returns seconds.
 
     Large square bf16 matmuls tile perfectly onto the MXU, so an anomalous
     time means a sick chip/runtime rather than a bad workload fit.
+    Initializes a JAX backend in the CALLING process — the agent reaches
+    it only through :func:`matmul_benchmark_in_child`.
     """
     import jax
     import jax.numpy as jnp
@@ -61,6 +77,27 @@ def matmul_benchmark(size: int = 1024, rounds: int = 4) -> float:
         x = _mm(x)
     x.block_until_ready()
     return time.monotonic() - start
+
+
+def matmul_benchmark_in_child(
+    size: int = 1024, env: Optional[Dict[str, str]] = None,
+) -> float:
+    """:func:`matmul_benchmark` in a child process that owns the chip
+    only for as long as the probe runs. ``env`` is the worker
+    environment (the probe must land on the backend the workers will)."""
+    proc = subprocess.run(  # noqa: S603
+        [sys.executable, "-m", "dlrover_tpu.diagnosis.node_check",
+         str(size)],
+        env=env, capture_output=True, text=True,
+        timeout=DEVICE_CHECK_TIMEOUT_S,
+    )
+    for line in proc.stdout.splitlines():
+        if line.startswith(_RESULT_TAG):
+            return float(line.split()[1])
+    raise RuntimeError(
+        f"device check child failed (rc={proc.returncode}): "
+        f"{proc.stderr[-2000:]}"
+    )
 
 
 _LEN = struct.Struct(">Q")
@@ -180,11 +217,13 @@ def run_check_workload(
     matmul_size: int = 1024,
     payload_mb: float = 4.0,
     partner_failed=None,
+    env: Optional[Dict[str, str]] = None,
 ) -> float:
-    """The full per-node check: fault injection hook → matmul → pair DCN
-    echo. Returns total elapsed seconds; raises on failure."""
+    """The full per-node check: fault injection hook → matmul (in a
+    child, with the workers' ``env``) → pair DCN echo. Returns total
+    elapsed seconds; raises on failure."""
     mock_error(node_rank)
-    mm = matmul_benchmark(size=matmul_size)
+    mm = matmul_benchmark_in_child(size=matmul_size, env=env)
     net = tcp_pair_benchmark(
         node_rank, group, payload_mb=payload_mb,
         partner_failed=partner_failed,
@@ -194,3 +233,7 @@ def run_check_workload(
         node_rank, mm, net, sorted(group),
     )
     return mm + net
+
+
+if __name__ == "__main__":
+    print(_RESULT_TAG, matmul_benchmark(size=int(sys.argv[1])), flush=True)

@@ -19,7 +19,14 @@ from dlrover_tpu.common.constants import (
     RendezvousName,
 )
 from dlrover_tpu.common.log import logger
-from dlrover_tpu.diagnosis.node_check import run_check_workload
+from dlrover_tpu.diagnosis.node_check import (
+    DEVICE_CHECK_TIMEOUT_S,
+    run_check_workload,
+)
+
+# a node whose device-check child hangs reports the fault only when that
+# child's time is up: its partners must still be waiting for the verdict
+_VERDICT_WAIT_S = DEVICE_CHECK_TIMEOUT_S + 30.0
 
 
 def _one_check_round(
@@ -59,6 +66,7 @@ def _one_check_round(
             config.node_rank, group,
             matmul_size=matmul_size, payload_mb=payload_mb,
             partner_failed=partner_failed,
+            env=config.base_worker_env(),
         )
         client.report_network_check(normal=True, elapsed=elapsed)
     except Exception as e:  # noqa: BLE001 — a failed check is a data point
@@ -70,7 +78,7 @@ def _one_check_round(
 
 
 def _wait_verdict(
-    client: MasterClient, timeout_s: float = 120.0
+    client: MasterClient, timeout_s: float = _VERDICT_WAIT_S
 ) -> Tuple[list, str]:
     deadline = time.monotonic() + timeout_s
     while time.monotonic() < deadline:

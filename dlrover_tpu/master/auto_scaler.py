@@ -67,7 +67,7 @@ class JobAutoScaler:
         # multiplicative plan every tick until fresh telemetry lands;
         # without a cooldown execute() would compound 0.5^ticks
         self.paral_cooldown_s = 300.0
-        self._last_paral_apply = 0.0
+        self._last_paral_apply = float("-inf")  # the first plan always applies
         # serving plane (serving/autoscaler.py): a traffic-driven optimizer
         # rides the same tick — signals provider feeds it, plans execute
         # through the serve scaler (replica processes/pods, NOT the

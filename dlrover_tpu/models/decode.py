@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.common.constants import ConfigKey, env_str
+from dlrover_tpu.common.log import log_once
 from dlrover_tpu.models.llama import _mlp, _rms_norm, _rope
 
 # K-block size of the fused decode kernel; caches sized in multiples of
@@ -78,6 +79,11 @@ def flash_decode_wanted(T: int, quantized: bool,
     if env in ("0", "off"):
         return False
     if T % _DECODE_BLOCK_K != 0 or jax.default_backend() != "tpu":
+        log_once(
+            "decode attention: XLA einsum path, fused kernel not eligible "
+            "(cache length %s %% %s = %s, default backend %r)",
+            T, _DECODE_BLOCK_K, T % _DECODE_BLOCK_K, jax.default_backend(),
+        )
         return False
     if env == "1":
         return True
@@ -271,6 +277,13 @@ def prefill(params: Dict, tokens, config,
         (jax.default_backend() == "tpu" if uf is None else uf)
         and P >= 256
     )
+    if not use_flash:
+        log_once(
+            "prefill attention: dense XLA path (use_flash_attention=%s, "
+            "default backend %r, prompt length %s; the kernel needs tpu "
+            "or an explicit True, and >= 256 tokens)",
+            uf, jax.default_backend(), P,
+        )
 
     def layer_fn(h, layer):
         xn = _rms_norm(h, layer["attn_norm"], c.norm_eps)

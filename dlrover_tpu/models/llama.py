@@ -27,6 +27,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from dlrover_tpu.common.log import log_once
 from dlrover_tpu.ops.flash_attention import flash_attention
 from dlrover_tpu.parallel.ring_attention import (
     full_causal_attention,
@@ -217,6 +218,11 @@ def _attention(x, layer, config: LlamaConfig, positions, mesh):
     use_flash = c.use_flash_attention
     if use_flash is None:
         use_flash = jax.default_backend() == "tpu"
+        if not use_flash:
+            log_once(
+                "attention: dense XLA path (use_flash_attention=None and "
+                "default backend is %r, not tpu)", jax.default_backend(),
+            )
     use_sp = (
         strategy is not None and mesh is not None
         and mesh.shape.get("sp", 1) > 1
@@ -244,6 +250,12 @@ def _attention(x, layer, config: LlamaConfig, positions, mesh):
     elif use_flash and _flash_shardable(mesh, B, c.n_heads):
         out = sharded_flash_attention(q, k, v, mesh)
     else:
+        if use_flash:
+            log_once(
+                "attention: flash kernel wanted but mesh %s does not divide "
+                "batch=%s heads=%s — dense XLA path",
+                str(dict(mesh.shape)), B, c.n_heads,
+            )
         out = full_causal_attention(q, k, v)
     out = out.transpose(0, 2, 1, 3).reshape(B, S, c.n_heads * c.head_dim)
     return jnp.einsum("bsh,hd->bsd", out, layer["wo"])

@@ -35,45 +35,33 @@ On non-TPU backends (CPU tests) the kernels run in pallas interpret mode.
 """
 
 import functools
-import logging
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from dlrover_tpu.common.constants import ConfigKey, env_int
-
-try:  # TPU memory spaces; absent on CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except (ImportError, AttributeError):  # pragma: no cover
-    logging.getLogger(__name__).debug(
-        "pallas TPU memory spaces unavailable; using default block specs",
-        exc_info=True,
-    )
-    pltpu = None
-    _VMEM = None
+from dlrover_tpu.common.log import log_once
 
 NEG_INF = float(-1e30)  # avoid -inf arithmetic inside the kernel
 LANES = 128  # lane width for replicated row statistics
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend != "tpu":
+        log_once(
+            "pallas kernels run in INTERPRET mode: default backend is %r, "
+            "not tpu", backend,
+        )
+        return True
+    return False
 
 
 def _vmem_spec(block_shape, index_map):
-    if _VMEM is not None:
-        return pl.BlockSpec(block_shape, index_map, memory_space=_VMEM)
-    return pl.BlockSpec(block_shape, index_map)  # pragma: no cover
-
-
-def _vmem_scratch(shape, dtype):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, dtype)
-    return pl.MemoryRef(shape, dtype)  # pragma: no cover
+    return pl.BlockSpec(block_shape, index_map, memory_space=pltpu.VMEM)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -195,9 +183,9 @@ def _fwd(
             jax.ShapeDtypeStruct((B, H, nq * bq, LANES), jnp.float32),
         ],
         scratch_shapes=[
-            _vmem_scratch((bq, LANES), jnp.float32),
-            _vmem_scratch((bq, LANES), jnp.float32),
-            _vmem_scratch((bq, D), jnp.float32),
+            pltpu.VMEM((bq, LANES), jnp.float32),
+            pltpu.VMEM((bq, LANES), jnp.float32),
+            pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=interpret,
     )(qp, kp, vp)
@@ -379,7 +367,7 @@ def _bwd(
         ],
         out_specs=_vmem_spec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, nq * bq, D), q.dtype),
-        scratch_shapes=[_vmem_scratch((bq, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
     )(qp, kp, vp, dop, lsep, deltap)
 
@@ -406,8 +394,8 @@ def _bwd(
             jax.ShapeDtypeStruct((B, H, nk * bk, D), v.dtype),
         ],
         scratch_shapes=[
-            _vmem_scratch((bk, D), jnp.float32),
-            _vmem_scratch((bk, D), jnp.float32),
+            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((bk, D), jnp.float32),
         ],
         interpret=interpret,
     )(qp, kp, vp, dop, lsep, deltap)
@@ -658,8 +646,6 @@ def flash_decode_attention(
     def _clamped2(i, j, pos_ref):
         return (i, jnp.minimum(j, pos_ref[0] // bk))
 
-    if pltpu is None:  # pragma: no cover — CPU build without pallas TPU
-        raise NotImplementedError("flash_decode_attention needs pallas TPU")
     in_specs = [
         _vmem_spec((g_blk, rows, Dh), lambda i, j, p: (i, 0, 0)),
         _vmem_spec((g_blk, bk, Dh), _clamped),
@@ -683,9 +669,9 @@ def flash_decode_attention(
             _vmem_spec((g_blk, rows, Dh), lambda i, j, p: (i, 0, 0)),
         ],
         scratch_shapes=[
-            _vmem_scratch((g_blk * rows, LANES), jnp.float32),
-            _vmem_scratch((g_blk * rows, LANES), jnp.float32),
-            _vmem_scratch((g_blk * rows, Dh), jnp.float32),
+            pltpu.VMEM((g_blk * rows, LANES), jnp.float32),
+            pltpu.VMEM((g_blk * rows, LANES), jnp.float32),
+            pltpu.VMEM((g_blk * rows, Dh), jnp.float32),
         ],
     )
     out_dtype = q.dtype

@@ -29,11 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from dlrover_tpu.common import jax_compat
-
-jax_compat.install()  # jax.shard_map alias on older 0.4.x wheels
-
-
 
 def stack_stages(tree: Any, n_stages: int) -> Any:
     """Reshape depth-stacked per-layer params ``(L, ...)`` into pipeline

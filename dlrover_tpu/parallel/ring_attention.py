@@ -26,14 +26,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dlrover_tpu.parallel.sharding import clamp_spec
-
-from dlrover_tpu.common import jax_compat
-
-jax_compat.install()  # jax.shard_map alias on older 0.4.x wheels
-
-
+from dlrover_tpu.common.log import log_once
 from dlrover_tpu.ops.flash_attention import flash_attention
+from dlrover_tpu.parallel.sharding import clamp_spec
 
 
 def _block_attend(q, k, v, mask, m, l, o, scale):
@@ -193,6 +188,11 @@ def ring_attention(
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
+        if not use_pallas:
+            log_once(
+                "ring attention: dense inner block, not the flash kernel "
+                "(default backend is %r, not tpu)", jax.default_backend(),
+            )
     if use_pallas:
         fn = functools.partial(
             _ring_flash_local, axis_name=sp_axis, scale=scale,

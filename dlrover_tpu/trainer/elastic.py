@@ -17,6 +17,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from dlrover_tpu.common.constants import MetricLabel
 from dlrover_tpu.common.log import logger
@@ -31,11 +32,44 @@ class TrainStepResult(NamedTuple):  # NamedTuple ⇒ a pytree, jit can return it
 
 
 def make_train_state(params, optimizer) -> Dict:
-    return {
+    opt_state = optimizer.init(params)
+    # the trainer hands the optimizer f32 grads, which promote moments
+    # made from bf16 params to f32 in the first update, for good. Start
+    # them where that update leaves them, so the state's dtypes are a
+    # fixed point of the step — else step 2 retraces, step 1's donation
+    # cannot alias, and a checkpoint written after step 1 does not match
+    # this function's restore target. Same numbers: bf16 to f32 is exact.
+    grads = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, jnp.float32), params
+    )
+    _, settled = jax.eval_shape(optimizer.update, grads, opt_state, params)
+    opt_state = jax.tree.map(
+        lambda x, to: x if x.dtype == to.dtype else x.astype(to.dtype),
+        opt_state, settled,
+    )
+    state = {
         "params": params,
-        "opt_state": optimizer.init(params),
+        "opt_state": opt_state,
         "step": jnp.zeros((), dtype=jnp.int32),
     }
+    # counters made from nothing land on the default device, but the step
+    # returns them replicated over the params' mesh: put them there now,
+    # so the state's layout is a fixed point of the step (else step 2
+    # recompiles for the new input shardings, and a restored state is not
+    # laid out like the saved one)
+    mesh = next(
+        (leaf.sharding.mesh for leaf in jax.tree.leaves(params)
+         if isinstance(getattr(leaf, "sharding", None), NamedSharding)),
+        None,
+    )
+    if mesh is None:
+        return state
+    replicated = NamedSharding(mesh, PartitionSpec())
+    return jax.tree.map(
+        lambda x: x if isinstance(x.sharding, NamedSharding)
+        else jax.device_put(x, replicated),
+        state,
+    )
 
 
 class ElasticTrainer:

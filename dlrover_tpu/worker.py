@@ -13,8 +13,7 @@ from dataclasses import dataclass
 from typing import Optional
 
 from dlrover_tpu.agent.master_client import MasterClient
-from dlrover_tpu.common import jax_compat
-from dlrover_tpu.common.constants import EnvKey
+from dlrover_tpu.common.constants import ConfigKey, EnvKey, env_str
 from dlrover_tpu.common.log import logger
 
 
@@ -131,30 +130,43 @@ class WorkerContext:
         return per_device_stats()
 
 
-def _enable_compilation_cache() -> None:
-    """Point XLA's persistent compilation cache at a per-host directory.
+def default_compile_cache_dir() -> str:
+    """The one fixed cache directory, inside the checkout. The path is
+    part of the cache key, so it must not move between incarnations:
+    never ``~``, a temp name, a pid or a time."""
+    return os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".xla_cache",
+    )
+
+
+def enable_compilation_cache() -> None:
+    """Turn on XLA's persistent compilation cache.
 
     Elastic restarts re-spawn worker processes, and under jit the first
     step would otherwise pay full recompilation (tens of seconds for a
     real model) every restart — the dominant term in restart-to-training
     time on TPU, where the reference's torch workers pay nothing. With the
     cache, a restarted worker (same world shape) deserializes the
-    executable instead (SURVEY.md §7 hard part b). Opt out with
-    DLROVER_TPU_COMPILE_CACHE=off; the directory survives process death by
-    design — it must live OUTSIDE any per-run tmpdir.
-    """
-    cache = os.getenv("DLROVER_TPU_COMPILE_CACHE", "")
-    if cache.lower() in ("off", "0", "disable"):
-        return
-    if not cache:
-        cache = os.path.join(
-            os.path.expanduser("~/.cache"), "dlrover_tpu", "xla_cache"
-        )
-    try:
-        os.makedirs(cache, exist_ok=True)
-        import jax
+    executable instead (SURVEY.md §7 hard part b).
 
-        jax.config.update("jax_compilation_cache_dir", cache)
+    Placement is the caller's: where ``JAX_COMPILATION_CACHE_DIR`` is set
+    jax already reads it and nothing here overrides it (the agent's
+    workers and warm spares inherit the variable); unset, the cache lives
+    in :func:`default_compile_cache_dir`. DLROVER_TPU_COMPILE_CACHE=off
+    disables it.
+    """
+    import jax
+
+    if env_str(ConfigKey.COMPILE_CACHE).lower() in ("off", "0", "disable"):
+        jax.config.update("jax_enable_compilation_cache", False)
+        return
+    cache = env_str(ConfigKey.JAX_COMPILATION_CACHE_DIR)
+    try:
+        if not cache:
+            cache = default_compile_cache_dir()
+            os.makedirs(cache, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", cache)
         # cache everything that took meaningful XLA time (the threshold is
         # against compile time proper, not trace+lower wall time — keep it
         # low or real train steps get filtered); tiny probe computations
@@ -164,11 +176,6 @@ def _enable_compilation_cache() -> None:
         logger.info("XLA compilation cache at %s", cache)
     except Exception as e:  # noqa: BLE001 — cache is an optimization only
         logger.warning("compilation cache unavailable: %r", e)
-
-
-# public alias: bench.py warms the same cache so driver runs don't pay
-# cold compiles against their wall-clock budget
-enable_compilation_cache = _enable_compilation_cache
 
 
 def init(initialize_jax_distributed: bool = True) -> WorkerContext:
@@ -181,11 +188,12 @@ def init(initialize_jax_distributed: bool = True) -> WorkerContext:
     """
     rank = int(os.getenv(EnvKey.RANK, "0"))
     world_size = int(os.getenv(EnvKey.WORLD_SIZE, "1"))
-    _enable_compilation_cache()
-    jax_compat.install()
+    enable_compilation_cache()
     coordinator = os.getenv(EnvKey.COORDINATOR_ADDR, "")
     if initialize_jax_distributed and world_size > 1 and coordinator:
-        jax_compat.distributed_initialize(
+        import jax
+
+        jax.distributed.initialize(
             coordinator_address=coordinator,
             num_processes=world_size,
             process_id=rank,

@@ -1,0 +1,152 @@
+"""The main path's kernels and train step compiled for a described v5e.
+
+No chip is attached here: the TPU compiler that is installed compiles for
+a ``v5e:2x2`` topology that is only described (on-chip-measurement guide,
+section 2, rehearsal 3). It refuses what the chip's compiler would refuse
+— unaligned slices, too much VMEM, a program that does not fit — which
+interpret mode never shows. Shapes are ``chip_smoke.py``'s: Llama-2-7B
+widths, 32 heads x 128, sequence 2048.
+
+The topology is described inside a module-scoped fixture, in the test's
+own process, never at import: only one process at a time may load libtpu,
+and every xdist worker imports this file.
+"""
+
+import dataclasses
+import importlib
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax.sharding import (
+    NamedSharding,
+    PartitionSpec as P,
+    SingleDeviceSharding,
+)
+
+from dlrover_tpu.models import llama
+from dlrover_tpu.ops.flash_attention import (
+    flash_attention,
+    flash_decode_attention,
+)
+from dlrover_tpu.parallel.mesh import build_mesh, plan_mesh
+from dlrover_tpu.trainer.elastic import ElasticTrainer, make_train_state
+
+B, H, S, D = 1, 32, 2048, 128        # attention, as chip_smoke.py
+BD, T, POS = 8, 2048, 1500           # decode batch, cache length, position
+HBM_BYTES = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a described-chip compile is written to the persistent cache but
+    # cannot be read back without a chip: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        try:
+            desc = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — whatever keeps libtpu away
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_flash_attention_forward(one_chip):
+    qkv = [_shape((B, H, S, D), jnp.bfloat16, one_chip)] * 3
+    text = _compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, interpret=False), *qkv)
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_flash_attention_forward_backward(one_chip):
+    qkv = [_shape((B, H, S, D), jnp.bfloat16, one_chip)] * 3
+    text = _compiled_text(
+        jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, interpret=False).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)),
+        *qkv)
+    # forward, dq, dk/dv
+    assert text.count("tpu_custom_call") == 3
+
+
+@pytest.mark.parametrize("kv_heads,quantized", [
+    pytest.param(32, False, id="bf16"),
+    pytest.param(32, True, id="int8-MHA"),
+    pytest.param(8, True, id="int8-GQA"),
+])
+def test_flash_decode_attention(one_chip, kv_heads, quantized):
+    cache_dtype = jnp.int8 if quantized else jnp.bfloat16
+    q = _shape((BD, kv_heads, H // kv_heads, D), jnp.bfloat16, one_chip)
+    kv = _shape((BD, kv_heads, T, D), cache_dtype, one_chip)
+    scales = [_shape((BD, kv_heads, T), jnp.float32, one_chip)] * 2
+
+    def fn(q, k, v, *s):
+        return flash_decode_attention(
+            q, k, v, POS, interpret=False,
+            k_scale=s[0] if s else None, v_scale=s[1] if s else None)
+
+    text = _compiled_text(fn, q, kv, kv, *(scales if quantized else ()))
+    assert "tpu_custom_call" in text
+
+
+def test_train_step_one_layer_fits_the_chip(topo, monkeypatch):
+    """``ElasticTrainer._build_step`` at 7B widths, depth 1: the flash
+    kernel is in the program forward and backward, and XLA's own memory
+    analysis stays under the chip's 16 GB."""
+    # the step picks interpret mode from the default backend, which is the
+    # CPU here: steer it, as the chip would
+    fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+    cfg = dataclasses.replace(
+        llama.LlamaConfig.llama7b(), n_layers=1, max_seq_len=S,
+        use_flash_attention=True,
+    )
+    plan = plan_mesh(1)
+    mesh = build_mesh(plan, devices=list(topo.devices))
+    on_mesh = NamedSharding(mesh, P())
+    optimizer = optax.adamw(3e-4)
+    trainer = ElasticTrainer(
+        loss_fn=lambda p, t: llama.next_token_loss(p, t, cfg, mesh),
+        optimizer=optimizer, global_batch_size=4, micro_batch_per_replica=2,
+    )
+    trainer.configure_for_world(plan)
+    state = jax.eval_shape(
+        lambda: make_train_state(
+            llama.init_params(cfg, jax.random.PRNGKey(0)), optimizer))
+    state = jax.tree.map(
+        lambda x: _shape(x.shape, x.dtype, on_mesh), state)
+    tokens = _shape((2, 2, S + 1), jnp.int32, on_mesh)
+    lowered = trainer._build_step().lower(state, tokens)
+    text = lowered.as_text()
+    for kernel in ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel"):
+        assert f'"{kernel}"' in text
+    mem = lowered.compile().memory_analysis()
+    # the donated state is aliased to the output: counted once
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.alias_size_in_bytes > 0.99 * mem.argument_size_in_bytes, (
+        "the step must alias its whole donated state")
+    assert peak < HBM_BYTES, f"{peak / 2**30:.1f} GiB"

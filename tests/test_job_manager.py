@@ -178,9 +178,12 @@ def test_stale_heartbeat_before_start_is_not_dead():
 def test_heartbeat_timeout_marks_no_heartbeat():
     jm, scaler = make_manager()
     node = jm.nodes[0]
-    node.start_time = time.monotonic() - 500
-    node.heartbeat_time = time.monotonic() - 400
-    jm.check_heartbeats()
+    # on the injectable clock: on a host up for under 400 s the real
+    # monotonic clock would make these stamps negative, i.e. "never beat"
+    now = 1000.0
+    node.start_time = now - 500
+    node.heartbeat_time = now - 400
+    jm.check_heartbeats(now=now)
     assert node.exit_reason == NodeExitReason.NO_HEARTBEAT
     assert scaler.relaunched == [0]  # budget-consuming relaunch
     assert node.relaunch_count == 1

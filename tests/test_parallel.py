@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax._src import monitoring
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dlrover_tpu.models import llama, mnist
@@ -253,6 +254,70 @@ class TestElasticTrainer:
         ys = (xs.sum(-1) > 0).astype(jnp.int32)
         state, result = trainer.train_step(state, {"x": xs, "y": ys})
         assert bool(jnp.isfinite(result.loss))
+
+    def test_bf16_params_start_with_the_f32_moments_step_one_gives(self):
+        """The trainer's f32 grads promote adamw's moments to f32 in the
+        first update. ``make_train_state`` starts them there: the state is
+        a fixed point of the step (one program, same dtypes and layout out
+        as in) and the numbers are those of optax's own promotion."""
+        config = mnist.MnistConfig(input_dim=8, hidden_dim=16, n_classes=2)
+        plan = plan_mesh(8, tp=2)
+        mesh = build_mesh(plan)
+        params = shard_tree(
+            mesh,
+            jax.tree.map(lambda p: p.astype(jnp.bfloat16),
+                         mnist.init_params(config, jax.random.PRNGKey(0))),
+            mnist.param_logical_axes(config),
+        )
+        optimizer = optax.adamw(1e-2)
+
+        def run(state):
+            trainer = ElasticTrainer(
+                loss_fn=mnist.loss_fn, optimizer=optimizer,
+                global_batch_size=16, micro_batch_per_replica=2,
+            )
+            trainer.configure_for_world(plan)
+            accum, micro = trainer.grad_accum_steps, trainer.micro_batch_global
+            xs = jax.random.normal(jax.random.PRNGKey(1), (accum, micro, 8))
+            batch = {"x": xs, "y": (xs.sum(-1) > 0).astype(jnp.int32)}
+            compiles = []
+
+            def on_compile(event, _seconds, **_):
+                if event == "/jax/core/compile/backend_compile_duration":
+                    compiles.append(event)
+
+            monitoring.register_event_duration_secs_listener(on_compile)
+            try:
+                state, _ = trainer.train_step(state, batch)
+                first = len(compiles)
+                for _ in range(2):
+                    state, _ = trainer.train_step(state, batch)
+            finally:
+                monitoring.unregister_event_duration_listener(on_compile)
+            return state, first, len(compiles) - first
+
+        def same_layout(a, b):
+            return all(
+                x.dtype == y.dtype
+                and x.sharding.is_equivalent_to(y.sharding, x.ndim)
+                for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+
+        state = make_train_state(params, optimizer)
+        adam = state["opt_state"][0]
+        assert {x.dtype for x in jax.tree.leaves((adam.mu, adam.nu))} == {
+            jnp.dtype(jnp.float32)}
+        assert same_layout(
+            adam.mu, jax.tree.map(lambda p: p.astype(jnp.float32), params))
+        settled, first, later = run(jax.tree.map(jnp.copy, state))
+        assert same_layout(settled, state)
+        assert first >= 1 and later == 0, "a step after the first compiled"
+        # the parent's way: bf16 moments, which step 1 promotes for good
+        promoted, _, later = run(
+            {**state, "opt_state": optimizer.init(params)})
+        assert later >= 1  # ... so step 2 compiles a second program
+        for a, b in zip(jax.tree.leaves(settled), jax.tree.leaves(promoted)):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 class TestMultiSlice:
