@@ -1,0 +1,59 @@
+"""Mixtral-class sparse-expert decoders through the program's
+``models/moe.py``: the llama attention block, a top-k router with
+renormalised gates, SwiGLU experts. ``"family": "mixtral_moe"``.
+
+``fields["program"]`` holds what the program needs beyond the published
+keys: ``capacity_factor`` and ``route_group_size`` of its slot layout.
+"""
+
+import jax.numpy as jnp
+
+from dlrover_tpu.models import moe
+
+REHEARSAL_FIELDS = {
+    "hidden_size": 64, "intermediate_size": 96, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 256,
+    "num_local_experts": 4, "num_experts_per_tok": 2,
+    "program": {"capacity_factor": 2.0, "route_group_size": 32},
+    # bf16 at width 64 strays further from float32 than at width 4096
+    "reference_tolerance": {"loss_rel": 2e-3, "grad_norm_rel": 2e-2},
+}
+
+init_params = moe.init_params
+logical_axes = moe.param_logical_axes
+
+
+def program_config(fields: dict, seq: int) -> moe.MoEConfig:
+    if fields["hidden_size"] != (fields["num_attention_heads"]
+                                 * fields["head_dim"]):
+        raise ValueError("models/moe.py ties head_dim to hidden/heads")
+    if fields["torch_dtype"] != "bfloat16" or fields["hidden_act"] != "silu":
+        raise ValueError("this family serves bf16 SwiGLU models only")
+    program = fields["program"]
+    experts, top_k = fields["num_local_experts"], fields["num_experts_per_tok"]
+    if program["capacity_factor"] * top_k < experts:
+        raise ValueError(
+            "capacity_factor below experts / top_k drops tokens: not the "
+            "published mathematics, and not what the reference computes")
+    return moe.MoEConfig(
+        vocab_size=fields["vocab_size"], dim=fields["hidden_size"],
+        n_layers=fields["num_hidden_layers"],
+        n_heads=fields["num_attention_heads"],
+        n_kv_heads=fields["num_key_value_heads"],
+        ffn_dim=fields["intermediate_size"], n_experts=experts, top_k=top_k,
+        capacity_factor=program["capacity_factor"],
+        route_group_size=program["route_group_size"],
+        router_aux_weight=fields["router_aux_loss_coef"], max_seq_len=seq,
+        rope_theta=fields["rope_theta"], norm_eps=fields["rms_norm_eps"],
+        dtype=jnp.bfloat16, remat=True,
+        remat_policy=fields.get("remat_policy"),
+    )
+
+
+def loss_fn(config, mesh):
+    return lambda params, tokens: moe.next_token_loss(
+        params, tokens, config, mesh)
+
+
+def reference_kwargs(fields: dict, seq: int) -> dict:
+    return {"route_group": fields["program"]["route_group_size"]}

@@ -1,0 +1,37 @@
+"""The flash-attention kernels' share of their roofline on chip 0.
+
+Kernel time: summed device time, inside whole step programs, of the
+trace events whose HLO text says ``custom_call_target="tpu_custom_call"``
+-- every Pallas kernel of the step. ``ops/flash_attention.py``'s three
+(forward, backward dq, backward dkv) are the only ones a train step of
+these models holds, and the trace gives them no name of their own
+(``kernel_metadata={}``): PERF.md lists the name the program must give.
+Least time: the FLOPs attention's forward and backward need for the
+steps seen (``harness/flops.py``: 2 + 5 causal score-sized matmuls a
+layer and microbatch, however the kernels split or repeat them) over the
+published bf16 peak. The bound is compute: at sequence 4096 and head
+size 128 a call needs some hundreds of FLOPs for each byte it must move.
+"""
+
+from benchmarks.harness import flops, trace_reduce
+
+PALLAS = 'custom_call_target="tpu_custom_call"'
+
+
+def read(ctx):
+    if not ctx["trace_raw"] or not ctx["peaks"]:
+        return None
+    planes = trace_reduce.device_planes(ctx["trace_raw"])
+    if not planes:
+        return None
+    seconds, _, steps = trace_reduce.kernel_seconds(
+        planes[0], PALLAS, ctx["step_module"])
+    if not seconds:
+        return None
+    job, fields = ctx["job"], ctx["fields"]
+    per_step = (job["grad_accum"] * fields["num_hidden_layers"]
+                * (flops.ATTN_FWD_MATMULS + flops.ATTN_BWD_MATMULS)
+                * flops.attention_matmul_flops(
+                    fields, job["seq"], job["rows_per_replica"]))
+    least = steps * per_step / ctx["peaks"]["bf16_flops_per_s"]
+    return 100.0 * least / seconds
