@@ -27,6 +27,7 @@ import os
 import queue
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -117,6 +118,7 @@ class CheckpointEngine:
             if world_size is not None
             else env_int(EnvKey.WORLD_SIZE, 1)
         )
+        self._source = f"worker_{self.rank}"  # of this engine's spans
         self._shm = SharedMemoryHandler(
             shm_name(self.job_name, self.node_rank, self.local_rank)
         )
@@ -241,7 +243,7 @@ class CheckpointEngine:
                        _on_drained=None, _wait_busy_s: float = 0.0) -> bool:
         """Traced entry point — see :meth:`_save_to_memory`."""
         with tracing.span(
-            SpanName.CKPT_SAVE_MEMORY, source=f"worker_{self.rank}",
+            SpanName.CKPT_SAVE_MEMORY, source=self._source,
             step=step, blocking=blocking,
         ) as sp:
             ok = self._save_to_memory(
@@ -266,31 +268,8 @@ class CheckpointEngine:
         HBM until the drain finishes. ``blocking=True`` restores the
         synchronous reference behavior (used by breakpoint saves where the
         process is about to exit)."""
-        local_ready, acquired, why = True, False, ""
-        if self._drain_thread is not None and self._drain_thread.is_alive():
-            if _wait_busy_s > 0:
-                self.wait_drained(_wait_busy_s)
-            if self._drain_thread.is_alive():
-                local_ready, why = False, "previous snapshot draining"
-        if local_ready and self._save_lock is not None:
-            acquired = self._save_lock.acquire(blocking=False)
-            if not acquired:
-                local_ready, why = False, "agent persisting previous"
-        # all-or-none across ranks: a save only proceeds if EVERY rank is
-        # ready (reference check_all_rank_ready, engine.py:57 — gloo
-        # allgather; here the master KV exchanges the flags). Without this,
-        # ranks whose drains finish at different times persist different
-        # steps and no step directory ever collects all its frames.
-        try:
-            ready = self._all_ranks_ready(
-                step, local_ready, min_wait=_wait_busy_s
-            )
-        except Exception:
-            # never leak the shared lock: the agent's persist path and all
-            # future saves block on it for the process lifetime otherwise
-            if acquired:
-                self._save_lock.release()
-            raise
+        with tracing.span(SpanName.CKPT_SAVE_READY, source=self._source):
+            ready, acquired, why = self._ready_to_save(step, _wait_busy_s)
         if not ready:
             if acquired:
                 self._save_lock.release()
@@ -300,21 +279,29 @@ class CheckpointEngine:
             return False
         block_t0 = time.monotonic()
         try:
-            meta, pending = self._plan_state(step, state)
+            with tracing.span(
+                SpanName.CKPT_SAVE_PLAN, source=self._source,
+            ) as sp:
+                meta, pending = self._plan_state(step, state)
+                nbytes = sum(shard["nbytes"] for shard, _ in pending)
+                sp.attrs.update(leaves=len(meta["leaves"]), bytes=nbytes)
             if self._meta_dict is not None:
                 # register the frame identity BEFORE the async drain: the
                 # agent discovers shm segments through this dict, and a
                 # breakpoint save must be able to find the frame and wait
                 # on its lock even if we die mid-drain (it reads the step
                 # from the shm meta itself, so identity is all it needs)
-                self._meta_dict.set(
-                    f"{self.node_rank}:{self.local_rank}",
-                    {
-                        "shm": self._shm.name,
-                        "ts": time.time(),
-                        "persisted": False,
-                    },
-                )
+                with tracing.span(
+                    SpanName.CKPT_SAVE_REGISTER, source=self._source,
+                ):
+                    self._meta_dict.set(
+                        f"{self.node_rank}:{self.local_rank}",
+                        {
+                            "shm": self._shm.name,
+                            "ts": time.time(),
+                            "persisted": False,
+                        },
+                    )
         except Exception:
             if self._save_lock is not None:
                 self._save_lock.release()
@@ -329,10 +316,11 @@ class CheckpointEngine:
         def _drain():
             try:
                 with tracing.activate(drain_parent), tracing.span(
-                    SpanName.CKPT_DRAIN, source=f"worker_{self.rank}",
-                    step=step,
+                    SpanName.CKPT_DRAIN, source=self._source,
+                    step=step, bytes=nbytes,
                 ):
-                    self._drain_frame(step, meta, pending, _on_drained)
+                    self._drain_frame(step, meta, pending, nbytes,
+                                      _on_drained)
             except Exception:  # noqa: BLE001 — a lost snapshot must be LOUD
                 self._drain_ok = False
                 logger.error(
@@ -356,18 +344,33 @@ class CheckpointEngine:
             self._drain_thread.start()
         return True
 
-    def _drain_frame(self, step, meta, pending, _on_drained) -> None:
+    def _drain_frame(self, step, meta, pending, nbytes,
+                     _on_drained) -> None:
         drain_t0 = time.monotonic()
-        buffers = [np.asarray(data) for _, data in pending]
-        self._shm.write_frame(meta, buffers)
+        with tracing.span(
+            SpanName.CKPT_DRAIN_D2H_WAIT, source=self._source,
+        ):
+            buffers = [np.asarray(data) for _, data in pending]
+        with tracing.span(
+            SpanName.CKPT_DRAIN_SHM_WRITE, source=self._source,
+        ) as sp:
+            sp.attrs.update(self._shm.write_frame(meta, buffers))
         drain_s = time.monotonic() - drain_t0
         self._drain_hist.observe(drain_s)
         if drain_s > 0:
-            self._drain_rate_gauge.set(
-                sum(b.nbytes for b in buffers) / drain_s
-            )
+            self._drain_rate_gauge.set(nbytes / drain_s)
         self._latest_step = step
         self._drain_ok = True
+        with tracing.span(
+            SpanName.CKPT_DRAIN_PUBLISH, source=self._source,
+        ):
+            self._publish_frame(step)
+        if _on_drained is not None:
+            _on_drained()
+
+    def _publish_frame(self, step: int) -> None:
+        """Tell whoever reads this frame that it holds ``step``: the
+        backup-group peers, the agent's saver, the master's KV."""
         if self._replicas is not None:
             # overlaps with training; reference replica.py:116
             # blocks on a gloo allgather here instead
@@ -390,8 +393,38 @@ class CheckpointEngine:
                 )
             except ConnectionError:
                 pass
-        if _on_drained is not None:
-            _on_drained()
+
+    def _ready_to_save(self, step: int,
+                       wait_busy_s: float) -> Tuple[bool, bool, str]:
+        """(every rank ready, this rank holds the save lock, why not):
+        no drain in flight here, the agent not persisting the frame, and
+        every peer of the saver group saying the same."""
+        local_ready, acquired, why = True, False, ""
+        if self._drain_thread is not None and self._drain_thread.is_alive():
+            if wait_busy_s > 0:
+                self.wait_drained(wait_busy_s)
+            if self._drain_thread.is_alive():
+                local_ready, why = False, "previous snapshot draining"
+        if local_ready and self._save_lock is not None:
+            acquired = self._save_lock.acquire(blocking=False)
+            if not acquired:
+                local_ready, why = False, "agent persisting previous"
+        # all-or-none across ranks: a save only proceeds if EVERY rank is
+        # ready (reference check_all_rank_ready, engine.py:57 — gloo
+        # allgather; here the master KV exchanges the flags). Without this,
+        # ranks whose drains finish at different times persist different
+        # steps and no step directory ever collects all its frames.
+        try:
+            ready = self._all_ranks_ready(
+                step, local_ready, min_wait=wait_busy_s
+            )
+        except Exception:
+            # never leak the shared lock: the agent's persist path and all
+            # future saves block on it for the process lifetime otherwise
+            if acquired:
+                self._save_lock.release()
+            raise
+        return ready, acquired, why
 
     def _all_ranks_ready(self, step: int, local_ready: bool,
                          min_wait: float = 0.0) -> bool:
@@ -495,7 +528,7 @@ class CheckpointEngine:
         path = path or self.ckpt_dir
 
         with tracing.span(
-            SpanName.CKPT_PERSIST_REQUEST, source=f"worker_{self.rank}",
+            SpanName.CKPT_PERSIST_REQUEST, source=self._source,
             step=step,
         ):
             # the persist request crosses the SharedQueue into the agent
@@ -763,32 +796,39 @@ class CheckpointEngine:
         Returns (state, step); step == -1 when nothing was restored.
         """
         with tracing.span(
-            SpanName.CKPT_RESTORE, source=f"worker_{self.rank}",
+            SpanName.CKPT_RESTORE, source=self._source,
         ) as sp:
+            rung = functools.partial(tracing.span, source=self._source)
             # an in-flight async snapshot must land before we read the frame
-            self.wait_drained()
+            with rung(SpanName.CKPT_RESTORE_WAIT_DRAINED):
+                self.wait_drained()
             restore_t0 = time.monotonic()
             self._report_event(JournalEvent.RESTORE_START)
-            # degradation ladder, each rung journaled with its reason:
-            # live reshard → shm flash → manifest chain → peer-frame
-            # restore → legacy storage
-            state, step = self._load_via_reshard(target, restore_t0)
+            # degradation ladder, each rung journaled with its reason and
+            # under a span of its own: live reshard → shm flash → manifest
+            # chain → peer-frame restore → legacy storage
+            with rung(SpanName.CKPT_RESTORE_RESHARD):
+                state, step = self._load_via_reshard(target, restore_t0)
             if state is not None:
                 sp.add_event("restored", medium="reshard", step=step)
                 return state, step
             if self._replicas is not None:
                 # a relaunched node's shm is empty — pull own frame from a
                 # backup-group peer first (replica.py restore semantics)
-                try:
-                    self._replicas.try_restore_shm(
-                        self._shm, self.local_rank
-                    )
-                except Exception as e:  # noqa: BLE001 — degrade to storage
-                    logger.warning("replica restore failed: %r", e)
-            local_step = self._verify_shm_or_repair()
-            step = self._shm_step_consistent(local_step)
+                with rung(SpanName.CKPT_RESTORE_REPLICA_PULL):
+                    try:
+                        self._replicas.try_restore_shm(
+                            self._shm, self.local_rank
+                        )
+                    except Exception as e:  # noqa: BLE001 — degrade to storage
+                        logger.warning("replica restore failed: %r", e)
+            with rung(SpanName.CKPT_RESTORE_VERIFY):
+                local_step = self._verify_shm_or_repair()
+            with rung(SpanName.CKPT_RESTORE_CONSISTENT):
+                step = self._shm_step_consistent(local_step)
             if step is not None and step >= 0:
-                state = self._load_from_shm(target, in_place=in_place)
+                with rung(SpanName.CKPT_RESTORE_SHM):
+                    state = self._load_from_shm(target, in_place=in_place)
                 if state is not None:
                     logger.info(
                         "restored step %s from shared memory", step
@@ -804,16 +844,18 @@ class CheckpointEngine:
                 sp.add_event("restored", medium="chain", step=step)
                 self._finish_restore(restore_t0, "chain", step)
                 return state, step
-            state, step = self._load_from_peer_frames(target)
+            with rung(SpanName.CKPT_RESTORE_PEER):
+                state, step = self._load_from_peer_frames(target)
             if state is not None:
                 logger.info("restored step %s from replica peer frames",
                             step)
                 sp.add_event("restored", medium="replica", step=step)
                 self._finish_restore(restore_t0, "replica", step)
                 return state, step
-            state, step = self._load_from_storage(
-                target, path or self.ckpt_dir
-            )
+            with rung(SpanName.CKPT_RESTORE_STORAGE):
+                state, step = self._load_from_storage(
+                    target, path or self.ckpt_dir
+                )
             sp.add_event("restored", medium="storage", step=step)
             self._finish_restore(restore_t0, "storage", step)
             return state, step
@@ -1085,7 +1127,7 @@ class CheckpointEngine:
             )
 
         with tracing.span(
-            SpanName.CKPT_CHAIN_RESTORE, source=f"worker_{self.rank}",
+            SpanName.CKPT_CHAIN_RESTORE, source=self._source,
         ) as sp:
             try:
                 step, frames = manifest.load_newest_chain(
@@ -1183,6 +1225,41 @@ _PACK_MAX_BYTES = 4 << 20
 _PACK_CHUNK_BYTES = 64 << 20
 
 
+class _RestorePool(ThreadPoolExecutor):
+    """``_assemble``'s threads. Each job runs under the trace context of
+    the thread that built the pool, so the read and host-to-device spans
+    become children of the restore rung that called ``_assemble``."""
+
+    def __init__(self):
+        super().__init__(_RESTORE_THREADS,
+                         thread_name_prefix="ckpt-restore")
+        self._parent = tracing.current_context()
+
+    def submit(self, fn, *args, **kwargs):
+        def job():
+            with tracing.activate(self._parent):
+                return fn(*args, **kwargs)
+
+        return super().submit(job)
+
+
+def _traced_read(reader, leaf_meta, shard_meta):
+    """One saved shard's bytes, under a span."""
+    with tracing.span(SpanName.CKPT_RESTORE_READ,
+                      bytes=shard_meta["nbytes"]):
+        return reader(leaf_meta, shard_meta)
+
+
+def _traced_put(value, where):
+    """``jax.device_put`` under a span: what the call itself takes. How
+    much of the transfer that is, is the backend's business (on the v5e
+    nearly all of it: PERF.md section 6, PR 24)."""
+    import jax
+
+    with tracing.span(SpanName.CKPT_RESTORE_H2D, bytes=int(value.nbytes)):
+        return jax.device_put(value, where)
+
+
 def _packable(dtype) -> bool:
     # bitcast_convert_type handles fixed-width numerics; bool is not
     # bitcastable, and 8-byte dtypes depend on the x64 flag — both take
@@ -1236,8 +1313,6 @@ class _ShardPacker:
 
 
 def _packed_chunk_job(device, entries):
-    import jax
-
     views = []
     layout = []
     off = 0
@@ -1250,7 +1325,7 @@ def _packed_chunk_job(device, entries):
         layout.append((off, int(b.nbytes), str(e["dtype"]), e["shape"]))
         off += int(b.nbytes)
     packed = np.concatenate(views) if views else np.zeros(0, np.uint8)
-    dbuf = jax.device_put(packed, device)
+    dbuf = _traced_put(packed, device)
     return _unpack_program(tuple(layout))(dbuf)
 
 
@@ -1301,14 +1376,11 @@ def _assemble(target, lookup: Dict[str, Dict], reader, reader_into=None):
     is written. (A mid-read I/O failure can still leave a partial fill;
     in-place callers own that trade.)"""
     import jax
-    from concurrent.futures import ThreadPoolExecutor
 
     named, treedef = _tree_flatten_with_names(target)
     if reader_into is not None:
         _validate_frame_against_target(named, lookup)
-    with ThreadPoolExecutor(
-        _RESTORE_THREADS, thread_name_prefix="ckpt-restore",
-    ) as pool:
+    with _RestorePool() as pool:
         packer = _ShardPacker(pool)
         finalizers = []
         for path, leaf in named:
@@ -1341,7 +1413,10 @@ def _assemble(target, lookup: Dict[str, Dict], reader, reader_into=None):
                 # in-place fast path: one saved shard covers the whole
                 # target leaf — fill it where it sits
                 def fill(out=leaf, lm=leaf_meta, sm=saved[0]):
-                    if not reader_into(lm, sm, out):
+                    with tracing.span(SpanName.CKPT_RESTORE_READ,
+                                      bytes=sm["nbytes"]):
+                        ok = reader_into(lm, sm, out)
+                    if not ok:
                         raise ValueError(f"in-place read failed for "
                                          f"{lm['path']}")
                     return out
@@ -1441,7 +1516,7 @@ def _make_region_reader(gshape, dtype, leaf_meta, reader):
                 list(shard_meta["start"]) == want_start
                 and list(shard_meta["lshape"]) == want_shape
             ):
-                data = reader(leaf_meta, shard_meta)
+                data = _traced_read(reader, leaf_meta, shard_meta)
                 return np.frombuffer(data, dtype=dtype).reshape(want_shape)
         out = np.zeros(want_shape, dtype=dtype)
         want_total = int(np.prod(want_shape)) if want_shape else 1
@@ -1460,7 +1535,7 @@ def _make_region_reader(gshape, dtype, leaf_meta, reader):
             ]
             if any(l >= h for l, h in zip(lo, hi)):
                 continue
-            data = reader(leaf_meta, shard_meta)
+            data = _traced_read(reader, leaf_meta, shard_meta)
             arr = np.frombuffer(data, dtype=dtype).reshape(s_shape)
             src = tuple(
                 slice(l - b, h - b) for l, h, b in zip(lo, hi, s_start)
@@ -1505,13 +1580,13 @@ def _submit_jax_leaf(pool, gshape, dtype, sharding, leaf_meta, reader,
 
         def scalar_job():
             if saved:
-                data = reader(leaf_meta, saved[0])
+                data = _traced_read(reader, leaf_meta, saved[0])
                 value = np.frombuffer(data, dtype=dtype).reshape(())
             else:
                 value = np.zeros((), dtype=dtype)
             if single_device:
                 return jnp.asarray(value)
-            return jax.device_put(value, sharding)
+            return _traced_put(value, sharding)
 
         fut = pool.submit(scalar_job)
         return fut.result
@@ -1535,7 +1610,7 @@ def _submit_jax_leaf(pool, gshape, dtype, sharding, leaf_meta, reader,
             ))
         else:
             fut = pool.submit(
-                lambda device=d, index=i: jax.device_put(
+                lambda device=d, index=i: _traced_put(
                     read_region(index), device
                 )
             )

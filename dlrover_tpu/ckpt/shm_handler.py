@@ -233,9 +233,12 @@ class SharedMemoryHandler:
 
     # -- write -------------------------------------------------------------
 
-    def write_frame(self, meta: Dict, buffers: List[np.ndarray]) -> None:
+    def write_frame(self, meta: Dict,
+                    buffers: List[np.ndarray]) -> Dict[str, float]:
         """Write meta + tensor buffers. ``meta['leaves']`` offsets must match
-        the order/sizes of ``buffers``."""
+        the order/sizes of ``buffers``. Returns where the data pass spent
+        its time: ``copy_s`` (buffers into the segment) and ``checksum_s``
+        (CRC32 + Adler32 over them), each summed over the buffers."""
         compute_crc = _crc_enabled()
         if compute_crc:
             # reserve fixed-width CRC slots for every shard that maps onto
@@ -287,10 +290,14 @@ class SharedMemoryHandler:
         pos = data_start
         crcs: Dict[int, int] = {}
         digs: Dict[int, bytes] = {}
+        copy_s = checksum_s = 0.0
+        t_checked = time.monotonic()
         for b in buffers:
             flat = np.ascontiguousarray(b).view(np.uint8).reshape(-1)
             n = flat.nbytes
             buf[pos : pos + n] = flat.data
+            t_copied = time.monotonic()
+            copy_s += t_copied - t_checked
             if compute_crc:
                 rel = pos - data_start
                 crcs[rel] = zlib.crc32(flat.data) & 0xFFFFFFFF
@@ -298,6 +305,8 @@ class SharedMemoryHandler:
                     crcs[rel], zlib.adler32(flat.data) & 0xFFFFFFFF
                 )
             pos += n
+            t_checked = time.monotonic()
+            checksum_s += t_checked - t_copied
         if compute_crc:
             for leaf in meta["leaves"]:
                 for shard in leaf.get("shards", []):
@@ -313,6 +322,7 @@ class SharedMemoryHandler:
         buf[8 : len(header)] = header[8:]
         buf[:8] = header[:8]
         self._maybe_inject_corruption(meta, data_start)
+        return {"copy_s": copy_s, "checksum_s": checksum_s}
 
     def _maybe_inject_corruption(self, meta: Dict, data_start: int) -> None:
         """``shm.write`` injection site: mutate the sealed frame's data the

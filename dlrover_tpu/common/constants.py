@@ -422,6 +422,36 @@ class SpanName:
     CKPT_PERSIST = "ckpt.persist"
     CKPT_COMMIT = "ckpt.commit"
     CKPT_RESTORE = "ckpt.restore"
+    # the same three from inside (ckpt/engine.py), one span a phase and
+    # none a leaf on the save's blocking path. Save block: readiness
+    # (drain-alive check, lock, peer exchange), the planning pass
+    # (flatten, on-device copy and D2H dispatch a shard), the meta-dict
+    # write. Drain thread: waiting for the D2H copies, the frame write
+    # (copy + checksums, split by its ``copy_s``/``checksum_s`` attrs),
+    # the hand-off to replicas, agent and master.
+    CKPT_SAVE_READY = "ckpt.save.ready"
+    CKPT_SAVE_PLAN = "ckpt.save.plan"
+    CKPT_SAVE_REGISTER = "ckpt.save.register"
+    CKPT_DRAIN_D2H_WAIT = "ckpt.drain.d2h_wait"
+    CKPT_DRAIN_SHM_WRITE = "ckpt.drain.shm_write"
+    CKPT_DRAIN_PUBLISH = "ckpt.drain.publish"
+    # restore: the wait for a drain in flight, then one span a rung of
+    # the ladder that was tried (``ckpt.chain_restore`` below is the
+    # chain rung's), and inside ``_assemble``, on its pool's threads, one
+    # span a byte read and one a host-to-device put
+    CKPT_RESTORE_WAIT_DRAINED = "ckpt.restore.wait_drained"
+    CKPT_RESTORE_RESHARD = "ckpt.restore.reshard"
+    CKPT_RESTORE_REPLICA_PULL = "ckpt.restore.replica_pull"
+    CKPT_RESTORE_VERIFY = "ckpt.restore.verify"
+    CKPT_RESTORE_CONSISTENT = "ckpt.restore.consistent"
+    CKPT_RESTORE_SHM = "ckpt.restore.shm"
+    CKPT_RESTORE_PEER = "ckpt.restore.peer"
+    CKPT_RESTORE_STORAGE = "ckpt.restore.storage"
+    CKPT_RESTORE_READ = "ckpt.restore.read"
+    CKPT_RESTORE_H2D = "ckpt.restore.h2d"
+    # one dispatch of the train step with every hook round it
+    # (trainer/elastic.py ElasticTrainer.train_step)
+    TRAIN_STEP = "train.step"
     # incremental-chain storage restore (engine._load_from_chain): the
     # newest-first candidate walk + striped frame reconstruction
     CKPT_CHAIN_RESTORE = "ckpt.chain_restore"
@@ -445,11 +475,10 @@ class SpanName:
     FANIN_FORWARD = "fanin.forward"
     FANIN_REPARENT = "fanin.reparent"
     # elastic decode-serving plane (dlrover_tpu/serving/): router-side
-    # routing of one request, replica-side generate handling, the
-    # batcher's prefill leg, a planned drain, and an applied serve plan
+    # routing of one request, replica-side generate handling, a planned
+    # drain, and an applied serve plan
     SERVE_ROUTE = "serve.route"
     SERVE_GENERATE = "serve.generate"
-    SERVE_PREFILL = "serve.prefill"
     SERVE_DRAIN = "serve.drain"
     SERVE_SCALE = "serve.scale"
     # per-request waterfall segments (serving/batcher.py): the TTFT

@@ -8,9 +8,16 @@ memory pressure among unexplained slowdowns. This module rides the
 jit/lower/compile paths the trainer (trainer/elastic.py `_build_step`)
 and serving engine (serving/engine.py `_note_shape`) already own:
 
-- every compile is timed with its abstract input signature
-  (``dlrover_compile_seconds`` + ``dlrover_compile_total{fn}``)
-- compile-cache hits/misses are counted per function
+- every first-seen abstract input signature is counted per function
+  (``dlrover_compile_total{fn}``), re-uses as hits: a *shadow* of the
+  jit cache by the dimensions the call site names, kept for storm
+  attribution. It cannot see a retrace those dimensions do not explain
+  (the same batch under a new state layout)
+- what the backend is really asked to compile is counted and timed where
+  jax reports it: one ``jax.monitoring`` listener for
+  ``/jax/core/compile/backend_compile_duration``, registered once for
+  the process, feeds ``dlrover_compile_requests_total`` (one a request,
+  served by the persistent cache or not) and ``dlrover_compile_seconds``
 - a sliding window per function detects *storms* — ≥N distinct
   signatures inside the window — and attributes the storm to the
   varying dimension (the dim whose distinct-value count is largest,
@@ -24,6 +31,7 @@ what makes attribution possible — an opaque hash could count storms but
 never explain them.
 """
 
+import sys
 import threading
 import time
 from collections import deque
@@ -62,25 +70,9 @@ def _storm_threshold() -> int:
     return env_int(ConfigKey.COMPILE_STORM_N, DEFAULT_STORM_THRESHOLD)
 
 
-class _Timer:
-    """Context manager returned by :meth:`CompileWatcher.time` — times
-    the enclosed compile only when the signature was a cache miss."""
-
-    def __init__(self, watcher: "CompileWatcher", fn: str, miss: bool):
-        self._watcher = watcher
-        self._fn = fn
-        self.miss = miss
-        self._t0: Optional[float] = None
-
-    def __enter__(self) -> "_Timer":
-        if self.miss:
-            self._t0 = self._watcher._monotonic()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        if self._t0 is not None and exc[0] is None:
-            self._watcher._observe_compile_s(
-                self._fn, self._watcher._monotonic() - self._t0)
+# jax's duration event round one request to the backend's compiler,
+# whether the persistent cache serves it or not
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 class CompileWatcher:
@@ -125,10 +117,15 @@ class CompileWatcher:
             "Signature re-uses (no retrace) per function",
             labelnames=("fn",),
         )
+        self._c_requests = registry.counter(
+            "dlrover_compile_requests_total",
+            "Compilation requests to the backend, served by the "
+            "persistent cache or not",
+        )
         self._h_seconds = registry.histogram(
             "dlrover_compile_seconds",
-            "Wall time of timed compiles (first call per signature — an "
-            "upper bound including the traced run)",
+            "Backend time of each compilation request (jax's "
+            "backend_compile_duration event)",
         )
         self._g_distinct = registry.gauge(
             "dlrover_compile_distinct_signatures",
@@ -164,14 +161,14 @@ class CompileWatcher:
             self._emit_storm(storm)
         return True
 
-    def time(self, fn: str, **dims: Any) -> _Timer:
-        """``with watcher.time("train_step", batch=b): step()`` — notes
-        the signature and, on a miss, times the enclosed block into
-        ``dlrover_compile_seconds``."""
-        return _Timer(self, fn, self.note(fn, **dims))
-
-    def _observe_compile_s(self, fn: str, seconds: float) -> None:
+    def compile_requested(self, seconds: float) -> None:
+        """One request to the backend's compiler took ``seconds``."""
+        self._c_requests.inc()
         self._h_seconds.observe(seconds)
+
+    def compile_requests(self) -> int:
+        """Requests to the backend's compiler so far in this process."""
+        return int(self._c_requests.value)
 
     # -- storm detection ---------------------------------------------------
 
@@ -250,6 +247,28 @@ class CompileWatcher:
 
 _default_watcher: Optional[CompileWatcher] = None
 _default_lock = threading.Lock()
+_listening = False
+
+
+def _on_jax_duration(event: str, duration_secs: float, **_: Any) -> None:
+    if event == BACKEND_COMPILE_EVENT:
+        get_watcher().compile_requested(duration_secs)
+
+
+def _listen_locked() -> None:
+    """Register the one listener that forwards jax's compile events to
+    whichever watcher is the process's at the time (jax keeps listeners
+    for the life of the process and offers no way to take one back).
+    Never the one to import jax: a process that has not imported it
+    compiles nothing, and a replica serving a toy engine must not pay
+    seconds of import inside its first request. Asked again at every
+    ``get_watcher()`` until jax is there."""
+    global _listening
+    monitoring = getattr(sys.modules.get("jax"), "monitoring", None)
+    if _listening or monitoring is None:
+        return
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    _listening = True
 
 
 def get_watcher() -> CompileWatcher:
@@ -260,6 +279,7 @@ def get_watcher() -> CompileWatcher:
     with _default_lock:
         if _default_watcher is None:
             _default_watcher = CompileWatcher()
+        _listen_locked()
         return _default_watcher
 
 
@@ -267,6 +287,7 @@ def set_watcher(watcher: CompileWatcher) -> CompileWatcher:
     global _default_watcher
     with _default_lock:
         _default_watcher = watcher
+        _listen_locked()
     return watcher
 
 

@@ -32,6 +32,16 @@ default: the ring is bounded and the recorder is the crash artifact).
 Span names are declared constants (``SpanName`` in common/constants.py);
 rule DLR007 rejects ad-hoc string literals at ``.span(...)`` call sites
 the same way DLR006 does for journal kinds and metric names.
+
+The profiler's clock: a worker installs a bridge (``install_bridge``,
+from ``worker.init()``), after which a span entered with ``with`` also
+enters an annotation named ``dlrover:<span name>`` on its thread — with
+``jax.profiler.TraceAnnotation`` as the factory it lands on the host
+lines of a profile beside the device ops whenever one is being taken, and
+costs the profiler's own flag check otherwise. This module never imports
+jax (master and agent processes use it): the factory is handed in. Spans
+ended by ``.end()`` without ``with`` have no thread to sit on and are not
+bridged; the disabled no-op is not bridged either.
 """
 
 import threading
@@ -39,7 +49,7 @@ import time
 import uuid
 from collections import deque
 from contextlib import contextmanager
-from typing import Any, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 from dlrover_tpu.common.constants import ConfigKey, env_flag, env_int
 
@@ -47,9 +57,27 @@ from dlrover_tpu.common.constants import ConfigKey, env_flag, env_int
 # purpose: it rides every RPC frame when a context is active.
 WIRE_KEY = "tc"
 
-DEFAULT_RING_SPANS = 2048
+# a worker's ring also takes one ``train.step`` span a step: at the 6.6
+# steps a second of the benchmark's dense cell 8192 spans are the last 20
+# minutes (2048 were five, less than one save interval of most jobs), and
+# weigh 4.7 MB when full (PERF.md section 6, PR 24)
+DEFAULT_RING_SPANS = 8192
+
+# prefix of a bridged span's name in the profiler trace
+BRIDGE_PREFIX = "dlrover:"
 
 _tls = threading.local()
+
+# name -> context manager; None until a worker installs one
+_bridge: Optional[Callable[[str], Any]] = None
+
+
+def install_bridge(annotation: Optional[Callable[[str], Any]]) -> None:
+    """Mirror every span entered with ``with`` as ``annotation(
+    "dlrover:<name>")``, entered and left on the span's own thread.
+    ``None`` uninstalls."""
+    global _bridge
+    _bridge = annotation
 
 
 class TraceContext(Tuple[str, str]):
@@ -83,7 +111,7 @@ class Span:
     __slots__ = (
         "trace_id", "span_id", "parent_id", "name", "source", "start_t",
         "end_t", "start_wall_ts", "status", "attrs", "events", "_tracer",
-        "_prev_ctx",
+        "_prev_ctx", "_annotation",
     )
 
     def __init__(self, tracer: "Tracer", name: str, source: str,
@@ -102,6 +130,7 @@ class Span:
         self.attrs = dict(attrs)
         self.events: List[Dict[str, Any]] = []
         self._prev_ctx: Optional[TraceContext] = None
+        self._annotation = None
 
     @property
     def context(self) -> TraceContext:
@@ -125,9 +154,15 @@ class Span:
     def __enter__(self) -> "Span":
         self._prev_ctx = current_context()
         _tls.ctx = self.context
+        if _bridge is not None:
+            self._annotation = _bridge(BRIDGE_PREFIX + self.name)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if exc_type is not None:
             self.status = "error"
             self.attrs.setdefault("error", repr(exc))

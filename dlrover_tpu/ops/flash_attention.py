@@ -188,6 +188,7 @@ def _fwd(
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qp, kp, vp)
     return o[:, :, :Sq], lse[:, :, :Sq, 0]
 
@@ -369,6 +370,7 @@ def _bwd(
         out_shape=jax.ShapeDtypeStruct((B, H, nq * bq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qp, kp, vp, dop, lsep, deltap)
 
     dk, dv = pl.pallas_call(
@@ -398,6 +400,7 @@ def _bwd(
             pltpu.VMEM((bk, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qp, kp, vp, dop, lsep, deltap)
 
     return dq[:, :, :Sq], dk[:, :, :Sk], dv[:, :, :Sk]
@@ -680,5 +683,6 @@ def flash_decode_attention(
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((fused, rows, Dh), out_dtype)],
         interpret=interpret,
+        name="flash_decode",
     )(pos_arr, *operands)[0]
     return out.reshape(B, KV, rows, Dh)[:, :, :G]

@@ -282,16 +282,15 @@ class BatchDecodeEngine:
         with self._shapes_lock:
             return len(self._shapes)
 
-    def _note_shape(self, key):
-        """Track the shape locally (compile_count invariant) and return
-        the process watcher's timer: a first-seen signature times the
-        enclosed jit call as a compile."""
+    def _note_shape(self, key) -> None:
+        """Track the shape locally (compile_count invariant) and note
+        its signature with the process watcher."""
         with self._shapes_lock:
             if key not in self._shapes:
                 self._shapes.add(key)
                 logger.info("serving engine traces %s", key)
         fn, dims = _shape_sig(key)
-        return get_watcher().time(fn, **dims)
+        get_watcher().note(fn, **dims)
 
     # -- pure prefill (prefill-worker threads) -----------------------------
 
@@ -353,12 +352,12 @@ class BatchDecodeEngine:
             raise ValueError(
                 f"bucket {bucket_len} exceeds cache length {self.cache_len}")
         padded = list(prompt) + [0] * (bucket_len - len(prompt))
-        with self._note_shape(("prefill", bucket_len)):
-            first, ks, vs = self._prefill_jit(
-                self._params,
-                jnp.asarray(padded, jnp.int32),
-                jnp.int32(len(prompt)),
-            )
+        self._note_shape(("prefill", bucket_len))
+        first, ks, vs = self._prefill_jit(
+            self._params,
+            jnp.asarray(padded, jnp.int32),
+            jnp.int32(len(prompt)),
+        )
         return PrefillResult(
             first_token=int(first),
             real_len=len(prompt),
@@ -446,14 +445,14 @@ class BatchDecodeEngine:
                 f"cache length {self.cache_len}")
         pre_ks, pre_vs = entry
         padded = list(prompt) + [0] * (bucket_len - len(prompt))
-        with self._note_shape(("prefill_sfx", bucket_len, m)):
-            first, ks, vs = self._sfx_jit(
-                self._params,
-                jnp.asarray(padded[m:], jnp.int32),
-                jnp.int32(len(prompt)),
-                pre_ks[:, :, :m],
-                pre_vs[:, :, :m],
-            )
+        self._note_shape(("prefill_sfx", bucket_len, m))
+        first, ks, vs = self._sfx_jit(
+            self._params,
+            jnp.asarray(padded[m:], jnp.int32),
+            jnp.int32(len(prompt)),
+            pre_ks[:, :, :m],
+            pre_vs[:, :, :m],
+        )
         return PrefillResult(
             first_token=int(first),
             real_len=len(prompt),
@@ -501,12 +500,12 @@ class BatchDecodeEngine:
         import jax.numpy as jnp
 
         ks, vs = result.payload
-        with self._note_shape(("insert", result.bucket_len)):
-            self._k, self._v, self._ks, self._vs, self._pos = \
-                self._insert_jit(
-                    self._k, self._v, self._ks, self._vs, self._pos, ks, vs,
-                    jnp.int32(slot), jnp.int32(result.real_len),
-                )
+        self._note_shape(("insert", result.bucket_len))
+        self._k, self._v, self._ks, self._vs, self._pos = \
+            self._insert_jit(
+                self._k, self._v, self._ks, self._vs, self._pos, ks, vs,
+                jnp.int32(slot), jnp.int32(result.real_len),
+            )
         return result.first_token
 
     def _step_fn(self, params, k_bufs, v_bufs, ks_bufs, vs_bufs, pos,
@@ -617,14 +616,14 @@ class BatchDecodeEngine:
              active: Sequence[bool]) -> List[int]:
         import jax.numpy as jnp
 
-        with self._note_shape(("step",)):
-            (nxt, self._k, self._v, self._ks, self._vs,
-             self._pos) = self._step_jit(
-                self._params, self._k, self._v, self._ks, self._vs,
-                self._pos,
-                jnp.asarray(list(tokens), jnp.int32),
-                jnp.asarray(list(active), bool),
-            )
+        self._note_shape(("step",))
+        (nxt, self._k, self._v, self._ks, self._vs,
+         self._pos) = self._step_jit(
+            self._params, self._k, self._v, self._ks, self._vs,
+            self._pos,
+            jnp.asarray(list(tokens), jnp.int32),
+            jnp.asarray(list(active), bool),
+        )
         return [int(t) for t in nxt]
 
     def set_params(self, params) -> None:

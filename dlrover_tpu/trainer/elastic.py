@@ -19,8 +19,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-from dlrover_tpu.common.constants import MetricLabel
+from dlrover_tpu.common.constants import MetricLabel, SpanName
 from dlrover_tpu.common.log import logger
+from dlrover_tpu.observability import tracing
 from dlrover_tpu.observability.compile_watch import get_watcher
 from dlrover_tpu.observability.memory import get_accountant
 from dlrover_tpu.parallel.mesh import ElasticMeshManager, MeshPlan, plan_mesh
@@ -212,15 +213,27 @@ class ElasticTrainer:
             self._train_step = self._build_step()
             self._register_state(state)
         shape = tuple(getattr(batch, "shape", ()) or ())
-        # structured compile signature: a varying rows-per-microbatch is
-        # exactly the ragged-batch storm the watcher attributes
-        with get_watcher().time(
-            "trainer.train_step",
-            accum=self.grad_accum_steps,
-            batch=shape[1] if len(shape) > 1 else 0,
-            seq_len=shape[2] if len(shape) > 2 else 0,
-        ):
-            return self._train_step(state, batch)
+        watcher = get_watcher()
+        traced = tracing.enabled()
+        with tracing.span(
+            SpanName.TRAIN_STEP, accum=self.grad_accum_steps,
+        ) as sp:
+            # structured compile signature: a varying rows-per-microbatch
+            # is exactly the ragged-batch storm the watcher attributes
+            watcher.note(
+                "trainer.train_step",
+                accum=self.grad_accum_steps,
+                batch=shape[1] if len(shape) > 1 else 0,
+                seq_len=shape[2] if len(shape) > 2 else 0,
+            )
+            # the counter is read only to fill the span's attribute
+            requests = watcher.compile_requests() if traced else 0
+            out = self._train_step(state, batch)
+            if traced:
+                compiles = watcher.compile_requests() - requests
+                if compiles:  # what the backend was asked, cached or not
+                    sp.attrs["compiles"] = compiles
+            return out
 
 
 def optax_global_norm(tree) -> jnp.ndarray:

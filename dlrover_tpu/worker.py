@@ -15,6 +15,7 @@ from typing import Optional
 from dlrover_tpu.agent.master_client import MasterClient
 from dlrover_tpu.common.constants import ConfigKey, EnvKey, env_str
 from dlrover_tpu.common.log import logger
+from dlrover_tpu.observability import compile_watch, tracing
 
 
 @dataclass
@@ -189,6 +190,13 @@ def init(initialize_jax_distributed: bool = True) -> WorkerContext:
     rank = int(os.getenv(EnvKey.RANK, "0"))
     world_size = int(os.getenv(EnvKey.WORLD_SIZE, "1"))
     enable_compilation_cache()
+    # the program's spans also sit in any profile taken of this process,
+    # beside the device ops and on their clock
+    import jax.profiler
+
+    tracing.install_bridge(jax.profiler.TraceAnnotation)
+    # and its compile requests are counted from here on, set-up's too
+    compile_watch.get_watcher()
     coordinator = os.getenv(EnvKey.COORDINATOR_ADDR, "")
     if initialize_jax_distributed and world_size > 1 and coordinator:
         import jax

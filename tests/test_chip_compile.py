@@ -34,6 +34,7 @@ from dlrover_tpu.parallel.mesh import build_mesh, plan_mesh
 from dlrover_tpu.trainer.elastic import ElasticTrainer, make_train_state
 
 B, H, S, D = 1, 32, 2048, 128        # attention, as chip_smoke.py
+KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 BD, T, POS = 8, 2048, 1500           # decode batch, cache length, position
 HBM_BYTES = 16 * 1024 ** 3
 
@@ -141,9 +142,17 @@ def test_train_step_one_layer_fits_the_chip(topo, monkeypatch):
     tokens = _shape((2, 2, S + 1), jnp.int32, on_mesh)
     lowered = trainer._build_step().lower(state, tokens)
     text = lowered.as_text()
-    for kernel in ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel"):
+    for kernel in KERNEL_NAMES:
         assert f'"{kernel}"' in text
-    mem = lowered.compile().memory_analysis()
+    compiled = lowered.compile()
+    # the compiled program's custom calls carry the kernels' names as
+    # their own (``%flash_fwd.13 = ... custom-call(``), and a profile
+    # names an op's events by that line
+    calls = [line.split()[0] for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for kernel in KERNEL_NAMES:
+        assert any(name.startswith(f"%{kernel}.") for name in calls), calls
+    mem = compiled.memory_analysis()
     # the donated state is aliased to the output: counted once
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)

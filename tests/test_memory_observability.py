@@ -315,12 +315,14 @@ def test_compile_note_hit_miss_counters():
     assert 'dlrover_compile_distinct_signatures{fn="prefill"} 2' in text
 
 
-def test_compile_timer_times_only_misses():
+@pytest.mark.parametrize("dims,first_seen", [
+    ([{"batch": 4}, {"batch": 4}], [True, False]),
+    ([{"batch": 4}, {"batch": 8}, {"batch": 4}], [True, True, False]),
+])
+def test_compile_note_says_whether_the_signature_is_first_seen(
+        dims, first_seen):
     w = CompileWatcher(registry=MetricsRegistry(), storm_threshold=100)
-    with w.time("step", batch=4) as t:
-        assert t.miss is True
-    with w.time("step", batch=4) as t:
-        assert t.miss is False
+    assert [w.note("step", **d) for d in dims] == first_seen
 
 
 def test_storm_fires_once_and_rearms_after_drain():
