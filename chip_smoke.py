@@ -51,6 +51,8 @@ sys.path.insert(0, HERE)
 # KILL_STEP's loss is written and before its save, resume from
 # KILL_STEP-1, replay KILL_STEP (the overlap), finish at TOTAL_STEPS
 KILL_STEP = 3
+# the names ops/flash_attention.py gives its Pallas calls (PR 24)
+FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 TOTAL_STEPS = 4
 
 # normalized max error (max|a-b| / max|b|) allowed between a bf16 kernel
@@ -478,9 +480,8 @@ def role_worker(a):
             rec("program",
                 lowered_custom_calls=text.count("tpu_custom_call"),
                 compiled_custom_calls=compiled.count("tpu_custom_call"),
-                kernels=sorted({n for n in (
-                    "_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel")
-                    if f'"{n}"' in text}),
+                kernels=sorted({n for n in FLASH_KERNELS
+                                if f'"{n}"' in text}),
                 collectives={op: compiled.count(f" {op}(")
                              + compiled.count(f" {op}-start(")
                              for op in ("all-gather", "reduce-scatter",
@@ -717,16 +718,14 @@ class Smoke:
         if a.rehearsal:
             self.say("train step: interpret-mode kernels (rehearsal)")
         else:
-            check(p["kernels"] == ["_bwd_dkv_kernel", "_bwd_dq_kernel",
-                                   "_fwd_kernel"]
+            check(p["kernels"] == sorted(FLASH_KERNELS)
                   and p["compiled_custom_calls"] >= 3,
                   f"flash kernel missing from the train step: {p}")
             self.say(
                 f"train step program: tpu_custom_call x"
                 f"{p['compiled_custom_calls']} compiled (lowered x"
                 f"{p['lowered_custom_calls']}): flash forward "
-                f"(_fwd_kernel) and backward (_bwd_dq_kernel, "
-                f"_bwd_dkv_kernel)")
+                f"(flash_fwd) and backward (flash_bwd_dq, flash_bwd_dkv)")
 
         # restore came from shm, bit for bit
         r0, r1 = one(ev, "restore", inc=0), one(ev, "restore", inc=1)

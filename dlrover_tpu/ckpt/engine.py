@@ -22,6 +22,7 @@ disagree) — the reference does the same with a gloo allgather
 (engine.py:375).
 """
 
+import contextlib
 import functools
 import os
 import queue
@@ -35,6 +36,7 @@ import numpy as np
 from dlrover_tpu.common.constants import (
     ConfigKey,
     EnvKey,
+    MetricLabel,
     SharedResourceName,
     SpanName,
     env_flag,
@@ -940,13 +942,12 @@ class CheckpointEngine:
         def reader(leaf_meta, shard_meta):
             return self._shm.read_shard_bytes(shard_meta)
 
-        reader_into = (
-            (lambda leaf_meta, shard_meta, out:
-             self._shm.read_shard_into(shard_meta, out))
-            if in_place else None
-        )
+        def reader_into(leaf_meta, shard_meta, out, offset=0):
+            return self._shm.read_shard_into(shard_meta, out, offset)
+
         try:
-            return _assemble(target, lookup, reader, reader_into=reader_into)
+            return _assemble(target, lookup, reader, reader_into=reader_into,
+                             in_place=in_place)
         except (KeyError, ValueError) as e:
             logger.warning("shm restore incomplete (%s) — trying storage", e)
             return None
@@ -1211,18 +1212,36 @@ class CheckpointEngine:
         return state, step
 
 
-# restore concurrency: shm-read + H2D of every target shard run on a
-# thread pool. H2D through PCIe pipelines across threads (measured ~1.7×
-# aggregate on v5e) and the host-side byte assembly of one shard overlaps
-# the device transfer of another.
+# restore concurrency: the reads and the host-to-device puts of a restore
+# run on a thread pool, a chunk of at most ``_PACK_CHUNK_BYTES`` a job. On
+# the v5e (PERF.md section 6, PR 25: one chip, 64 MiB puts from warm
+# page-aligned host memory) one thread moves 5.3-8.3 GB/s, two 7.6, four
+# 9.8, eight 11.4, sixteen 12.2: three and more fill the link, and the
+# rest serve what does not go through the ring.
 _RESTORE_THREADS = 8
-# shards below this ride a PACKED transfer: many-small-leaf states (dlrm
-# embeddings, per-layer checkpoints, optimizer scalars) otherwise pay a
-# fixed per-device_put cost per leaf (not measured on this installation's
-# chip). Packing turns N small puts into
-# ceil(bytes/_PACK_CHUNK) big ones + one on-device unpack program.
+# regions up to this size share a chunk and ride one PACKED transfer as
+# ``uint8``, unpacked by one program on the device: many-small-leaf
+# states (dlrm embeddings, per-layer checkpoints, optimizer scalars)
+# otherwise pay the fixed cost of a ``device_put`` a leaf. Larger ones go
+# in their own dtype: the unpack's ``reshape(-1, itemsize)`` has a minor
+# dimension of 2 or 4, which the TPU's tiling pads 32x and more.
 _PACK_MAX_BYTES = 4 << 20
+# and fill their chunk only so far: one thread reads a whole batch before
+# its transfer can start, so small leaves go in transfers an eighth of a
+# chunk deep, at a fixed cost a put that 8 MB still dwarf
+_PACK_BATCH_BYTES = 8 << 20
 _PACK_CHUNK_BYTES = 64 << 20
+# the staging ring. Its fresh pages are what a restore still pays for
+# host memory (64 MiB: 0.07 s alone, 0.17-0.35 s with eight threads at
+# it), and four chunks in flight already fill the link: restores with
+# three, four and eight took 0.74, 0.72 and 0.88 s.
+_STAGING_CHUNKS = 4
+_PAGE_BYTES = 4096
+# threads that compile the programs rebuilding large leaves, from the
+# moment the leaves are seen and beside the pool's reads and puts: eight
+# programs took 2.4 s on four threads, nine 3.9 s on one (0.3 s and less
+# where the persistent compile cache holds them)
+_COMPILE_THREADS = 4
 
 
 class _RestorePool(ThreadPoolExecutor):
@@ -1230,9 +1249,8 @@ class _RestorePool(ThreadPoolExecutor):
     the thread that built the pool, so the read and host-to-device spans
     become children of the restore rung that called ``_assemble``."""
 
-    def __init__(self):
-        super().__init__(_RESTORE_THREADS,
-                         thread_name_prefix="ckpt-restore")
+    def __init__(self, threads: int, name: str = "ckpt-restore"):
+        super().__init__(threads, thread_name_prefix=name)
         self._parent = tracing.current_context()
 
     def submit(self, fn, *args, **kwargs):
@@ -1243,28 +1261,66 @@ class _RestorePool(ThreadPoolExecutor):
         return super().submit(job)
 
 
+def _restore_bytes(path: str):
+    """The registry's count of restored array bytes by the way they took
+    (``MetricLabel.RESTORE_PATHS``): through the ring, or from a host
+    buffer of their own."""
+    from dlrover_tpu.observability.registry import get_registry
+
+    if path not in MetricLabel.RESTORE_PATHS:
+        raise ValueError(path)
+    return get_registry().counter(
+        "dlrover_ckpt_restore_bytes_total",
+        "Array bytes restored to devices, by path (staged ring or direct)",
+        labelnames=("path",),
+    ).labels(path=path)  # noqa: DLR013 — one of RESTORE_PATHS, checked
+
+
 def _traced_read(reader, leaf_meta, shard_meta):
-    """One saved shard's bytes, under a span."""
+    """One saved shard's bytes in a buffer of their own, under a span."""
     with tracing.span(SpanName.CKPT_RESTORE_READ,
-                      bytes=shard_meta["nbytes"]):
+                      bytes=shard_meta["nbytes"], staged=False):
         return reader(leaf_meta, shard_meta)
 
 
 def _traced_put(value, where):
-    """``jax.device_put`` under a span: what the call itself takes. How
-    much of the transfer that is, is the backend's business (on the v5e
-    nearly all of it: PERF.md section 6, PR 24)."""
+    """``jax.device_put`` of a host array that nothing else writes, under
+    a span: what the call itself takes. How much of the transfer that is,
+    is the backend's business: on the v5e nearly all of it from a
+    ``bytearray`` (PERF.md section 6, PR 24), 0.4 ms of 10 from 64 MiB of
+    page-aligned memory (PR 25)."""
     import jax
 
     with tracing.span(SpanName.CKPT_RESTORE_H2D, bytes=int(value.nbytes)):
-        return jax.device_put(value, where)
+        out = jax.device_put(value, where)
+    _restore_bytes(MetricLabel.RESTORE_PATH_DIRECT).inc(int(value.nbytes))
+    return out
+
+
+def _staged_put(view, device):
+    """A window of a staging chunk as an array on ``device`` that owns
+    its bytes: the next job overwrites the chunk. So the transfer is
+    waited for, and where the backend makes the host buffer the array's
+    own memory the array is copied on the device — the CPU backend does
+    that to any 64-byte-aligned buffer whatever ``may_alias`` says (jax
+    0.9.0). The TPU's copies into HBM, but ``device_put`` returns 0.4 ms
+    into a 64 MiB transfer's 10, with the chunk still being read."""
+    import jax
+    import jax.numpy as jnp
+
+    with tracing.span(SpanName.CKPT_RESTORE_H2D, bytes=int(view.nbytes)):
+        out = jax.device_put(view, device)
+        if device.platform == "cpu":
+            out = jnp.copy(out)
+        return jax.block_until_ready(out)
 
 
 def _packable(dtype) -> bool:
-    # bitcast_convert_type handles fixed-width numerics; bool is not
-    # bitcastable, and 8-byte dtypes depend on the x64 flag — both take
-    # the direct path. ml_dtypes customs (bfloat16, float8s) register
-    # with numpy kind 'V', so test via jnp's dtype lattice, not kind.
+    # what a staging chunk can carry: fixed-width numerics, viewed in
+    # their own dtype or bitcast on the device. bool is not bitcastable,
+    # and 8-byte dtypes depend on the x64 flag — both take the direct
+    # path. ml_dtypes customs (bfloat16, float8s) register with numpy
+    # kind 'V', so test via jnp's dtype lattice, not kind.
     import jax.numpy as jnp
 
     dt = np.dtype(dtype)
@@ -1276,57 +1332,299 @@ def _packable(dtype) -> bool:
         return False
 
 
-class _ShardPacker:
-    """Accumulate small per-device regions; ship each device's backlog as
-    one uint8 buffer + one jitted on-device unpack (slice→bitcast→reshape
-    per region — HBM-side ops, free next to the link)."""
+class _StagingRing:
+    """The host buffers every staged byte of one restore passes through:
+    ``_STAGING_CHUNKS`` chunks of ``_PACK_CHUNK_BYTES``, each made and
+    touched once by the first job that finds none free, then handed from
+    job to job and dropped with the ring. Beyond them a restore reads
+    into no fresh page: a ``pread`` of 512 MB of the segment takes 0.027 s
+    into a warm chunk and 0.79 s into a buffer made for it (PERF.md
+    section 6, PR 25)."""
 
-    def __init__(self, pool):
+    def __init__(self):
+        # last in, first out: a warm chunk is taken before a place
+        # (None) for one not made yet
+        self._free: "queue.LifoQueue[Optional[np.ndarray]]" = (
+            queue.LifoQueue())
+        for _ in range(_STAGING_CHUNKS):
+            self._free.put(None)
+
+    @contextlib.contextmanager
+    def chunk(self):
+        # made side by side where several jobs start at once: fresh pages
+        # come faster to several threads than to one on the v5e's host
+        # (PERF.md section 6, PR 25)
+        buf = self._free.get()
+        if buf is None:
+            buf = self._make()
+        try:
+            yield buf
+        finally:
+            self._free.put(buf)
+
+    @staticmethod
+    def _make() -> np.ndarray:
+        with tracing.span(SpanName.CKPT_RESTORE_RING,
+                          bytes=_PACK_CHUNK_BYTES):
+            # page-aligned, as a transfer engine wants its source (and
+            # the CPU backend then aliases every put: tier-1 holds
+            # ``_staged_put`` to its copy)
+            raw = np.empty(_PACK_CHUNK_BYTES + _PAGE_BYTES, np.uint8)
+            start = -raw.ctypes.data % _PAGE_BYTES
+            buf = raw[start:start + _PACK_CHUNK_BYTES]
+            buf.fill(0)  # the page faults, here and not under a read
+            return buf
+
+
+class _ShardRange:
+    """Where a region's bytes come from when it is exactly one saved
+    shard and the reader fills a caller's buffer: any byte range of it,
+    straight into the staging chunk."""
+
+    def __init__(self, reader_into, leaf_meta, shard_meta):
+        self._args = (reader_into, leaf_meta, shard_meta)
+
+    def fill(self, out: np.ndarray, offset: int) -> None:
+        reader_into, leaf_meta, shard_meta = self._args
+        with tracing.span(SpanName.CKPT_RESTORE_READ,
+                          bytes=int(out.nbytes), staged=True):
+            if not reader_into(leaf_meta, shard_meta, out, offset):
+                raise ValueError(
+                    f"staged read failed for {leaf_meta['path']} at byte "
+                    f"{offset}"
+                )
+
+
+class _HostRegion:
+    """Where a region's bytes come from otherwise (cut from several saved
+    shards, or a reader that only gives whole shards): assembled on the
+    host once, by the first job to ask, then copied range by range."""
+
+    def __init__(self, read):
+        self._read = read
+        self._lock = threading.Lock()
+        self._bytes: Optional[np.ndarray] = None
+
+    def fill(self, out: np.ndarray, offset: int) -> None:
+        with self._lock:
+            if self._bytes is None:
+                self._bytes = np.ascontiguousarray(
+                    self._read()).reshape(-1).view(np.uint8)
+        out[:] = self._bytes[offset:offset + out.nbytes]
+
+
+def _row_blocks(shape, itemsize: int):
+    """How a C-order region larger than a chunk is cut: ``(block shape,
+    [(byte offset, start indices, region bytes it is the first to
+    bring)])``. Blocks run along the first axis whose slices fit a chunk,
+    one after another for every index of the axes before it; all have one
+    shape, so one program places them all — the last of a run starts
+    early enough to end with the axis and brings some rows twice."""
+    axis, inner = 0, int(np.prod(shape[1:], dtype=np.int64)) * itemsize
+    while inner > _PACK_CHUNK_BYTES:
+        axis += 1
+        inner = int(np.prod(shape[axis + 1:], dtype=np.int64)) * itemsize
+    rows = shape[axis]
+    runs = -(-rows // max(1, _PACK_CHUNK_BYTES // inner))
+    per = -(-rows // runs)
+    block_shape = (1,) * axis + (per,) + tuple(shape[axis + 1:])
+    blocks = []  # a tuple in the end: part of ``_rebuild_program``'s key
+    for n, lead in enumerate(np.ndindex(*shape[:axis])):
+        for run in range(runs):
+            start = min(run * per, rows - per)
+            fresh = min((run + 1) * per, rows) - run * per
+            blocks.append((
+                (n * rows + start) * inner,
+                lead + (start,) + (0,) * (len(shape) - axis - 1),
+                fresh * inner,
+            ))
+    return block_shape, tuple(blocks)
+
+
+@functools.lru_cache(maxsize=64)
+def _rebuild_program(shape, dtype_str, block_shape, starts, device):
+    """The one compiled program that rebuilds a region larger than a
+    chunk on its device from its blocks (``_row_blocks``): each written
+    at its start indices into a buffer of the region's shape, which for
+    a narrow float (``_carrier``) is then seen in the region's dtype, at
+    a copy of the region while the program runs. One request to the
+    compiler a (shape, dtype): a request is a quarter to half a second on
+    the v5e, however small the program (PERF.md section 6, PR 25).
+    Module-level lru_cache: regions of one shape and dtype — and later
+    restores of the process — share it."""
+    import jax
+    import jax.numpy as jnp
+
+    where = jax.sharding.SingleDeviceSharding(device)
+    dt = _np_dtype(dtype_str)
+    carrier = _carrier(dt)
+
+    def rebuild(*parts):
+        buf = jnp.zeros(shape, carrier)
+        for part, start in zip(parts, starts):
+            buf = jax.lax.dynamic_update_slice(buf, part, start)
+        return buf if carrier == dt else jax.lax.bitcast_convert_type(
+            buf, dt)
+
+    with tracing.span(SpanName.CKPT_RESTORE_COMPILE, shape=str(shape),
+                      dtype=dtype_str):
+        return jax.jit(rebuild).lower(*(
+            jax.ShapeDtypeStruct(block_shape, carrier, sharding=where)
+            for _ in starts
+        )).compile()
+
+
+def _carrier(dtype) -> np.dtype:
+    """What a block's elements are on their way through
+    ``_rebuild_program``: themselves, but for a float narrower than 32
+    bits the unsigned integer of its width. A backend may widen such a
+    float to move it (the CPU's does with bfloat16, in a concatenate as
+    in an update, and quiets its NaNs); integers and float32 no backend
+    rounds."""
+    import jax.numpy as jnp
+
+    dt = np.dtype(dtype)
+    if dt.itemsize < 4 and jnp.issubdtype(dt, jnp.floating):
+        return np.dtype(f"uint{8 * dt.itemsize}")
+    return dt
+
+
+class _Region:
+    """One addressable region of a jax leaf on its way through the ring:
+    where its bytes come from and, once submitted, where its device array
+    will be (``fut`` of a chunk's arrays, ``pos`` among them)."""
+
+    __slots__ = ("source", "dtype", "shape", "nbytes", "fut", "pos")
+
+    def __init__(self, source, dtype, shape):
+        self.source = source
+        self.dtype = np.dtype(dtype)
+        self.shape = tuple(shape)
+        self.nbytes = (int(np.prod(self.shape, dtype=np.int64))
+                       * self.dtype.itemsize)
+        self.fut = None
+        self.pos = 0
+
+    def result(self):
+        return self.fut.result()[self.pos]
+
+
+class _Stager:
+    """Every packable region of a restore on its way to its device
+    through the staging ring: read (or copied) into a chunk, viewed there
+    in its own dtype, put, and the chunk handed on once the transfer has
+    consumed it. A region takes a slice of a chunk — small ones share one
+    and ride one packed transfer — or, when larger than a chunk, several:
+    its blocks are read and put side by side with every other region's
+    and rebuilt on the device."""
+
+    def __init__(self, pool, compiler):
         self._pool = pool
-        self._pending: Dict[Any, list] = {}
-        self._bytes: Dict[Any, int] = {}
+        self._ring = _StagingRing()
+        self._pending: Dict[Any, List[_Region]] = {}
+        self._compiler = compiler
+        self._programs: Dict[Any, Any] = {}
+        self._moved = _restore_bytes(MetricLabel.RESTORE_PATH_STAGED)
 
-    def add(self, device, read_fn, dtype, shape):
+    def add(self, device, source, dtype, shape):
         """Register one region; returns a finalizer for its device array."""
-        entry = {"read": read_fn, "dtype": np.dtype(dtype),
-                 "shape": tuple(shape), "fut": None, "pos": 0}
-        self._pending.setdefault(device, []).append(entry)
-        nbytes = int(np.prod(shape) if shape else 1) * entry["dtype"].itemsize
-        self._bytes[device] = self._bytes.get(device, 0) + nbytes
-        if self._bytes[device] >= _PACK_CHUNK_BYTES:
-            self._flush_device(device)
-        return lambda: entry["fut"].result()[entry["pos"]]
-
-    def _flush_device(self, device) -> None:
-        entries = self._pending.pop(device, [])
-        self._bytes.pop(device, None)
-        if not entries:
-            return
-        fut = self._pool.submit(_packed_chunk_job, device, entries)
-        for pos, e in enumerate(entries):
-            e["fut"] = fut
-            e["pos"] = pos
+        region = _Region(source, dtype, shape)
+        if region.nbytes > _PACK_CHUNK_BYTES:
+            return self._add_blocks(device, region)
+        if region.nbytes > _PACK_MAX_BYTES:
+            self._submit_chunk(device, [region])
+            return region.result
+        pending = self._pending.setdefault(device, [])
+        if pending and (sum(r.nbytes for r in pending) + region.nbytes
+                        > _PACK_BATCH_BYTES):
+            self._submit_chunk(device, self._pending.pop(device))
+            pending = self._pending.setdefault(device, [])
+        pending.append(region)
+        return region.result
 
     def flush(self) -> None:
+        """Submit the chunks still filling. Before any finalizer runs."""
         for device in list(self._pending):
-            self._flush_device(device)
+            self._submit_chunk(device, self._pending.pop(device))
 
+    def _submit_chunk(self, device, regions: List[_Region]) -> None:
+        fut = self._pool.submit(self._chunk_job, device, regions)
+        for pos, region in enumerate(regions):
+            region.fut, region.pos = fut, pos
 
-def _packed_chunk_job(device, entries):
-    views = []
-    layout = []
-    off = 0
-    for e in entries:
-        arr = e["read"]()
-        if not arr.flags["C_CONTIGUOUS"]:
-            arr = np.ascontiguousarray(arr)
-        b = arr.reshape(-1).view(np.uint8)
-        views.append(b)
-        layout.append((off, int(b.nbytes), str(e["dtype"]), e["shape"]))
-        off += int(b.nbytes)
-    packed = np.concatenate(views) if views else np.zeros(0, np.uint8)
-    dbuf = _traced_put(packed, device)
-    return _unpack_program(tuple(layout))(dbuf)
+    def _chunk_job(self, device, regions: List[_Region]):
+        """One chunk of whole regions: one alone goes in its own dtype,
+        several go packed as bytes and are unpacked on the device."""
+        with self._ring.chunk() as chunk:
+            layout, end = [], 0
+            for r in regions:
+                r.source.fill(chunk[end:end + r.nbytes], 0)
+                layout.append((end, r.nbytes, str(r.dtype), r.shape))
+                end += r.nbytes
+            if len(regions) == 1:
+                only = regions[0]
+                out = (_staged_put(
+                    chunk[:end].view(only.dtype).reshape(only.shape),
+                    device),)
+            else:
+                out = _unpack_program(tuple(layout))(
+                    _staged_put(chunk[:end], device))
+        self._moved.inc(end)
+        return out
+
+    def _add_blocks(self, device, region: _Region):
+        """A region larger than a chunk: its blocks go through the ring
+        side by side with everything else, and the job that brings the
+        last one rebuilds the region on the device. Beyond the restored
+        state the device so holds the blocks of the regions being
+        rebuilt: about one region's bytes."""
+        block_shape, blocks = _row_blocks(region.shape,
+                                          region.dtype.itemsize)
+        key = (region.shape, str(region.dtype), block_shape,
+               tuple(start for _, start, _ in blocks), device)
+        if key not in self._programs:
+            # on a thread of their own from the moment the region is
+            # seen: the compile falls inside the restore in a process
+            # that has not restored before, which is every resumed worker
+            self._programs[key] = self._compiler.submit(
+                _rebuild_program, *key)
+        program = self._programs[key]
+        carrier = _carrier(region.dtype)
+        nbytes = (int(np.prod(block_shape, dtype=np.int64))
+                  * region.dtype.itemsize)
+        parts = [None] * len(blocks)
+        left = [len(blocks)]
+        lock = threading.Lock()
+
+        def block_job(n, offset, fresh):
+            # no block travels before its program is there: a cold
+            # compiler holds transfers back, not device memory
+            rebuild = program.result()
+            with self._ring.chunk() as chunk:
+                region.source.fill(chunk[:nbytes], offset)
+                parts[n] = _staged_put(
+                    chunk[:nbytes].view(carrier).reshape(block_shape),
+                    device)
+            self._moved.inc(fresh)
+            with lock:
+                left[0] -= 1
+                last = not left[0]
+            if not last:
+                return None
+            out = rebuild(*parts)
+            parts.clear()
+            return out
+
+        futs = [
+            self._pool.submit(block_job, n, offset, fresh)
+            for n, (offset, _, fresh) in enumerate(blocks)
+        ]
+
+        def finalize():
+            done = [fut.result() for fut in futs]
+            return next(out for out in done if out is not None)
+
+        return finalize
 
 
 @functools.lru_cache(maxsize=64)
@@ -1355,33 +1653,46 @@ def _unpack_program(layout):
     return jax.jit(unpack)
 
 
-def _assemble(target, lookup: Dict[str, Dict], reader, reader_into=None):
+def _assemble(target, lookup: Dict[str, Dict], reader, reader_into=None,
+              in_place: bool = False):
     """Rebuild a pytree like ``target`` from saved leaf metas + a byte
     reader. Handles re-sharding: each needed addressable shard is cut from
     whichever saved shards cover its global index range.
 
-    Two-phase: every (leaf, shard) read+transfer is submitted to a thread
-    pool first (small regions coalesced per device by the packer), then
-    finalized in tree order — so transfers overlap instead of running one
-    ``device_put`` at a time (VERDICT r1 weak #3, r2 weak #3).
+    Two-phase: every read+transfer is submitted to a thread pool first,
+    then finalized in tree order — so transfers overlap instead of
+    running one ``device_put`` at a time (VERDICT r1 weak #3, r2 weak #3).
+    Regions of jax leaves go through the pool's staging ring
+    (:class:`_Stager`), a chunk a job, whatever their size.
 
-    ``reader_into(leaf_meta, shard_meta, out) -> bool`` (optional): fill
-    a writable buffer in place; numpy target leaves that exactly match a
-    single saved shard are then restored without allocating. In-place
-    fills mutate the caller's buffers as they land, so the frame is
-    validated against the target UP FRONT: every target path must exist,
-    every numpy array leaf must match the frame's dtype and global shape,
-    and every array leaf's saved shards must cover its full global region
-    — a structurally-mismatched or incomplete frame fails before any byte
-    is written. (A mid-read I/O failure can still leave a partial fill;
-    in-place callers own that trade.)"""
+    ``reader(leaf_meta, shard_meta)`` gives one saved shard's bytes.
+    ``reader_into(leaf_meta, shard_meta, out, offset=0) -> bool``
+    (optional) fills a writable buffer with the shard's bytes from
+    ``offset`` on: a region that is exactly one saved shard is then read
+    range by range straight into staging and never into a buffer of its
+    own.
+
+    ``in_place`` (needs ``reader_into``): numpy target leaves that
+    exactly match a single saved shard are filled where they sit.
+    In-place fills mutate the caller's buffers as they land, so the
+    frame is validated against the target UP FRONT: every target path
+    must exist, every numpy array leaf must match the frame's dtype and
+    global shape, and every array leaf's saved shards must cover its full
+    global region — a structurally-mismatched or incomplete frame fails
+    before any byte is written. (A mid-read I/O failure can still leave a
+    partial fill; in-place callers own that trade.)"""
     import jax
 
+    from dlrover_tpu.observability import compile_watch
+
     named, treedef = _tree_flatten_with_names(target)
-    if reader_into is not None:
+    if in_place:
         _validate_frame_against_target(named, lookup)
-    with _RestorePool() as pool:
-        packer = _ShardPacker(pool)
+    compiles = compile_watch.get_watcher()
+    asked = compiles.compile_requests()
+    with _RestorePool(_RESTORE_THREADS) as pool, _RestorePool(
+            _COMPILE_THREADS, "ckpt-restore-compile") as compiler:
+        stager = _Stager(pool, compiler)
         finalizers = []
         for path, leaf in named:
             if path not in lookup:
@@ -1395,12 +1706,12 @@ def _assemble(target, lookup: Dict[str, Dict], reader, reader_into=None):
             if _is_jax_array(leaf) or hasattr(leaf, "sharding"):
                 finalizers.append(_submit_jax_leaf(
                     pool, gshape, dtype, leaf.sharding, leaf_meta, reader,
-                    packer,
+                    reader_into, stager,
                 ))
                 continue
             saved = leaf_meta["shards"]
             if (
-                reader_into is not None
+                in_place
                 and isinstance(leaf, np.ndarray)
                 and leaf.flags.writeable
                 and leaf.flags["C_CONTIGUOUS"]
@@ -1414,7 +1725,7 @@ def _assemble(target, lookup: Dict[str, Dict], reader, reader_into=None):
                 # target leaf — fill it where it sits
                 def fill(out=leaf, lm=leaf_meta, sm=saved[0]):
                     with tracing.span(SpanName.CKPT_RESTORE_READ,
-                                      bytes=sm["nbytes"]):
+                                      bytes=sm["nbytes"], staged=False):
                         ok = reader_into(lm, sm, out)
                     if not ok:
                         raise ValueError(f"in-place read failed for "
@@ -1437,10 +1748,14 @@ def _assemble(target, lookup: Dict[str, Dict], reader, reader_into=None):
                 f.result() if f.result().flags.writeable
                 else f.result().copy()
             ))
-        packer.flush()
+        stager.flush()
         # finalize inside the pool context so worker exceptions surface
         # here (future.result re-raises KeyError/ValueError for callers)
         out_leaves = [f() for f in finalizers]
+    # on the rung's span: what the programs that rebuild large leaves on
+    # the device cost this restore in requests to the compiler
+    tracing.add_span_event(
+        "assembled", compile_requests=compiles.compile_requests() - asked)
     return jax.tree_util.tree_unflatten(treedef, out_leaves)
 
 
@@ -1497,13 +1812,28 @@ def _region_shape(index, gshape):
     )
 
 
+def _exact_shard(leaf_meta, index, gshape) -> Optional[Dict]:
+    """The saved shard that is exactly the global-index region ``index``,
+    or None: the common same-topology restore finds one for every region."""
+    want_start = [
+        (sl.start or 0) for sl in index
+    ] if index else [0] * len(gshape)
+    want_shape = list(_region_shape(index, gshape))
+    for shard_meta in leaf_meta["shards"]:
+        if (
+            list(shard_meta["start"]) == want_start
+            and list(shard_meta["lshape"]) == want_shape
+        ):
+            return shard_meta
+    return None
+
+
 def _make_region_reader(gshape, dtype, leaf_meta, reader):
-    """Reader of one global index region from the saved shards.
+    """Reader of one global index region from the saved shards, into a
+    host array of its own.
 
     Fast path: a single saved shard covering exactly the wanted region is
-    returned as a zero-copy ``np.frombuffer`` view of the shard bytes —
-    the common same-topology restore does no host copy beyond the shm
-    read itself."""
+    returned as a zero-copy ``np.frombuffer`` view of the shard bytes."""
     saved = leaf_meta["shards"]
 
     def read_region(index):
@@ -1511,13 +1841,10 @@ def _make_region_reader(gshape, dtype, leaf_meta, reader):
             (sl.start or 0) for sl in index
         ] if index else [0] * len(gshape)
         want_shape = list(_region_shape(index, gshape))
-        for shard_meta in saved:
-            if (
-                list(shard_meta["start"]) == want_start
-                and list(shard_meta["lshape"]) == want_shape
-            ):
-                data = _traced_read(reader, leaf_meta, shard_meta)
-                return np.frombuffer(data, dtype=dtype).reshape(want_shape)
+        exact = _exact_shard(leaf_meta, index, gshape)
+        if exact is not None:
+            data = _traced_read(reader, leaf_meta, exact)
+            return np.frombuffer(data, dtype=dtype).reshape(want_shape)
         out = np.zeros(want_shape, dtype=dtype)
         want_total = int(np.prod(want_shape)) if want_shape else 1
         filled = 0
@@ -1560,7 +1887,7 @@ def _make_region_reader(gshape, dtype, leaf_meta, reader):
 
 
 def _submit_jax_leaf(pool, gshape, dtype, sharding, leaf_meta, reader,
-                     packer: Optional["_ShardPacker"] = None):
+                     reader_into, stager: _Stager):
     """Submit all read+H2D work for one jax.Array leaf; return a
     finalizer producing the global array."""
     import jax
@@ -1601,20 +1928,23 @@ def _submit_jax_leaf(pool, gshape, dtype, sharding, leaf_meta, reader,
 
     getters = []
     for d, i in sharding.addressable_devices_indices_map(gshape).items():
-        shape = _region_shape(i, gshape)
-        nbytes = int(np.prod(shape) if shape else 1) * np.dtype(dtype).itemsize
-        if (packer is not None and nbytes <= _PACK_MAX_BYTES
-                and _packable(dtype)):
-            getters.append(packer.add(
-                d, lambda index=i: read_region(index), dtype, shape,
-            ))
-        else:
+        if not _packable(dtype):
             fut = pool.submit(
                 lambda device=d, index=i: _traced_put(
                     read_region(index), device
                 )
             )
             getters.append(fut.result)
+            continue
+        # a region that is exactly one saved shard streams by byte range;
+        # one cut from several is assembled on the host first
+        exact = _exact_shard(leaf_meta, i, gshape)
+        if reader_into is not None and exact is not None:
+            source = _ShardRange(reader_into, leaf_meta, exact)
+        else:
+            source = _HostRegion(lambda index=i: read_region(index))
+        getters.append(
+            stager.add(d, source, dtype, _region_shape(i, gshape)))
 
     def finalize():
         return jax.make_array_from_single_device_arrays(

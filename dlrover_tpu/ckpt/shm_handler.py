@@ -26,6 +26,7 @@ import os
 import struct
 import time
 import zlib
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional
 
 import msgpack
@@ -204,9 +205,13 @@ class SharedMemoryHandler:
         """fd on the segment's /dev/shm file, for pread-based reads.
 
         Reading large segments through the mmap walks a 4 KB-page mapping
-        and measures 4-45x slower than pread on VM hosts (nested-paging
-        TLB cost; tmpfs gets no hugepages) — the kernel's read path does
-        not pay it. Linux-only; callers fall back to the mmap view."""
+        (tmpfs gets no hugepages), which the kernel's read path does not.
+        On the v5e's host (PERF.md section 6, PR 25; 512 MB into a warm
+        64 MiB buffer, one thread): ``pread`` 0.027 s, the mapping 0.107 s
+        in the process that wrote the segment (4x) and 1.03 s on a fresh
+        process's first walk, where ``pread`` takes 0.083 s (12x). r05
+        measured 4-45x on other VM hosts. Linux-only; callers fall back
+        to the mmap view."""
         import os
 
         if self._fd is not None and self._fd_shm is self._shm:
@@ -380,7 +385,7 @@ class SharedMemoryHandler:
         """Read exactly ``len(buf)`` bytes at ``offset``, looping over
         short reads: a single ``preadv`` caps at MAX_RW_COUNT (~2 GB on
         Linux), so one-shot reads silently truncate on multi-GB frames
-        and would push them onto the 4-45x slower mmap walk."""
+        and would push them onto the slower mmap walk (``_shard_fd``)."""
         import os
 
         mv = memoryview(buf).cast("B")
@@ -424,25 +429,26 @@ class SharedMemoryHandler:
                 return buf
         return bytes(self._shm.buf[off : off + n])
 
-    def read_shard_into(self, shard_meta: Dict, out) -> bool:
-        """Read one shard directly into ``out`` (a writable buffer of
-        exactly the shard's size) — no fresh allocation, so steady-state
-        restores into preallocated staging skip the page-population cost
-        that dominates fresh-buffer reads on VM hosts."""
+    def read_shard_into(self, shard_meta: Dict, out,
+                        offset: int = 0) -> bool:
+        """Fill ``out`` (a writable contiguous buffer) with the shard's
+        bytes from ``offset`` on — no allocation, so a restore that reads
+        into warm staging skips the page population that dominates
+        fresh-buffer reads (PERF.md section 6, PR 25). False when the
+        range does not lie inside the shard."""
         if not self.open():
             return False
-        off = shard_meta["abs_offset"]
-        n = shard_meta["nbytes"]
         mv = memoryview(out)
-        if mv.nbytes != n:
-            return False
         if not mv.contiguous:
             return False
         mv = mv.cast("B")
+        if offset < 0 or offset + mv.nbytes > shard_meta["nbytes"]:
+            return False
+        off = shard_meta["abs_offset"] + offset
         fd = self._shard_fd()
         if fd is not None and self._preadv_full(fd, mv, off):
             return True
-        mv[:] = self._shm.buf[off : off + n]
+        mv[:] = self._shm.buf[off : off + mv.nbytes]
         return True
 
     def read_frame_bytes(self):
@@ -507,25 +513,51 @@ def parse_frame(blob: bytes) -> Optional[Dict]:
     return meta
 
 
-def frame_shard_bytes(meta: Dict, shard_meta: Dict) -> bytes:
-    blob = meta["_blob"]
+def frame_shard_bytes(meta: Dict, shard_meta: Dict) -> memoryview:
+    """One shard of a parsed frame, as a window onto the blob: no copy
+    (a ``bytes`` slice would put every shard into fresh pages)."""
     off = shard_meta["abs_offset"]
-    return blob[off : off + shard_meta["nbytes"]]
+    return memoryview(meta["_blob"])[off : off + shard_meta["nbytes"]]
+
+
+# threads of the CRC pass over a frame: ``zlib.crc32`` releases the
+# interpreter lock, so the shards of a frame are checked side by side
+_VERIFY_THREADS = 8
 
 
 def _verify_shards(meta: Dict, read: Callable[[Dict], Any]) -> List[str]:
-    bad: List[str] = []
-    for leaf in meta.get("leaves", []):
-        for shard in leaf.get("shards", []):
-            stamp = shard.get("crc")
-            if not stamp or "abs_offset" not in shard:
-                continue
-            data = read(shard)
-            if (data is None
-                    or (zlib.crc32(data) & 0xFFFFFFFF)
-                    != _CRC.unpack(stamp)[0]):
-                bad.append(f"{leaf.get('path', '?')}@{shard['offset']}")
-    return bad
+    """Names (``leafpath@offset``, in the frame's order) of the stamped
+    shards whose bytes do not give their CRC. Every stamped shard is
+    checked and the pass ends before it returns: largest shards first
+    over ``_VERIFY_THREADS`` threads, since one thread a frame made the
+    check a fifth of a restore (PERF.md section 6, PR 25)."""
+    stamped = [
+        (leaf, shard)
+        for leaf in meta.get("leaves", [])
+        for shard in leaf.get("shards", [])
+        if shard.get("crc") and "abs_offset" in shard
+    ]
+
+    def intact(shard: Dict) -> bool:
+        data = read(shard)
+        return (data is not None
+                and (zlib.crc32(data) & 0xFFFFFFFF)
+                == _CRC.unpack(shard["crc"])[0])
+
+    by_size = sorted(stamped, key=lambda ls: -int(ls[1].get("nbytes", 0)))
+    with ThreadPoolExecutor(
+        min(_VERIFY_THREADS, max(1, len(stamped))),
+        thread_name_prefix="ckpt-verify",
+    ) as pool:
+        corrupt = {
+            id(shard) for (_, shard), good in zip(
+                by_size, pool.map(intact, [shard for _, shard in by_size]))
+            if not good
+        }
+    return [
+        f"{leaf.get('path', '?')}@{shard['offset']}"
+        for leaf, shard in stamped if id(shard) in corrupt
+    ]
 
 
 def verify_parsed_frame(meta: Dict) -> List[str]:

@@ -438,7 +438,9 @@ class SpanName:
     # restore: the wait for a drain in flight, then one span a rung of
     # the ladder that was tried (``ckpt.chain_restore`` below is the
     # chain rung's), and inside ``_assemble``, on its pool's threads, one
-    # span a byte read and one a host-to-device put
+    # span a byte read (``staged`` says whether into the staging ring),
+    # one a host-to-device put, one a chunk of the ring made and faulted,
+    # one a pair of programs compiled to rebuild large leaves on a device
     CKPT_RESTORE_WAIT_DRAINED = "ckpt.restore.wait_drained"
     CKPT_RESTORE_RESHARD = "ckpt.restore.reshard"
     CKPT_RESTORE_REPLICA_PULL = "ckpt.restore.replica_pull"
@@ -449,6 +451,8 @@ class SpanName:
     CKPT_RESTORE_STORAGE = "ckpt.restore.storage"
     CKPT_RESTORE_READ = "ckpt.restore.read"
     CKPT_RESTORE_H2D = "ckpt.restore.h2d"
+    CKPT_RESTORE_RING = "ckpt.restore.ring"
+    CKPT_RESTORE_COMPILE = "ckpt.restore.compile"
     # one dispatch of the train step with every hook round it
     # (trainer/elastic.py ElasticTrainer.train_step)
     TRAIN_STEP = "train.step"
@@ -591,6 +595,12 @@ class MetricLabel:
         RUNG_RESHARD, RUNG_SHM, RUNG_CHAIN, RUNG_REPLICA, RUNG_STORAGE,
         RUNG_UNKNOWN,
     )
+    # how restored array bytes reached their device (ckpt/engine.py
+    # dlrover_ckpt_restore_bytes_total): through the staging ring, or put
+    # from a host buffer of their own
+    RESTORE_PATH_STAGED = "staged"
+    RESTORE_PATH_DIRECT = "direct"
+    RESTORE_PATHS = (RESTORE_PATH_STAGED, RESTORE_PATH_DIRECT)
     # checkpoint-commit triggers (ckpt/ckpt_saver.py → ckpt_committed
     # journal events): the cadence save, a membership-change/SIGTERM
     # breakpoint save, and the brain's predicted-failure pre-emptive save

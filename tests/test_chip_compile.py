@@ -113,6 +113,35 @@ def test_flash_decode_attention(one_chip, kv_heads, quantized):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("shape,dtype", [
+    ((32000, 4096), "float32"),        # an embedding's moment: 500 MiB
+    ((1, 4096, 14336), "bfloat16"),    # a stacked FFN weight: 112 MiB
+])
+def test_restore_rebuilds_a_large_leaf_in_its_own_bytes(topo, shape, dtype):
+    """``ckpt/engine.py`` ``_rebuild_program`` at the benchmark's leaves:
+    the blocks a restore puts chunk by chunk become the leaf, and the
+    program holds nothing beyond them and it but, for a narrow float
+    that travels as integers, one copy of the leaf — none padded by the
+    tiling of a two- or four-byte minor dimension."""
+    import numpy as np
+
+    from dlrover_tpu.ckpt import engine
+
+    itemsize = engine._np_dtype(dtype).itemsize
+    block_shape, blocks = engine._row_blocks(shape, itemsize)
+    compiled = engine._rebuild_program.__wrapped__(
+        shape, dtype, block_shape, tuple(start for _, start, _ in blocks),
+        topo.devices[0])
+    leaf = int(np.prod(shape)) * itemsize
+    block = int(np.prod(block_shape)) * itemsize
+    assert block <= engine._PACK_CHUNK_BYTES
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == leaf
+    assert memory.argument_size_in_bytes == len(blocks) * block
+    copies = engine._carrier(dtype) != engine._np_dtype(dtype)
+    assert memory.temp_size_in_bytes <= block + copies * leaf
+
+
 def test_train_step_one_layer_fits_the_chip(topo, monkeypatch):
     """``ElasticTrainer._build_step`` at 7B widths, depth 1: the flash
     kernel is in the program forward and backward, and XLA's own memory

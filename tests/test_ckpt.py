@@ -514,7 +514,7 @@ def test_packed_restore_many_small_leaves(tmp_path, mesh):
     """Many small leaves (mixed dtypes, sharded + replicated + scalar)
     restore bit-exact through the packed transfer path, with the H2D put
     count collapsing to ~one per device rather than one per leaf×device
-    (engine.py _ShardPacker — the per-put fixed cost is what dominated
+    (engine.py _Stager — the per-put fixed cost is what dominated
     many-leaf restores)."""
     import numpy as np
 

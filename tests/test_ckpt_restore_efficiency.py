@@ -24,6 +24,8 @@ import pytest
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
+from jax.sharding import Mesh, NamedSharding  # noqa: E402
+from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from dlrover_tpu.ckpt.engine import CheckpointEngine  # noqa: E402
 from dlrover_tpu.ckpt.shm_handler import SharedMemoryHandler, shm_name  # noqa: E402
@@ -34,16 +36,27 @@ _READ_RATE = 100e6  # bytes/s; per-stream (host reads parallelize)
 
 
 def test_restore_keeps_synthetic_link_90pct_busy(tmp_path, monkeypatch):
-    # 48 leaves x 4 MB: enough pipeline depth that the first read's
-    # latency and the engine's fixed costs (pool spin-up, meta parse)
-    # are amortized; total 192 MB -> floor 1.92 s at 100 MB/s
+    # 48 leaves x 4 MB (they share staging chunks and ride packed
+    # transfers) and one of 96 MB (split over two chunks and rebuilt on
+    # the device): enough pipeline depth that the first read's latency
+    # and the engine's fixed costs (pool spin-up, meta parse) are
+    # amortized; total 288 MB -> floor 2.88 s at 100 MB/s. The target is
+    # laid on a one-device mesh, as a trainer's state is: a leaf without
+    # a mesh is restored uncommitted, from a buffer of its own.
     n_leaves, leaf_elems = 48, 1 << 20
+    where = NamedSharding(Mesh(np.array(jax.devices()[:1]), ("d",)), P())
     state = {
-        f"w{i}": jnp.asarray(
-            np.random.default_rng(i).standard_normal(leaf_elems, np.float32)
+        f"w{i}": jax.device_put(
+            np.random.default_rng(i).standard_normal(leaf_elems, np.float32),
+            where,
         )
         for i in range(n_leaves)
     }
+    state["big"] = jax.device_put(
+        np.random.default_rng(99).standard_normal(
+            (24, 1 << 20), np.float32),
+        where,
+    )
     jax.block_until_ready(state)
     nbytes = sum(x.nbytes for x in state.values())
 
@@ -78,11 +91,11 @@ def test_restore_keeps_synthetic_link_90pct_busy(tmp_path, monkeypatch):
             _throttle_link(x)
             return real_put(x, *a, **kw)
 
-        real_read = SharedMemoryHandler.read_shard_bytes
+        real_read = SharedMemoryHandler.read_shard_into
 
-        def slow_read(self, shard_meta):
-            time.sleep(shard_meta["nbytes"] / _READ_RATE)  # concurrent
-            return real_read(self, shard_meta)
+        def slow_read(self, shard_meta, out, offset=0):
+            time.sleep(memoryview(out).nbytes / _READ_RATE)  # concurrent
+            return real_read(self, shard_meta, out, offset)
 
         # one warm-up load (page cache, any lazy imports), unthrottled
         engine.load(state)
@@ -90,7 +103,7 @@ def test_restore_keeps_synthetic_link_90pct_busy(tmp_path, monkeypatch):
         monkeypatch.setattr(jnp, "asarray", slow_asarray)
         monkeypatch.setattr(jax, "device_put", slow_put)
         monkeypatch.setattr(
-            SharedMemoryHandler, "read_shard_bytes", slow_read
+            SharedMemoryHandler, "read_shard_into", slow_read
         )
 
         # Other processes on the host (tier-1 runs six workers) can only
@@ -114,6 +127,7 @@ def test_restore_keeps_synthetic_link_90pct_busy(tmp_path, monkeypatch):
 
         monkeypatch.undo()
         assert jnp.array_equal(restored["w0"], state["w0"])
+        assert jnp.array_equal(restored["big"], state["big"])
         # serial read-then-transfer would land at ~0.5; the pipeline must
         # keep the link >=90% busy
         efficiency, wall, busy = max(readings)

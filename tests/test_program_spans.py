@@ -239,8 +239,11 @@ def test_save_drain_restore_leave_the_span_tree(tmp_path):
     assert sum(sp.attrs["bytes"] for sp in reads
                if sp.attrs["bytes"] == 64) == 256
     assert reads and puts
+    # each into a staging chunk the ring made on the pool's threads
+    rings = finished(SpanName.CKPT_RESTORE_RING)
+    assert rings and all(sp.attrs["staged"] for sp in reads)
     assert all(sp.parent_id == shm.span_id and sp.trace_id == restore.trace_id
-               for sp in reads + puts)
+               for sp in reads + puts + rings)
     pool_threads = {ident for kind, name, ident in FakeAnnotation.log
                     if name == "dlrover:ckpt.restore.read"}
     assert pool_threads and main not in pool_threads
@@ -248,7 +251,7 @@ def test_save_drain_restore_leave_the_span_tree(tmp_path):
     top, inside = program_spans.last_restore(
         sorted(finished(), key=lambda sp: sp.start_t))
     assert top is restore
-    assert {sp.span_id for sp in rungs + reads + puts} == {
+    assert {sp.span_id for sp in rungs + reads + puts + rings} == {
         sp.span_id for sp in inside}
 
 
