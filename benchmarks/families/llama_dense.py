@@ -1,10 +1,17 @@
 """Dense Llama-class decoders (GQA, RoPE, RMSNorm, SwiGLU) through the
 program's ``models/llama.py``. A configuration file names this family by
 ``"family": "llama_dense"``; its keys are the published ``config.json``'s.
+
+The family is the only place that knows what its architecture computes:
+``run.py`` holds the contract (``FAMILY_CONTRACT``) and ``jobs/train.py``
+asks through it. This one is a plain decoder, so its yardstick is the
+library's: ``reference/decoder.py`` and ``harness/flops.py``.
 """
 
 import jax.numpy as jnp
 
+from benchmarks.harness import flops
+from benchmarks.reference import decoder
 from dlrover_tpu.models import llama
 
 # --rehearsal only: control flow on the CPU, never a measurement
@@ -43,5 +50,11 @@ def loss_fn(config, mesh):
         params, tokens, config, mesh)
 
 
-def reference_kwargs(fields: dict, seq: int) -> dict:
-    return {}
+def reference(fields: dict, seq: int):
+    return lambda params, tokens: decoder.loss_and_grad_norm(
+        params, tokens, fields)
+
+
+param_count = flops.param_count
+train_flops_per_token = flops.train_flops_per_token
+flash_attention_flops = flops.flash_attention_flops

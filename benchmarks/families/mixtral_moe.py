@@ -4,10 +4,15 @@ renormalised gates, SwiGLU experts. ``"family": "mixtral_moe"``.
 
 ``fields["program"]`` holds what the program needs beyond the published
 keys: ``capacity_factor`` and ``route_group_size`` of its slot layout.
+
+Its yardstick is the library's (``reference/decoder.py``'s top-k FFN,
+``harness/flops.py``'s active experts and one attention call a layer).
 """
 
 import jax.numpy as jnp
 
+from benchmarks.harness import flops
+from benchmarks.reference import decoder
 from dlrover_tpu.models import moe
 
 REHEARSAL_FIELDS = {
@@ -55,5 +60,13 @@ def loss_fn(config, mesh):
         params, tokens, config, mesh)
 
 
-def reference_kwargs(fields: dict, seq: int) -> dict:
-    return {"route_group": fields["program"]["route_group_size"]}
+def reference(fields: dict, seq: int):
+    # only the auxiliary term depends on the program's routing group
+    group = fields["program"]["route_group_size"]
+    return lambda params, tokens: decoder.loss_and_grad_norm(
+        params, tokens, fields, route_group=group)
+
+
+param_count = flops.param_count
+train_flops_per_token = flops.train_flops_per_token
+flash_attention_flops = flops.flash_attention_flops

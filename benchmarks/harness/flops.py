@@ -9,6 +9,13 @@ plus causal attention. The embedding gather is no matmul and is not
 counted; of the experts only the ``num_experts_per_tok`` a token is
 routed to count; recomputation (remat) and the slots an expert layout
 pads (capacity factor) are the program's cost, not the algorithm's.
+
+This is the library of the plain-decoder families: ``llama_dense`` and
+``mixtral_moe`` answer ``param_count``, ``train_flops_per_token`` and
+``flash_attention_flops`` from it. ``jobs/train.py`` and the readers ask
+the cell's family, never this file (but ``named_kernels._least_flops``,
+for a job that carries no count): an architecture these shapes do not
+describe counts for itself in its own family, by the rule above.
 """
 
 
@@ -64,6 +71,13 @@ def attention_matmul_flops(f: dict, seq: int, rows: int = 1) -> float:
 # the recomputed QK^T, dP = dO V^T, dV = P^T dO, dQ = dS K, dK = dS^T Q
 ATTN_FWD_MATMULS = 2
 ATTN_BWD_MATMULS = 5
+
+
+def flash_attention_flops(f: dict, seq: int, rows: int):
+    """(forward, backward): the least the flash kernels need for one
+    microbatch of ``rows`` sequences where every layer calls them once."""
+    one = f["num_hidden_layers"] * attention_matmul_flops(f, seq, rows)
+    return ATTN_FWD_MATMULS * one, ATTN_BWD_MATMULS * one
 
 
 def train_flops_per_token(f: dict, seq: int) -> float:

@@ -32,14 +32,34 @@ def kernel_seconds(plane, prefixes, step_module):
             len(whole))
 
 
-def attention_roofline(ctx, prefixes, matmuls):
-    """100 x least time / kernel time on chip 0. Least time: ``matmuls``
-    causal score-sized matmuls a layer and microbatch (``flops.py``) for
-    the steps seen, over the published bf16 peak; the bound is compute,
-    as for ``flash_attn_roofline``. None without a trace, a peak, or a
-    kernel of that name in it. Prints a note ``kernel_calls``: calls a
-    step, the steps counted, and the step programs the profile holds."""
+def _least_flops(ctx, flops_key):
+    """``job[flops_key]``. A job from before PR 26 carries no count and the
+    ``rows_per_replica`` of a plain decoder instead, which is what
+    ``tests/test_program_spans.py`` still hands the readers: counted by
+    ``flops.py`` then. To go with that test's next edit (``PERF.md``
+    section 7); ``jobs/train.py`` always gives the count."""
+    job = ctx["job"]
+    if flops_key in job:
+        return job[flops_key]
+    fwd, bwd = flops.flash_attention_flops(
+        ctx["fields"], job["seq"], job["rows_per_replica"])
+    return {"flash_fwd_flops": fwd, "flash_bwd_flops": bwd}[flops_key]
+
+
+def attention_roofline(ctx, prefixes, flops_key):
+    """100 x least time / kernel time on chip 0. Least time: the FLOPs
+    ``ctx["job"][flops_key]`` (``flash_fwd_flops`` or ``flash_bwd_flops``:
+    what the cell's family counts for one microbatch over every call its
+    architecture makes, ``jobs/train.py`` puts them there) x microbatches
+    a step x the whole steps seen, over the published bf16 peak; the
+    bound is compute, as for ``flash_attn_roofline``. None without a
+    trace, a peak, a kernel of that name in it, or where the family
+    counts no FLOPs for it. Prints a note ``kernel_calls``: calls a step,
+    the steps counted, and the step programs the profile holds."""
     if not ctx["trace_raw"] or not ctx["peaks"]:
+        return None
+    per_microbatch = _least_flops(ctx, flops_key)
+    if not per_microbatch:
         return None
     planes = trace_reduce.device_planes(ctx["trace_raw"])
     if not planes:
@@ -52,9 +72,6 @@ def attention_roofline(ctx, prefixes, matmuls):
         "kernel_calls", kernel=prefixes[0], a_step=calls, whole_steps=steps,
         step_programs=len(trace_reduce.step_events(
             planes[0], ctx["step_module"])))
-    job, fields = ctx["job"], ctx["fields"]
-    per_step = (job["grad_accum"] * fields["num_hidden_layers"] * matmuls
-                * flops.attention_matmul_flops(
-                    fields, job["seq"], job["rows_per_replica"]))
-    least = steps * per_step / ctx["peaks"]["bf16_flops_per_s"]
+    least = (steps * ctx["job"]["grad_accum"] * per_microbatch
+             / ctx["peaks"]["bf16_flops_per_s"])
     return 100.0 * least / seconds

@@ -2,8 +2,17 @@
 
 ``worker.init()`` -> mesh from the live devices -> jitted, sharded init
 from the seed -> the float32 reference check -> ``ElasticTrainer`` ->
-warm-up -> the measured window -> (flash-save traffic) a restore from
-shm, compared bit for bit. No agent, no child process.
+warm-up -> the measured window -> (flash-save traffic) a warm-up restore
+from shm, then the timed ones, each compared bit for bit. No agent, no
+child process.
+
+What the architecture computes is its family's to say (``run.py``
+``FAMILY_CONTRACT``): this file asks ``family.reference(fields, seq)`` for
+the plain computation, ``family.param_count(fields)`` for what the state
+must hold, ``family.train_flops_per_token`` and
+``family.flash_attention_flops`` for the counts the readers divide by
+(``job["train_flops_per_token"]``, ``job["flash_fwd_flops"]``,
+``job["flash_bwd_flops"]``: no reader imports a family).
 
 Traffic parameters (``benchmarks/traffic/<name>.json``):
 
@@ -11,11 +20,18 @@ Traffic parameters (``benchmarks/traffic/<name>.json``):
   ``grad_accum * rows_per_replica * data replicas * seq`` tokens;
 - ``save_every_steps``: 0 for none, else a memory save after every N
   steps. The window is then whole cycles of N steps and one save;
-- ``restores_after_window``: how many ``load_checkpoint`` calls follow
-  the window, one after another, each into a fresh target and compared
-  bit for bit; ``restore_s`` is their total time over their number, and
-  a note gives each. More than one steadies nothing (the host's speed
-  drifts over minutes, ``PERF.md`` section 6), so the cells take one;
+- ``restore_warmups``: how many ``load_checkpoint`` calls come first
+  once the window has closed, untimed as restores: the first restore of
+  a process loads (or, in a fresh checkout, compiles) the programs that
+  rebuild large leaves and faults its staging chunks in, which is
+  warm-up like a step's compilation. Their seconds are added to
+  ``setup_s`` and kept in ``job["restore_warmups_s"]``; each is compared
+  bit for bit like a timed one. They come after the window and not
+  before it so that the window's memory peak stays the snapshot's;
+- ``restores_after_window``: how many timed ``load_checkpoint`` calls
+  follow, one after another, each into a fresh target and compared bit
+  for bit; ``restore_s`` is their total time over their number, and a
+  note gives each;
 - ``trace_steps``: how many steps the ``--trace 1`` run profiles.
 
 The loop keeps one step in flight: it dispatches step k+1, then blocks
@@ -31,9 +47,10 @@ import shutil
 import tempfile
 import time
 
-from benchmarks.harness import flops, stats
+from benchmarks.harness import stats
 
 STEP_MODULE = "step_fn"  # ElasticTrainer._build_step's jitted function
+RESTORE_HIST = "dlrover_ckpt_restore_seconds{source=shm}"
 
 
 def _batch_maker(np, mesh, vocab, rows, accum, seq, seed):
@@ -132,7 +149,6 @@ def _run(env, cleanup) -> dict:
     import numpy as np
     import optax
 
-    from benchmarks.reference import decoder
     from dlrover_tpu import worker
     from dlrover_tpu.ckpt.checkpointer import Checkpointer, StorageType
     from dlrover_tpu.ckpt.shm_handler import shm_name
@@ -189,10 +205,11 @@ def _run(env, cleanup) -> dict:
         lambda k: family.init_params(config, k),
         out_shardings=shardings)(key))
     n_params = sum(x.size for x in jax.tree.leaves(params))
-    if not args.rehearsal and n_params != flops.param_count(fields):
+    counted = family.param_count(fields)
+    if n_params != counted:
         raise RuntimeError(
-            f"the program made {n_params} parameters, the configuration "
-            f"file counts {flops.param_count(fields)}")
+            f"the program made {n_params} parameters, the family counts "
+            f"{counted} from the configuration file")
     batch_for = _batch_maker(np, mesh, fields["vocab_size"], rows, accum,
                              seq, args.seed)
     loss_fn = family.loss_fn(config, mesh)
@@ -206,9 +223,7 @@ def _run(env, cleanup) -> dict:
 
     sys_loss, sys_norm = (float(x) for x in jax.jit(system)(params, first))
     ref_loss, ref_norm = (float(x) for x in jax.jit(
-        lambda p, t: decoder.loss_and_grad_norm(
-            p, t, fields, **family.reference_kwargs(fields, seq))
-    )(params, first))
+        family.reference(fields, seq))(params, first))
     tol = fields["reference_tolerance"]
     loss_rel = abs(sys_loss - ref_loss) / abs(ref_loss)
     norm_rel = abs(sys_norm - ref_norm) / abs(ref_norm)
@@ -233,7 +248,14 @@ def _run(env, cleanup) -> dict:
     state = jax.block_until_ready(make_train_state(params, optimizer))
     del params
     state_bytes = sum(x.nbytes for x in jax.tree.leaves(state))
+    # what the architecture needs, as its family counts it: a token's
+    # forward and backward, and the flash kernels' least for one microbatch
+    flops_per_token = family.train_flops_per_token(fields, seq)
+    flash_fwd, flash_bwd = family.flash_attention_flops(
+        fields, seq, traffic["rows_per_replica"])
     note("model", params=n_params, state_bytes=state_bytes,
+         train_flops_per_token=flops_per_token,
+         flash_fwd_flops=flash_fwd, flash_bwd_flops=flash_bwd,
          mesh={k: v for k, v in mesh.shape.items() if v > 1},
          tokens_per_step=tokens_per_step, seq=seq, rows=rows, accum=accum,
          state_ready_s=time.monotonic() - env["t_start"])
@@ -287,7 +309,7 @@ def _run(env, cleanup) -> dict:
             registry.histogram("dlrover_ckpt_save_block_seconds"),
         "dlrover_ckpt_drain_seconds":
             registry.histogram("dlrover_ckpt_drain_seconds"),
-        "dlrover_ckpt_restore_seconds{source=shm}":
+        RESTORE_HIST:
             registry.histogram("dlrover_ckpt_restore_seconds",
                                labelnames=("source",)).labels(source="shm"),
     }
@@ -376,8 +398,9 @@ def _run(env, cleanup) -> dict:
     hbm_reserved = [int(m.get("bytes_reserved", 0)) for m in memory]
 
     # -- after the window --------------------------------------------------
-    restore_times = []
+    restore_times, warmup_times = [], []
     restores = traffic["restores_after_window"] if every else 0
+    warmups = traffic["restore_warmups"] if every and restores else 0
     saved_ok = True
     if every and not ckpt.engine.wait_drained(600):
         failed += 1  # the last snapshot was lost
@@ -386,11 +409,13 @@ def _run(env, cleanup) -> dict:
         target = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                            sharding=x.sharding), state)
-    for _ in range(restores):
+    for n in range(warmups + restores):
+        timed = n >= warmups
         t = time.monotonic()
         restored, restored_step = ckpt.load_checkpoint(target)
         jax.block_until_ready(restored)
-        restore_times.append(time.monotonic() - t)
+        (restore_times if timed else warmup_times).append(
+            time.monotonic() - t)
         same = sum_saved = sum_restored = None
         if restored_step == step:
             same, sum_saved, sum_restored = (
@@ -398,10 +423,14 @@ def _run(env, cleanup) -> dict:
         if not same:
             failed += 1
             saved_ok = False
-        note("restore", seconds=restore_times[-1], saved_step=step,
-             restored_step=restored_step, bits_equal=same,
+        note("restore" if timed else "restore_warmup",
+             seconds=(restore_times if timed else warmup_times)[-1],
+             saved_step=step, restored_step=restored_step, bits_equal=same,
              digest_saved=sum_saved, digest_restored=sum_restored)
         del restored
+        if not timed:  # the registry's restores are the timed ones
+            before[RESTORE_HIST] = snapshot()[RESTORE_HIST]
+    setup_s += sum(warmup_times)  # warm-up is set-up, wherever it runs
     after = snapshot()
 
     finite = [n for n, v in losses.items() if not np.isfinite(v)]
@@ -429,7 +458,7 @@ def _run(env, cleanup) -> dict:
     return {
         "correct": bool(reference_ok and not finite
                         and compiled_in_window == 0 and saved_ok),
-        "attempted": steps + saves + restores,
+        "attempted": steps + saves + warmups + restores,
         "failed": failed,
         "end_to_end": end_to_end,
         "trace_dir": tracer.dir,
@@ -441,9 +470,10 @@ def _run(env, cleanup) -> dict:
         "job": {
             "tokens_per_s_untraced": tokens / (window_s - tracer.overhead_s),
             "tokens_per_step": tokens_per_step, "seq": seq, "rows": rows,
-            "rows_per_replica": traffic["rows_per_replica"],
             "grad_accum": accum, "steps": steps, "saves": saves,
             "state_bytes": state_bytes, "chips": chips,
-            "train_flops_per_token": flops.train_flops_per_token(fields, seq),
+            "train_flops_per_token": flops_per_token,
+            "flash_fwd_flops": flash_fwd, "flash_bwd_flops": flash_bwd,
+            "restore_warmups_s": warmup_times,
         },
     }

@@ -1,6 +1,6 @@
 """State bytes over the registry's
 ``dlrover_ckpt_restore_seconds{source="shm"}`` per restore (the mean
-over the restores after the window), in MB/s (1e6 bytes)."""
+over the timed restores, the warm-up left out), in MB/s (1e6 bytes)."""
 
 
 def read(ctx):

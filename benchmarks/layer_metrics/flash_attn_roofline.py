@@ -7,19 +7,25 @@ trace events whose HLO text says ``custom_call_target="tpu_custom_call"``
 these models holds, and the trace gives them no name of their own
 (``kernel_metadata={}``): PERF.md lists the name the program must give.
 Least time: the FLOPs attention's forward and backward need for the
-steps seen (``harness/flops.py``: 2 + 5 causal score-sized matmuls a
-layer and microbatch, however the kernels split or repeat them) over the
-published bf16 peak. The bound is compute: at sequence 4096 and head
-size 128 a call needs some hundreds of FLOPs for each byte it must move.
+whole steps seen, ``job["flash_fwd_flops"] + job["flash_bwd_flops"]`` a
+microbatch, over the published bf16 peak. The cell's family counts them
+over every call its architecture makes of the kernels (for a plain
+decoder ``harness/flops.py``'s 2 + 5 causal score-sized matmuls a layer,
+however the kernels split or repeat them) and ``jobs/train.py`` puts
+them into the job; a family that counts none has no roofline here. The
+bound is compute: at sequence 4096 and head size 128 a call needs some
+hundreds of FLOPs for each byte it must move.
 """
 
-from benchmarks.harness import flops, trace_reduce
+from benchmarks.harness import trace_reduce
 
 PALLAS = 'custom_call_target="tpu_custom_call"'
 
 
 def read(ctx):
-    if not ctx["trace_raw"] or not ctx["peaks"]:
+    job = ctx["job"]
+    if not ctx["trace_raw"] or not ctx["peaks"] or not (
+            job["flash_fwd_flops"] + job["flash_bwd_flops"]):
         return None
     planes = trace_reduce.device_planes(ctx["trace_raw"])
     if not planes:
@@ -28,10 +34,7 @@ def read(ctx):
         planes[0], PALLAS, ctx["step_module"])
     if not seconds:
         return None
-    job, fields = ctx["job"], ctx["fields"]
-    per_step = (job["grad_accum"] * fields["num_hidden_layers"]
-                * (flops.ATTN_FWD_MATMULS + flops.ATTN_BWD_MATMULS)
-                * flops.attention_matmul_flops(
-                    fields, job["seq"], job["rows_per_replica"]))
-    least = steps * per_step / ctx["peaks"]["bf16_flops_per_s"]
+    least = (steps * job["grad_accum"]
+             * (job["flash_fwd_flops"] + job["flash_bwd_flops"])
+             / ctx["peaks"]["bf16_flops_per_s"])
     return 100.0 * least / seconds

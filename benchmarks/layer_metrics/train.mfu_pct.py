@@ -1,7 +1,8 @@
 """Model FLOP/s utilization: tokens per second of the window (less the
 seconds the profiler's own start and stop held the loop) times the
-FLOPs a token's forward and backward need (``harness/flops.py``: matmul
-parameters x 6 + causal attention, active experts only, no recompute),
+FLOPs a token's forward and backward need (the cell's family's
+``train_flops_per_token``, in ``job``: matmul parameters x 6 + causal
+attention, active experts only, no recompute),
 over chips x the published bf16 peak (``harness/peaks.py``)."""
 
 

@@ -50,6 +50,31 @@ def load_reader(name):
     return module
 
 
+# what every ``families/<family>.py`` exports (``README.md`` says what
+# each must do): the program's side of the architecture, then its
+# yardstick. No name has a default
+FAMILY_CONTRACT = (
+    "REHEARSAL_FIELDS", "program_config", "init_params", "logical_axes",
+    "loss_fn", "reference", "param_count", "train_flops_per_token",
+    "flash_attention_flops")
+
+
+class FamilyContractError(Exception):
+    """A family module lacks a name of ``FAMILY_CONTRACT``."""
+
+
+def load_family(name):
+    """``families/<name>.py``, refused where it lacks a name of the
+    contract: a yardstick that is not there is never guessed."""
+    family = importlib.import_module("benchmarks.families." + name)
+    missing = [n for n in FAMILY_CONTRACT if not hasattr(family, n)]
+    if missing:
+        raise FamilyContractError(
+            f"benchmarks/families/{name}.py lacks {', '.join(missing)}: a "
+            f"family exports {', '.join(FAMILY_CONTRACT)}")
+    return family
+
+
 def metrics_of(cell_name, entries):
     """The entries of ``end_to_end`` or ``per_layer`` this cell reports."""
     return [m for m in entries
@@ -88,6 +113,11 @@ def main(argv=None):
             os.environ.get("XLA_FLAGS", "") +
             f" --xla_force_host_platform_device_count={cell['chips']}")
     sys.path.insert(0, ROOT)
+    try:
+        family = load_family(fields["family"])
+    except FamilyContractError as e:
+        print(e, file=sys.stderr)
+        return 2
     import jax
 
     found = jax.devices()
@@ -103,8 +133,6 @@ def main(argv=None):
               f"{len(found)}", file=sys.stderr)
         return 3
 
-    family = importlib.import_module(
-        "benchmarks.families." + fields["family"])
     if args.rehearsal:
         fields = {**fields, **family.REHEARSAL_FIELDS}
     job = importlib.import_module("benchmarks.jobs." + traffic["job"])
