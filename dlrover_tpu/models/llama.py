@@ -282,6 +282,55 @@ def _mlp(x, layer):
     return jnp.einsum("bsf,fd->bsd", gate * up, layer["w2"])
 
 
+def decoder_layer(h, layer, config, positions, mesh):
+    """One decoder layer on ``h`` (B, S, D). ``positions`` None takes them
+    from ``h``'s own shape (inside a pipeline stage that is the local
+    shard). A layer tree that holds ``attn_post_norm`` / ``ffn_post_norm``
+    gets each branch normed again before it joins the residual (sandwich
+    norm, models/looped.py)."""
+    c = config
+    if positions is None:
+        positions = jnp.broadcast_to(
+            jnp.arange(h.shape[1])[None, :], h.shape[:2]
+        )
+    branch = _attention(
+        _rms_norm(h, layer["attn_norm"], c.norm_eps),
+        layer, c, positions, mesh,
+    )
+    if "attn_post_norm" in layer:
+        branch = _rms_norm(branch, layer["attn_post_norm"], c.norm_eps)
+    h = h + branch
+    branch = _mlp(_rms_norm(h, layer["ffn_norm"], c.norm_eps), layer)
+    if "ffn_post_norm" in layer:
+        branch = _rms_norm(branch, layer["ffn_post_norm"], c.norm_eps)
+    return h + branch
+
+
+def decoder_stack(x, layers, config, positions, mesh):
+    """The stacked layers as one ``lax.scan``, each layer under
+    ``jax.checkpoint`` where the config asks for remat. A function of its
+    own so that a caller may run the same weights more than once
+    (models/looped.py) or stage by stage (``forward_pp``)."""
+
+    def layer_fn(h, layer):
+        return decoder_layer(h, layer, config, positions, mesh), None
+
+    scan_fn = layer_fn
+    if config.remat:
+        scan_fn = jax.checkpoint(
+            layer_fn, prevent_cse=False, policy=_remat_policy(config),
+        )
+    x, _ = jax.lax.scan(scan_fn, x, layers)
+    return x
+
+
+def lm_head(x, weight):
+    """(B, S, D) x (D, vocab) -> f32 logits."""
+    return jnp.einsum(
+        "bsd,dv->bsv", x, weight, preferred_element_type=jnp.float32,
+    )
+
+
 def forward(
     params: Dict,
     tokens,
@@ -293,38 +342,25 @@ def forward(
     B, S = tokens.shape
     x = params["tok_embed"][tokens]
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-
-    def layer_fn(h, layer):
-        h = h + _attention(
-            _rms_norm(h, layer["attn_norm"], c.norm_eps),
-            layer, c, positions, mesh,
-        )
-        h = h + _mlp(_rms_norm(h, layer["ffn_norm"], c.norm_eps), layer)
-        return h, None
-
-    scan_fn = layer_fn
-    if c.remat:
-        scan_fn = jax.checkpoint(
-            layer_fn, prevent_cse=False, policy=_remat_policy(c),
-        )
-    x, _ = jax.lax.scan(scan_fn, x, params["layers"])
+    x = decoder_stack(x, params["layers"], c, positions, mesh)
     x = _rms_norm(x, params["final_norm"], c.norm_eps)
-    logits = jnp.einsum(
-        "bsd,dv->bsv", x, params["lm_head"],
-        preferred_element_type=jnp.float32,
-    )
-    return logits
+    return lm_head(x, params["lm_head"])
+
+
+def token_nll(logits, targets):
+    """Per-token NLL via logsumexp − gathered-logit: mathematically
+    identical to log_softmax + gather, but never materializes the full
+    (B, S, V) log-probability tensor — at vocab 32k/seq 2048 that
+    intermediate is ~1 GB of pure HBM traffic per pass. Measured on one
+    v5e: −3% step time (+1.7 MFU points) on the bench model."""
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return lse - tgt
 
 
 def cross_entropy(logits, targets):
-    """Mean NLL via logsumexp − gathered-logit: mathematically identical
-    to log_softmax + gather, but never materializes the full (B, S, V)
-    log-probability tensor — at vocab 32k/seq 2048 that intermediate is
-    ~1 GB of pure HBM traffic per pass. Measured on one v5e: −3% step
-    time (+1.7 MFU points) on the bench model."""
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return (lse - tgt).mean()
+    """Mean NLL over every token (:func:`token_nll`)."""
+    return token_nll(logits, targets).mean()
 
 
 def next_token_loss(params, tokens, config: LlamaConfig, mesh=None):
@@ -374,28 +410,10 @@ def forward_pp(
                 break
     x = params["tok_embed"][tokens]
 
-    def layer_fn(h, layer):
+    def stage_fn(layer_group, h):
         # positions from the *local* activation shape: inside the pipeline
         # body the batch dim is the per-(dp,fsdp)-rank shard, not B/M
-        positions = jnp.broadcast_to(
-            jnp.arange(h.shape[1])[None, :], h.shape[:2]
-        )
-        h = h + _attention(
-            _rms_norm(h, layer["attn_norm"], c.norm_eps),
-            layer, c, positions, None,
-        )
-        h = h + _mlp(_rms_norm(h, layer["ffn_norm"], c.norm_eps), layer)
-        return h, None
-
-    scan_fn = layer_fn
-    if c.remat:
-        scan_fn = jax.checkpoint(
-            layer_fn, prevent_cse=False, policy=_remat_policy(c),
-        )
-
-    def stage_fn(layer_group, h):
-        h, _ = jax.lax.scan(scan_fn, h, layer_group)
-        return h
+        return decoder_stack(h, layer_group, c, None, None)
 
     stages = stack_stages(params["layers"], S_pp)
     ym = pipeline_apply(
@@ -405,10 +423,7 @@ def forward_pp(
     )
     y = unmicrobatch(ym)
     y = _rms_norm(y, params["final_norm"], c.norm_eps)
-    return jnp.einsum(
-        "bsd,dv->bsv", y, params["lm_head"],
-        preferred_element_type=jnp.float32,
-    )
+    return lm_head(y, params["lm_head"])
 
 
 def next_token_loss_pp(params, tokens, config: LlamaConfig, mesh,

@@ -30,6 +30,10 @@ from dlrover_tpu.parallel.mesh import ElasticMeshManager, MeshPlan, plan_mesh
 class TrainStepResult(NamedTuple):  # NamedTuple ⇒ a pytree, jit can return it
     loss: Any
     grad_norm: Any
+    # what a loss_fn returned beside its loss, averaged over the step's
+    # microbatches; device arrays the step path never reads back. Empty
+    # for a scalar loss
+    stats: Any = {}
 
 
 def make_train_state(params, optimizer) -> Dict:
@@ -76,13 +80,17 @@ def make_train_state(params, optimizer) -> Dict:
 class ElasticTrainer:
     def __init__(
         self,
-        loss_fn: Callable,  # loss_fn(params, microbatch) -> scalar
+        # loss_fn(params, microbatch) -> scalar, or (scalar, stats dict);
+        # a dict ``span_attrs`` on it (models/looped.py: ``passes``) rides
+        # on every train.step span
+        loss_fn: Callable,
         optimizer,          # optax GradientTransformation
         global_batch_size: int,
         micro_batch_per_replica: int,
         mesh_manager: Optional[ElasticMeshManager] = None,
     ):
         self._loss_fn = loss_fn
+        self._span_attrs = dict(getattr(loss_fn, "span_attrs", {}))
         self._optimizer = optimizer
         self.global_batch_size = global_batch_size
         self.micro_batch_per_replica = micro_batch_per_replica
@@ -156,20 +164,26 @@ class ElasticTrainer:
             iterated sequentially, second axis sharded over data axes."""
             params = state["params"]
 
+            def loss_and_stats(p, microbatch):
+                out = loss_fn(p, microbatch)
+                return out if isinstance(out, tuple) else (out, {})
+
             def micro_step(carry, microbatch):
                 grad_acc, loss_acc = carry
-                loss, grads = jax.value_and_grad(loss_fn)(params, microbatch)
+                (loss, stats), grads = jax.value_and_grad(
+                    loss_and_stats, has_aux=True)(params, microbatch)
                 grads = jax.tree.map(
                     lambda a, g: a + g.astype(jnp.float32), grad_acc, grads
                 )
-                return (grads, loss_acc + loss), None
+                return (grads, loss_acc + loss), stats
 
             zeros = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params
             )
-            (grads, loss_sum), _ = jax.lax.scan(
+            (grads, loss_sum), stats = jax.lax.scan(
                 micro_step, (zeros, jnp.zeros((), jnp.float32)), batch
             )
+            stats = jax.tree.map(lambda s: s.mean(axis=0), stats)
             grads = jax.tree.map(lambda g: g / accum, grads)
             grad_norm = optax_global_norm(grads)
             updates, new_opt = optimizer.update(
@@ -184,7 +198,8 @@ class ElasticTrainer:
                 "opt_state": new_opt,
                 "step": state["step"] + 1,
             }
-            return new_state, TrainStepResult(loss_sum / accum, grad_norm)
+            return new_state, TrainStepResult(
+                loss_sum / accum, grad_norm, stats)
 
         return jax.jit(step_fn, donate_argnums=(0,))
 
@@ -217,6 +232,7 @@ class ElasticTrainer:
         traced = tracing.enabled()
         with tracing.span(
             SpanName.TRAIN_STEP, accum=self.grad_accum_steps,
+            **self._span_attrs,
         ) as sp:
             # structured compile signature: a varying rows-per-microbatch
             # is exactly the ragged-batch storm the watcher attributes

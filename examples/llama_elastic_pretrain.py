@@ -10,6 +10,9 @@ counterpart of the reference's examples/pytorch/ jobs):
 - Flash Checkpoint — async memory saves every step, storage every N
 - training-event span + per-step publishing (goodput accounting, hang
   detection feed)
+- `MODEL=looped` trains the weight-shared looped model (models/looped.py)
+  on the same path: the step's exit statistics come back with the loss
+  and are published as registry gauges
 
 Run (single host, 2 workers on CPU for a quick look):
 
@@ -33,7 +36,7 @@ import optax
 
 from dlrover_tpu import worker
 from dlrover_tpu.ckpt.checkpointer import Checkpointer, StorageType
-from dlrover_tpu.models import llama
+from dlrover_tpu.models import llama, looped
 from dlrover_tpu.parallel.mesh import build_mesh, plan_mesh
 from dlrover_tpu.parallel.sharding import global_batch_from_local, shard_tree
 from dlrover_tpu.trainer.data import ElasticDataLoader, ElasticDistributedSampler
@@ -42,6 +45,7 @@ from dlrover_tpu.trainer.elastic import ElasticTrainer, make_train_state
 TOTAL_STEPS = int(os.getenv("TRAIN_STEPS", "30"))
 GLOBAL_BATCH = int(os.getenv("GLOBAL_BATCH", "8"))
 SEQ_LEN = int(os.getenv("SEQ_LEN", "64"))
+MODEL = os.getenv("MODEL", "llama")  # or "looped"
 CKPT_EVERY = 10
 
 
@@ -53,21 +57,33 @@ def synthetic_dataset(vocab: int, n: int = 4096):
 def main() -> int:
     ctx = worker.init()
     n_devices = len(jax.devices())
-    config = llama.LlamaConfig(
+    if MODEL not in ("llama", "looped"):
+        raise SystemExit(f"unknown MODEL {MODEL!r}; llama or looped")
+    model = looped if MODEL == "looped" else llama
+    shapes = dict(
         vocab_size=2048, dim=128, n_layers=4, n_heads=4, n_kv_heads=2,
         ffn_dim=256, max_seq_len=SEQ_LEN, remat=True, dtype=jnp.float32,
     )
+    if MODEL == "looped":
+        config = looped.LoopedConfig(**shapes, n_passes=2)
+    else:
+        config = llama.LlamaConfig(**shapes)
 
     # mesh from the live world: model axes fixed, fsdp absorbs the rest
     plan = plan_mesh(n_devices, tp=1, sp=1)
     mesh = build_mesh(plan)
     params = shard_tree(
-        mesh, llama.init_params(config, jax.random.PRNGKey(0)),
-        llama.param_logical_axes(config),
+        mesh, model.init_params(config, jax.random.PRNGKey(0)),
+        model.param_logical_axes(config),
     )
+    if MODEL == "looped":
+        loss_fn = looped.make_loss_fn(config, mesh, with_stats=True)
+    else:
+        def loss_fn(p, t):
+            return llama.next_token_loss(p, t, config, mesh)
 
     trainer = ElasticTrainer(
-        loss_fn=lambda p, t: llama.next_token_loss(p, t, config, mesh),
+        loss_fn=loss_fn,
         optimizer=optax.adamw(3e-4),
         global_batch_size=GLOBAL_BATCH,
         micro_batch_per_replica=max(1, GLOBAL_BATCH // (2 * plan.dp_total)),
@@ -127,8 +143,14 @@ def main() -> int:
                 # reaches the master via the agent's SharedDict forward
                 ctx.report_step(step)
                 if step % 10 == 0:
-                    print(f"step {step}: loss {float(result.loss):.4f}",
-                          flush=True)
+                    # one read-back for the loss and whatever came with it
+                    loss, stats = jax.device_get((result.loss, result.stats))
+                    print(f"step {step}: loss {float(loss):.4f}", flush=True)
+                    if stats:
+                        looped.publish_stats(stats)
+                        print(f"step {step}: exit mass "
+                              f"{np.round(stats['exit_mass'], 3).tolist()}",
+                              flush=True)
     if ctx.is_leader:
         print(f"DONE at step {step}", flush=True)
     return 0
