@@ -12,9 +12,13 @@ full_ckpt_engine.py:33. TPU-native redesign:
   (megatron_engine.py:71 saving-ranks logic), while TP/FSDP/PP/SP/EP shards
   land with their global start indices so storage restore can reassemble
   under a different topology;
-- device→host copies are started async for all shards first
-  (``copy_to_host_async``), then drained into shm — the blocking time is one
-  HBM→host DMA of the state, not a serialize.
+- the save's pause makes a private copy of every owned shard on its
+  device, shards over 64 MiB as row blocks, by one program a device; a
+  background thread then fetches the blocks through a window of two
+  chunks (``copy_to_host_async``, the next issued as one lands) and writes
+  each into the shm frame as it lands — the blocking time is one dispatch,
+  and the device-to-host link never holds more than the window, so the
+  training loop's own read-backs do not queue behind the state.
 
 Step-consistency across hosts on restore from shm uses the master KV store
 (each host publishes its shm step; restore falls back to storage when hosts
@@ -22,6 +26,7 @@ disagree) — the reference does the same with a gloo allgather
 (engine.py:375).
 """
 
+import collections
 import contextlib
 import functools
 import os
@@ -188,11 +193,15 @@ class CheckpointEngine:
         _reg = get_registry()
         self._save_block_hist = _reg.histogram(
             "dlrover_ckpt_save_block_seconds",
-            "Training pause per save (plan + D2H dispatch)",
+            "Training pause per save (plan + on-device snapshot dispatch)",
         )
         self._drain_hist = _reg.histogram(
             "dlrover_ckpt_drain_seconds",
             "Background shm drain duration per snapshot",
+        )
+        self._drain_blocks = _reg.counter(
+            "dlrover_ckpt_drain_blocks_total",
+            "Pieces of snapshots fetched through the device-to-host window",
         )
         self._restore_hist = _reg.histogram(
             "dlrover_ckpt_restore_seconds",
@@ -262,12 +271,13 @@ class CheckpointEngine:
         engine.py:340 skips rather than blocks).
 
         TPU-first async split: the *training pause* is only the planning
-        pass + ``copy_to_host_async`` dispatch (device DMA engines run the
-        D2H alongside the next step's compute); a background thread drains
-        the transfers into the shm frame and publishes the snapshot. jax
-        arrays are immutable, so the captured ``state`` stays valid while
-        training races ahead — the cost is those buffers staying alive in
-        HBM until the drain finishes. ``blocking=True`` restores the
+        pass, which dispatches the on-device snapshot (``_plan_state``) and
+        no device-to-host copy; a background thread fetches the snapshot
+        block by block through a bounded window (device DMA engines run
+        the D2H alongside the next step's compute), writes each block into
+        the shm frame as it lands and publishes the snapshot
+        (``_drain_frame``). The cost is the snapshot's blocks staying alive
+        in HBM until each is written. ``blocking=True`` restores the
         synchronous reference behavior (used by breakpoint saves where the
         process is about to exit)."""
         with tracing.span(SpanName.CKPT_SAVE_READY, source=self._source):
@@ -320,9 +330,9 @@ class CheckpointEngine:
                 with tracing.activate(drain_parent), tracing.span(
                     SpanName.CKPT_DRAIN, source=self._source,
                     step=step, bytes=nbytes,
-                ):
+                ) as sp:
                     self._drain_frame(step, meta, pending, nbytes,
-                                      _on_drained)
+                                      _on_drained, sp)
             except Exception:  # noqa: BLE001 — a lost snapshot must be LOUD
                 self._drain_ok = False
                 logger.error(
@@ -347,16 +357,11 @@ class CheckpointEngine:
         return True
 
     def _drain_frame(self, step, meta, pending, nbytes,
-                     _on_drained) -> None:
+                     _on_drained, drain_span) -> None:
         drain_t0 = time.monotonic()
-        with tracing.span(
-            SpanName.CKPT_DRAIN_D2H_WAIT, source=self._source,
-        ):
-            buffers = [np.asarray(data) for _, data in pending]
-        with tracing.span(
-            SpanName.CKPT_DRAIN_SHM_WRITE, source=self._source,
-        ) as sp:
-            sp.attrs.update(self._shm.write_frame(meta, buffers))
+        fetched = self._fetch_and_write(meta, pending)
+        drain_span.attrs.update(fetched)
+        self._drain_blocks.inc(fetched["blocks"])
         drain_s = time.monotonic() - drain_t0
         self._drain_hist.observe(drain_s)
         if drain_s > 0:
@@ -369,6 +374,85 @@ class CheckpointEngine:
             self._publish_frame(step)
         if _on_drained is not None:
             _on_drained()
+
+    def _fetch_and_write(self, meta, pending) -> Dict[str, Any]:
+        """The snapshot's way to the frame: its pieces (``_plan_state``)
+        are fetched in frame order with at most ``_D2H_WINDOW_BYTES`` of
+        them issued and not landed — what a read-back of the training loop
+        can find ahead of it on the link — and each is written into the
+        frame and let go, its device buffer with it, as it lands; the
+        checksums of one run while the next is on the link. Empties
+        ``pending``. ``ckpt.drain.d2h_wait`` is open from the first piece
+        issued to the last landed, ``ckpt.drain.shm_write`` from the first
+        written to the seal. Returns the ``ckpt.drain`` span's account of
+        it: ``blocks`` (pieces fetched), ``split_leaves`` (shards that came
+        in several), ``inflight_peak_bytes`` and ``blocked_s`` (what this
+        thread waited for landings)."""
+        here = tracing.current_context()
+        phase = functools.partial(
+            tracing.span, source=self._source, parent=here)
+        sizes = [shard["nbytes"] for shard, _ in pending]
+        waiting = collections.deque(
+            (n, *piece) for n, (_, pieces) in enumerate(pending)
+            for piece in pieces
+        )
+        stats = {
+            "blocks": len(waiting),
+            "split_leaves": sum(len(pieces) > 1 for _, pieces in pending),
+            "inflight_peak_bytes": 0,
+            "blocked_s": 0.0,
+        }
+        pending.clear()
+        issued: collections.deque = collections.deque()
+        inflight = 0
+
+        def link_bytes(data) -> int:  # host leaves are there already
+            return data.nbytes if hasattr(data, "copy_to_host_async") else 0
+
+        def issue():
+            # a piece larger than the window travels alone
+            nonlocal inflight
+            while waiting:
+                data = waiting[0][2]
+                if inflight and (
+                        inflight + link_bytes(data) > _D2H_WINDOW_BYTES):
+                    break
+                if link_bytes(data):
+                    data.copy_to_host_async()
+                    inflight += data.nbytes
+                issued.append(waiting.popleft())
+            stats["inflight_peak_bytes"] = max(
+                stats["inflight_peak_bytes"], inflight)
+
+        # the two phases overlap, so neither can hand the thread's trace
+        # context back on leaving: ``activate`` does
+        with tracing.activate(here), contextlib.ExitStack() as phases:
+            d2h = phases.enter_context(phase(SpanName.CKPT_DRAIN_D2H_WAIT))
+            issue()
+            frame = self._shm.open_frame(meta, sizes)
+            write = None
+            while issued:
+                shard, offset, data, skip = issued.popleft()
+                t = time.monotonic()
+                host = np.asarray(data)
+                stats["blocked_s"] += time.monotonic() - t
+                inflight -= link_bytes(data)
+                issue()
+                if not issued:  # the last has landed; its write is to come
+                    d2h.__exit__(None, None, None)
+                if write is None:
+                    write = phases.enter_context(
+                        phase(SpanName.CKPT_DRAIN_SHM_WRITE))
+                frame.write(
+                    shard, offset,
+                    host.reshape(-1).view(np.uint8)[skip:] if skip else host,
+                )
+                del host, data
+            if write is None:  # a state without arrays
+                write = phases.enter_context(
+                    phase(SpanName.CKPT_DRAIN_SHM_WRITE))
+            write.attrs.update(frame.seal())
+        return stats
 
     def _publish_frame(self, step: int) -> None:
         """Tell whoever reads this frame that it holds ``step``: the
@@ -622,66 +706,48 @@ class CheckpointEngine:
         logger.info("restored shard-ledger data state from step %s", step)
 
     def _plan_state(self, step: int, state) -> Tuple[Dict, List]:
-        """Planning pass: build frame metadata and dispatch async work for
-        every owned shard. Returns (meta, pending) — no blocking work.
+        """Planning pass: build frame metadata and snapshot every owned
+        shard. Returns (meta, pending) — no blocking work, and no
+        device-to-host copy issued: that is the drain's, piece by piece
+        (``_fetch_and_write``). ``pending`` holds, in frame order, one
+        ``(shard meta, pieces)`` a shard, a piece being ``(its byte offset
+        in the shard, array, leading bytes of the array to leave out)``.
 
         Donation safety: the standard train step donates its state
         (trainer/elastic.py jit donate_argnums), which DELETES the old
         device buffers when the next step dispatches — while our drain
         thread may still be reading them. So by default each shard is
-        snapshotted on-device first (``jnp.copy``, an async HBM→HBM DMA
+        snapshotted on-device first (``_snapshot``: one program a device,
         enqueued before the next step's execution, so it reads the
         pre-donation bytes) and the drain reads the private copy. Costs one
-        transient state copy in HBM until the drain frees it; disable via
-        DLROVER_TPU_CKPT_DEVICE_SNAPSHOT=0 when the training loop is known
-        not to donate."""
-        import jax
-        import jax.numpy as jnp
-
+        transient state copy in HBM, given back block by block as the
+        drain writes them; disable via DLROVER_TPU_CKPT_DEVICE_SNAPSHOT=0
+        when the training loop is known not to donate: shards then travel
+        whole."""
         named, _ = _tree_flatten_with_names(state)
         leaves_meta: List[Dict] = []
         offset = 0
-        pending: List[Tuple[Dict, Any]] = []
+        pending: List[Tuple[Dict, List]] = []
+        # device -> (place in pending, shard) of what it holds
+        on_device: Dict[Any, List[Tuple[int, Any]]] = {}
         for path, leaf in named:
             if _is_jax_array(leaf):
-                shards = [
-                    s for s in leaf.addressable_shards if s.replica_id == 0
-                ]
-                if not shards:
-                    # purely-replicated copy owned by another host
-                    leaves_meta.append({
-                        "path": path, "kind": "array",
-                        "dtype": str(leaf.dtype),
-                        "gshape": list(leaf.shape),
-                        "shards": [],
-                    })
-                    continue
-                datas = []
-                for s in shards:
-                    data = s.data
-                    if self._device_snapshot:
-                        data = jnp.copy(data)
-                    # start async D2H for overlap; drained later
-                    try:
-                        data.copy_to_host_async()
-                    except Exception:  # noqa: BLE001,DLR003 — CPU backend no-op
-                        pass
-                    datas.append(data)
                 shard_metas = []
-                for s, data in zip(shards, datas):
-                    start = [
-                        (sl.start or 0) for sl in s.index
-                    ] if s.index else [0] * leaf.ndim
-                    pending.append((
-                        {
-                            "offset": offset,
-                            "nbytes": int(data.nbytes),
-                            "lshape": list(data.shape),
-                            "start": start,
-                        },
-                        data,
-                    ))
-                    shard_metas.append(pending[-1][0])
+                for s in leaf.addressable_shards:
+                    if s.replica_id != 0:
+                        continue  # another device's, or another host's
+                    data = s.data
+                    shard_metas.append({
+                        "offset": offset,
+                        "nbytes": int(data.nbytes),
+                        "lshape": list(data.shape),
+                        "start": [
+                            (sl.start or 0) for sl in s.index
+                        ] if s.index else [0] * leaf.ndim,
+                    })
+                    on_device.setdefault(s.device, []).append(
+                        (len(pending), data))
+                    pending.append((shard_metas[-1], [(0, data, 0)]))
                     offset += int(data.nbytes)
                 leaves_meta.append({
                     "path": path, "kind": "array",
@@ -697,7 +763,7 @@ class CheckpointEngine:
                         "lshape": list(leaf.shape),
                         "start": [0] * leaf.ndim,
                     },
-                    leaf,
+                    [(0, leaf, 0)],
                 ))
                 leaves_meta.append({
                     "path": path, "kind": "array",
@@ -712,6 +778,11 @@ class CheckpointEngine:
                 leaves_meta.append({
                     "path": path, "kind": "value", "value": leaf,
                 })
+        if self._device_snapshot:
+            for held in on_device.values():
+                places, shards = zip(*held)
+                for n, pieces in zip(places, _snapshot(shards)):
+                    pending[n] = (pending[n][0], pieces)
         meta = {
             "step": step,
             "ts": time.time(),
@@ -1231,6 +1302,12 @@ _PACK_MAX_BYTES = 4 << 20
 # chunk deep, at a fixed cost a put that 8 MB still dwarf
 _PACK_BATCH_BYTES = 8 << 20
 _PACK_CHUNK_BYTES = 64 << 20
+# what a save's drain keeps on the device-to-host link at a time: two
+# chunks, one landing while the next is queued behind it. With the whole
+# snapshot queued at once a scalar's read-back took up to 675 ms (1.2
+# without): longer than the step the loop has in flight, so the chip ran
+# dry (PERF.md section 6, PR 24)
+_D2H_WINDOW_BYTES = 2 * _PACK_CHUNK_BYTES
 # the staging ring. Its fresh pages are what a restore still pays for
 # host memory (64 MiB: 0.07 s alone, 0.17-0.35 s with eight threads at
 # it), and four chunks in flight already fill the link: restores with
@@ -1439,6 +1516,62 @@ def _row_blocks(shape, itemsize: int):
                 fresh * inner,
             ))
     return block_shape, tuple(blocks)
+
+
+def _snapshot(shards) -> List[List[Tuple[int, Any, int]]]:
+    """Private copies of one device's ``shards`` (single-device arrays),
+    made on the device by one program, dispatched here and nothing waited
+    for: for each shard its pieces, as ``_plan_state`` hands them to the
+    drain. A shard of more than ``_PACK_CHUNK_BYTES`` is copied as the
+    row blocks the restore would cut it into (``_row_blocks``: one shape,
+    so the last of a run brings some rows twice, and the drain leaves
+    them out), each a transfer of its own; a smaller one whole."""
+    cuts = [
+        _row_blocks(tuple(x.shape), x.dtype.itemsize)
+        if x.nbytes > _PACK_CHUNK_BYTES else None
+        for x in shards
+    ]
+    copies = _snapshot_program(tuple(
+        cut and (cut[0], tuple(start for _, start, _ in cut[1]))
+        for cut in cuts
+    ))(*shards)
+    out = []
+    for cut, copy in zip(cuts, copies):
+        if cut is None:
+            out.append([(0, copy, 0)])
+            continue
+        block_bytes = copy[0].nbytes
+        out.append([
+            (offset + block_bytes - fresh, block, block_bytes - fresh)
+            for (offset, _, fresh), block in zip(cut[1], copy)
+        ])
+    return out
+
+
+@functools.lru_cache(maxsize=64)
+def _snapshot_program(cuts):
+    """The program behind ``_snapshot``: an entry of ``cuts`` an operand,
+    ``None`` for a copy of the whole, else ``(block shape, the blocks'
+    start indices)``. Every output is a buffer of its own (``jnp.copy``
+    and ``lax.slice`` are operations; an operand handed through would be
+    the caller's buffer, which the next step's donation deletes). One
+    request to the compiler a device and state, in the first save; jit
+    keeps the executable by the operands' shapes and device.
+    Module-level lru_cache: later saves of the process share it."""
+    import jax
+    import jax.numpy as jnp
+
+    def snapshot(*shards):
+        return [
+            jnp.copy(x) if cut is None else [
+                jax.lax.slice(
+                    x, start, [a + b for a, b in zip(start, cut[0])])
+                for start in cut[1]
+            ]
+            for x, cut in zip(shards, cuts)
+        ]
+
+    return jax.jit(snapshot)
 
 
 @functools.lru_cache(maxsize=64)

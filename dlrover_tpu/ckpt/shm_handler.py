@@ -244,42 +244,47 @@ class SharedMemoryHandler:
         the order/sizes of ``buffers``. Returns where the data pass spent
         its time: ``copy_s`` (buffers into the segment) and ``checksum_s``
         (CRC32 + Adler32 over them), each summed over the buffers."""
+        frame = self.open_frame(meta, [int(b.nbytes) for b in buffers])
+        for shard, b in enumerate(buffers):
+            frame.write(shard, 0, b)
+        return frame.seal()
+
+    def open_frame(self, meta: Dict, sizes: List[int]) -> "FrameWriter":
+        """Start a frame of ``meta`` and data shards of ``sizes`` bytes, in
+        the order of the offsets in ``meta['leaves']``: the header is sized
+        and the frame in the segment invalidated; the shards' bytes then
+        come piece by piece through the returned writer, whose ``seal``
+        publishes the frame."""
         compute_crc = _crc_enabled()
+        starts, rel = [], 0
+        for n in sizes:
+            starts.append(rel)
+            rel += n
         if compute_crc:
             # reserve fixed-width CRC slots for every shard that maps onto
             # a buffer BEFORE sizing the header: real CRCs are stamped
             # after the data pass, and a 4-byte bin always packs to the
             # same length, so the header size (and thus every abs_offset)
             # stays stable across the re-pack
-            rel, expected = 0, {}
-            for b in buffers:
-                expected[rel] = int(b.nbytes)
-                rel += int(b.nbytes)
+            expected = dict(zip(starts, sizes))
             for leaf in meta["leaves"]:
                 for shard in leaf.get("shards", []):
                     if expected.get(shard["offset"]) == shard["nbytes"]:
                         shard["crc"] = b"\x00\x00\x00\x00"
                         shard["dig"] = b"\x00" * 8
+        # offsets in meta are relative to data_start; rewrite the header
+        # with absolute offsets until its own length (abs_offset adds
+        # bytes) no longer moves them
+        data_start = None
         header = pack_frame(meta)
-        data_start = len(header)
-        total = data_start + sum(int(b.nbytes) for b in buffers)
-        # offsets in meta are relative to data_start; rewrite header with
-        # absolute offsets now that we know data_start
-        for leaf in meta["leaves"]:
-            for shard in leaf.get("shards", []):
-                shard["abs_offset"] = data_start + shard["offset"]
-        header = pack_frame(meta)
-        # repacking can change len(header) (abs_offset adds bytes) — fix up
         while len(header) != data_start:
             data_start = len(header)
             for leaf in meta["leaves"]:
                 for shard in leaf.get("shards", []):
                     shard["abs_offset"] = data_start + shard["offset"]
             header = pack_frame(meta)
-        total = data_start + sum(int(b.nbytes) for b in buffers)
-        if not self._ensure(total):
+        if not self._ensure(data_start + rel):
             raise RuntimeError(f"cannot create shm segment {self._name}")
-        buf = self._shm.buf
         # crash-consistent write order: invalidate the frame (zero length
         # word), write tensor data, write the meta bytes, then seal by
         # writing the length word LAST. A writer killed at any point leaves
@@ -288,46 +293,11 @@ class SharedMemoryHandler:
         # data. This is what makes it safe for the agent to SIGKILL a
         # wedged worker without a long graceful-exit grace. The length
         # word is the frame's COMMIT MARKER; the per-shard CRCs stamped
-        # below cover what the marker can't: corruption that happens
+        # at the seal cover what the marker can't: corruption that happens
         # *after* a clean seal (bit rot, a stray writer) or a torn
         # replica/storage copy of a sealed frame.
-        buf[:8] = _U64.pack(0)
-        pos = data_start
-        crcs: Dict[int, int] = {}
-        digs: Dict[int, bytes] = {}
-        copy_s = checksum_s = 0.0
-        t_checked = time.monotonic()
-        for b in buffers:
-            flat = np.ascontiguousarray(b).view(np.uint8).reshape(-1)
-            n = flat.nbytes
-            buf[pos : pos + n] = flat.data
-            t_copied = time.monotonic()
-            copy_s += t_copied - t_checked
-            if compute_crc:
-                rel = pos - data_start
-                crcs[rel] = zlib.crc32(flat.data) & 0xFFFFFFFF
-                digs[rel] = _DIG.pack(
-                    crcs[rel], zlib.adler32(flat.data) & 0xFFFFFFFF
-                )
-            pos += n
-            t_checked = time.monotonic()
-            checksum_s += t_checked - t_copied
-        if compute_crc:
-            for leaf in meta["leaves"]:
-                for shard in leaf.get("shards", []):
-                    crc = crcs.get(shard["offset"])
-                    if crc is not None and "crc" in shard:
-                        shard["crc"] = _CRC.pack(crc)
-                    dig = digs.get(shard["offset"])
-                    if dig is not None and "dig" in shard:
-                        shard["dig"] = dig
-            sealed = pack_frame(meta)
-            assert len(sealed) == len(header), "CRC stamp changed header size"
-            header = sealed
-        buf[8 : len(header)] = header[8:]
-        buf[:8] = header[:8]
-        self._maybe_inject_corruption(meta, data_start)
-        return {"copy_s": copy_s, "checksum_s": checksum_s}
+        self._shm.buf[:8] = _U64.pack(0)
+        return FrameWriter(self, meta, header, starts, sizes, compute_crc)
 
     def _maybe_inject_corruption(self, meta: Dict, data_start: int) -> None:
         """``shm.write`` injection site: mutate the sealed frame's data the
@@ -499,6 +469,85 @@ class SharedMemoryHandler:
             return buf[off : off + n]
 
         return _verify_shards(meta, _view)
+
+
+class FrameWriter:
+    """The data pass and the seal of one frame
+    (``SharedMemoryHandler.open_frame``). A shard's bytes arrive in one
+    piece or in several, each shard's in order, and its CRC32 and Adler32
+    are carried from piece to piece: the sealed frame is the same, byte
+    for byte, whatever the pieces were."""
+
+    def __init__(self, handler: SharedMemoryHandler, meta: Dict,
+                 header: bytes, starts: List[int], sizes: List[int],
+                 compute_crc: bool):
+        self._handler = handler
+        self._meta = meta
+        self._header = header
+        self._starts = starts
+        self._sizes = sizes
+        self._compute_crc = compute_crc
+        self._written = [0] * len(sizes)
+        self._crc = [zlib.crc32(b"")] * len(sizes)
+        self._adler = [zlib.adler32(b"")] * len(sizes)
+        self._copy_s = self._checksum_s = 0.0
+
+    def write(self, shard: int, offset: int, data) -> None:
+        """The next bytes of ``shard``: they start at ``offset`` in it,
+        which is where its last piece ended."""
+        t_start = time.monotonic()
+        flat = np.ascontiguousarray(data).view(np.uint8).reshape(-1)
+        n = flat.nbytes
+        if (offset != self._written[shard]
+                or offset + n > self._sizes[shard]):
+            raise ValueError(
+                f"shard {shard}: a piece of {n} bytes at {offset}, after "
+                f"{self._written[shard]} of {self._sizes[shard]} bytes")
+        if n:
+            # ``copyto`` lets other threads run while it copies; a slice
+            # assignment to the memoryview holds the interpreter lock for
+            # all of it (PERF.md section 6, PR 24)
+            np.copyto(np.frombuffer(
+                self._handler._shm.buf, np.uint8, n,
+                len(self._header) + self._starts[shard] + offset), flat)
+        t_copied = time.monotonic()
+        if self._compute_crc:
+            self._crc[shard] = zlib.crc32(flat, self._crc[shard])
+            self._adler[shard] = zlib.adler32(flat, self._adler[shard])
+        self._written[shard] = offset + n
+        self._copy_s += t_copied - t_start
+        self._checksum_s += time.monotonic() - t_copied
+
+    def seal(self) -> Dict[str, float]:
+        """Stamp the checksums, write the header and, last, the length
+        word. Returns ``copy_s`` and ``checksum_s`` summed over the
+        pieces."""
+        if self._written != self._sizes:
+            raise ValueError(
+                f"frame sealed with {sum(self._written)} of "
+                f"{sum(self._sizes)} data bytes written")
+        meta, header = self._meta, self._header
+        if self._compute_crc:
+            crcs = {
+                rel: (crc & 0xFFFFFFFF, adler & 0xFFFFFFFF)
+                for rel, crc, adler in zip(
+                    self._starts, self._crc, self._adler)
+            }
+            for leaf in meta["leaves"]:
+                for shard in leaf.get("shards", []):
+                    stamp = crcs.get(shard["offset"])
+                    if stamp is not None and "crc" in shard:
+                        shard["crc"] = _CRC.pack(stamp[0])
+                    if stamp is not None and "dig" in shard:
+                        shard["dig"] = _DIG.pack(*stamp)
+            sealed = pack_frame(meta)
+            assert len(sealed) == len(header), "CRC stamp changed header size"
+            header = sealed
+        buf = self._handler._shm.buf
+        buf[8 : len(header)] = header[8:]
+        buf[:8] = header[:8]
+        self._handler._maybe_inject_corruption(meta, len(header))
+        return {"copy_s": self._copy_s, "checksum_s": self._checksum_s}
 
 
 def parse_frame(blob: bytes) -> Optional[Dict]:

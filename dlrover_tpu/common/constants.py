@@ -425,9 +425,11 @@ class SpanName:
     # the same three from inside (ckpt/engine.py), one span a phase and
     # none a leaf on the save's blocking path. Save block: readiness
     # (drain-alive check, lock, peer exchange), the planning pass
-    # (flatten, on-device copy and D2H dispatch a shard), the meta-dict
-    # write. Drain thread: waiting for the D2H copies, the frame write
-    # (copy + checksums, split by its ``copy_s``/``checksum_s`` attrs),
+    # (flatten, one on-device snapshot program a device), the meta-dict
+    # write. Drain thread: the snapshot on the device-to-host link (first
+    # block issued to last landed), the frame write (first piece to the
+    # seal: copy + checksums, split by its ``copy_s``/``checksum_s``
+    # attrs) -- the two overlap, both children of ``ckpt.drain`` -- then
     # the hand-off to replicas, agent and master.
     CKPT_SAVE_READY = "ckpt.save.ready"
     CKPT_SAVE_PLAN = "ckpt.save.plan"

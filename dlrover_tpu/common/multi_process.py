@@ -526,6 +526,13 @@ def create_shared_memory(
         shm = shared_memory.SharedMemory(name=name, create=create, size=size)
     except FileNotFoundError:
         return None
+    except ValueError:
+        # "cannot mmap an empty file": opened between the creator's
+        # shm_open and its ftruncate. For a reader the segment is not
+        # there yet (the agent's saver then waits on the frame's lock)
+        if create:
+            raise
+        return None
     except FileExistsError:
         shm = shared_memory.SharedMemory(name=name, create=False)
         if size and shm.size < size:
