@@ -228,11 +228,6 @@ class CheckpointEngine:
             "Live-reshard attempts that fell to the next rung, by reason",
             labelnames=("reason",),
         )
-        # donation safety (see _plan_state): snapshot shards on-device
-        # before the async drain unless explicitly disabled
-        self._device_snapshot = env_flag(
-            ConfigKey.CKPT_DEVICE_SNAPSHOT, default=True
-        )
 
     def _replica_manager_from_env(self):
         """Workers under an agent with ``--ckpt-replica`` build their push
@@ -716,19 +711,17 @@ class CheckpointEngine:
         Donation safety: the standard train step donates its state
         (trainer/elastic.py jit donate_argnums), which DELETES the old
         device buffers when the next step dispatches — while our drain
-        thread may still be reading them. So by default each shard is
-        snapshotted on-device first (``_snapshot``: one program a device,
-        enqueued before the next step's execution, so it reads the
-        pre-donation bytes) and the drain reads the private copy. Costs one
-        transient state copy in HBM, given back block by block as the
-        drain writes them; disable via DLROVER_TPU_CKPT_DEVICE_SNAPSHOT=0
-        when the training loop is known not to donate: shards then travel
-        whole."""
+        thread may still be reading them. So each shard is snapshotted
+        on-device first (``_snapshot``: one program a device, enqueued
+        before the next step's execution, so it reads the pre-donation
+        bytes) and the drain reads the private copy. Costs one transient
+        state copy in HBM, given back block by block as the drain writes
+        them."""
         named, _ = _tree_flatten_with_names(state)
         leaves_meta: List[Dict] = []
         offset = 0
         pending: List[Tuple[Dict, List]] = []
-        # device -> (place in pending, shard) of what it holds
+        # device -> (place in pending, shard): the snapshot makes the pieces
         on_device: Dict[Any, List[Tuple[int, Any]]] = {}
         for path, leaf in named:
             if _is_jax_array(leaf):
@@ -747,7 +740,7 @@ class CheckpointEngine:
                     })
                     on_device.setdefault(s.device, []).append(
                         (len(pending), data))
-                    pending.append((shard_metas[-1], [(0, data, 0)]))
+                    pending.append((shard_metas[-1], None))
                     offset += int(data.nbytes)
                 leaves_meta.append({
                     "path": path, "kind": "array",
@@ -778,11 +771,10 @@ class CheckpointEngine:
                 leaves_meta.append({
                     "path": path, "kind": "value", "value": leaf,
                 })
-        if self._device_snapshot:
-            for held in on_device.values():
-                places, shards = zip(*held)
-                for n, pieces in zip(places, _snapshot(shards)):
-                    pending[n] = (pending[n][0], pieces)
+        for held in on_device.values():
+            places, shards = zip(*held)
+            for n, pieces in zip(places, _snapshot(shards)):
+                pending[n] = (pending[n][0], pieces)
         meta = {
             "step": step,
             "ts": time.time(),
@@ -855,16 +847,9 @@ class CheckpointEngine:
         except (ConnectionError, ValueError):
             return step
 
-    def load(self, target, path: str = "",
-             in_place: bool = False) -> Tuple[Any, int]:
+    def load(self, target, path: str = "") -> Tuple[Any, int]:
         """Restore into the structure of ``target`` (a pytree whose array
         leaves are jax.Arrays or ShapeDtypeStructs carrying shardings).
-
-        ``in_place=True`` fills writable numpy target leaves directly
-        (torch ``load_state_dict`` semantics) instead of materializing
-        fresh buffers — the fast path for host-resident states, where
-        fresh-page population, not the copy, is the bound. jax leaves are
-        immutable and unaffected.
 
         Returns (state, step); step == -1 when nothing was restored.
         """
@@ -901,7 +886,7 @@ class CheckpointEngine:
                 step = self._shm_step_consistent(local_step)
             if step is not None and step >= 0:
                 with rung(SpanName.CKPT_RESTORE_SHM):
-                    state = self._load_from_shm(target, in_place=in_place)
+                    state = self._load_from_shm(target)
                 if state is not None:
                     logger.info(
                         "restored step %s from shared memory", step
@@ -1004,7 +989,7 @@ class CheckpointEngine:
             {"medium": source, "step": step, "duration_s": elapsed},
         )
 
-    def _load_from_shm(self, target, in_place: bool = False):
+    def _load_from_shm(self, target):
         meta = self._shm.read_meta()
         if meta is None:
             return None
@@ -1017,8 +1002,7 @@ class CheckpointEngine:
             return self._shm.read_shard_into(shard_meta, out, offset)
 
         try:
-            return _assemble(target, lookup, reader, reader_into=reader_into,
-                             in_place=in_place)
+            return _assemble(target, lookup, reader, reader_into=reader_into)
         except (KeyError, ValueError) as e:
             logger.warning("shm restore incomplete (%s) — trying storage", e)
             return None
@@ -1786,8 +1770,7 @@ def _unpack_program(layout):
     return jax.jit(unpack)
 
 
-def _assemble(target, lookup: Dict[str, Dict], reader, reader_into=None,
-              in_place: bool = False):
+def _assemble(target, lookup: Dict[str, Dict], reader, reader_into=None):
     """Rebuild a pytree like ``target`` from saved leaf metas + a byte
     reader. Handles re-sharding: each needed addressable shard is cut from
     whichever saved shards cover its global index range.
@@ -1803,24 +1786,12 @@ def _assemble(target, lookup: Dict[str, Dict], reader, reader_into=None,
     (optional) fills a writable buffer with the shard's bytes from
     ``offset`` on: a region that is exactly one saved shard is then read
     range by range straight into staging and never into a buffer of its
-    own.
-
-    ``in_place`` (needs ``reader_into``): numpy target leaves that
-    exactly match a single saved shard are filled where they sit.
-    In-place fills mutate the caller's buffers as they land, so the
-    frame is validated against the target UP FRONT: every target path
-    must exist, every numpy array leaf must match the frame's dtype and
-    global shape, and every array leaf's saved shards must cover its full
-    global region — a structurally-mismatched or incomplete frame fails
-    before any byte is written. (A mid-read I/O failure can still leave a
-    partial fill; in-place callers own that trade.)"""
+    own."""
     import jax
 
     from dlrover_tpu.observability import compile_watch
 
     named, treedef = _tree_flatten_with_names(target)
-    if in_place:
-        _validate_frame_against_target(named, lookup)
     compiles = compile_watch.get_watcher()
     asked = compiles.compile_requests()
     with _RestorePool(_RESTORE_THREADS) as pool, _RestorePool(
@@ -1841,32 +1812,6 @@ def _assemble(target, lookup: Dict[str, Dict], reader, reader_into=None,
                     pool, gshape, dtype, leaf.sharding, leaf_meta, reader,
                     reader_into, stager,
                 ))
-                continue
-            saved = leaf_meta["shards"]
-            if (
-                in_place
-                and isinstance(leaf, np.ndarray)
-                and leaf.flags.writeable
-                and leaf.flags["C_CONTIGUOUS"]
-                and leaf.dtype == dtype
-                and leaf.shape == gshape
-                and len(saved) == 1
-                and list(saved[0]["start"]) == [0] * len(gshape)
-                and tuple(saved[0]["lshape"]) == gshape
-            ):
-                # in-place fast path: one saved shard covers the whole
-                # target leaf — fill it where it sits
-                def fill(out=leaf, lm=leaf_meta, sm=saved[0]):
-                    with tracing.span(SpanName.CKPT_RESTORE_READ,
-                                      bytes=sm["nbytes"], staged=False):
-                        ok = reader_into(lm, sm, out)
-                    if not ok:
-                        raise ValueError(f"in-place read failed for "
-                                         f"{lm['path']}")
-                    return out
-
-                fut = pool.submit(fill)
-                finalizers.append(fut.result)
                 continue
             # plain numpy target: reassemble the full global array
             read_region = _make_region_reader(
@@ -1890,48 +1835,6 @@ def _assemble(target, lookup: Dict[str, Dict], reader, reader_into=None,
     tracing.add_span_event(
         "assembled", compile_requests=compiles.compile_requests() - asked)
     return jax.tree_util.tree_unflatten(treedef, out_leaves)
-
-
-def _validate_frame_against_target(named, lookup) -> None:
-    """Up-front structural validation for in-place restores: missing
-    paths, numpy dtype/global-shape mismatches, and incomplete shard
-    coverage all raise BEFORE any target buffer is mutated, so a bad
-    frame falls through to the storage path with the caller's state
-    untouched. Coverage is checked by clipped-shard volume, which cannot
-    over-count disjoint shards (the save planner never overlaps shards);
-    the per-region check in ``_make_region_reader`` stays as the byte-
-    accurate backstop."""
-    for path, leaf in named:
-        leaf_meta = lookup.get(path)
-        if leaf_meta is None:
-            raise KeyError(path)
-        if leaf_meta["kind"] == "value":
-            continue
-        dtype = _np_dtype(leaf_meta["dtype"])
-        gshape = tuple(leaf_meta["gshape"])
-        if isinstance(leaf, np.ndarray):
-            if leaf.dtype != dtype:
-                raise ValueError(
-                    f"{path}: frame dtype {dtype} != target {leaf.dtype}"
-                )
-            if leaf.shape != gshape:
-                raise ValueError(
-                    f"{path}: frame gshape {gshape} != target {leaf.shape}"
-                )
-        total = int(np.prod(gshape)) if gshape else 1
-        covered = 0
-        for shard_meta in leaf_meta["shards"]:
-            vol = 1
-            for start, length, g in zip(
-                shard_meta["start"], shard_meta["lshape"], gshape
-            ):
-                vol *= max(0, min(start + length, g) - max(start, 0))
-            covered += vol if gshape else 1
-        if covered < total:
-            raise ValueError(
-                f"checkpoint incomplete for {path}: shards cover "
-                f"{covered}/{total} elements of gshape {gshape}"
-            )
 
 
 def _region_shape(index, gshape):

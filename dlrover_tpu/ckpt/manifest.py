@@ -1,10 +1,10 @@
 """Incremental, crash-consistent checkpoint chains (the manifest plane).
 
 Flash Checkpoint's cold path used to persist every frame whole through a
-single serial writer — the 86 MB/s cliff BENCH_r05 measured at the 3 GB
-host-scale point, and also the fragile path: a saver killed mid-persist
-left the step whole-or-nothing. This module replaces it with delta chains
-(FastPersist, arxiv 2406.13768, motivates decoupled parallel checkpoint
+single serial writer (86 MB/s for a 3 GB host-resident state; CPU
+sandbox, before PR 9), and also the fragile path: a saver killed
+mid-persist left the step whole-or-nothing. This module replaces it with
+delta chains (FastPersist, arxiv 2406.13768, motivates decoupled parallel checkpoint
 writes; ElasWave, arxiv 2510.00606, the graded-recovery framing):
 
 - **dirty-shard deltas**: the saver compares per-shard content digests

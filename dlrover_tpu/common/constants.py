@@ -254,7 +254,6 @@ class ConfigKey:
     # ckpt
     IPC_SOCKET = "DLROVER_TPU_IPC_SOCKET"
     CKPT_CRC = "DLROVER_TPU_CKPT_CRC"
-    CKPT_DEVICE_SNAPSHOT = "DLROVER_TPU_CKPT_DEVICE_SNAPSHOT"
     CKPT_READY_TIMEOUT = "DLROVER_TPU_CKPT_READY_TIMEOUT"
     CKPT_READY_COOLDOWN = "DLROVER_TPU_CKPT_READY_COOLDOWN"
     CKPT_STORAGE_WAIT = "DLROVER_TPU_CKPT_STORAGE_WAIT"
@@ -269,7 +268,6 @@ class ConfigKey:
     # survivor agents to publish their reshard service addresses
     RESHARD = "DLROVER_TPU_RESHARD"
     RESHARD_TIMEOUT_S = "DLROVER_TPU_RESHARD_TIMEOUT_S"
-    RESHARD_PORT = "DLROVER_TPU_RESHARD_PORT"
     # mesh re-decomposition (parallel/replan.py): enable flag for the
     # world-cut planner (default on; off = same-decomposition reshard,
     # the pre-replan behavior), the largest tensor-parallel degree the

@@ -654,8 +654,8 @@ def kv_bytes_per_slot_theoretical(config, cache_len: int,
     """What one decode slot's KV residency *should* cost for a model
     config: n_layers × 2 (k+v) × n_kv_heads × cache_len × head_dim ×
     dtype bytes, plus the per-token f32 scale pair when quantized.
-    ``bench.py memory`` compares the accountant's measured bytes/slot
-    against this (acceptance: within 10%)."""
+    ``tests/test_memory_observability.py`` holds the accountant's
+    measured bytes/slot to this (within 10%)."""
     elem = 1 if quantize else 2  # int8 vs bf16
     per_slot = (config.n_layers * 2 * config.n_kv_heads
                 * cache_len * config.head_dim * elem)

@@ -16,7 +16,7 @@ The pieces, each riding an existing subsystem instead of reinventing it:
   leases, syncs, training, and ROSE borrow/handback elasticity;
 - :mod:`dlrover_tpu.rl.drill` — the seeded end-to-end drill (chaos
   SIGKILLs a rollout replica AND the learner mid-episode) behind
-  ``examples/rl_rollout.py`` and the ``bench.py`` ``rl`` section.
+  ``examples/rl_rollout.py`` and ``tests/test_rl_rollout.py``.
 """
 
 from dlrover_tpu.rl.buffer import Trajectory, TrajectoryLedger, content_hash

@@ -12,9 +12,9 @@ run must still finish with
   (``unified_failover``, ``rl_lease_requeued``, ``rl_weight_sync``,
   ``serve_scale`` borrow+handback, ``rl_rollout_drained``).
 
-``examples/rl_rollout.py`` is the CLI face; ``bench.py``'s ``rl``
-section runs the same drill and reports trajectories/s, weight-sync
-latency, and max staleness.
+``examples/rl_rollout.py`` is the CLI face; ``tests/test_rl_rollout.py``
+runs the same drill in tier-1. The report carries trajectories/s,
+weight-sync latency, and max staleness.
 """
 
 import time

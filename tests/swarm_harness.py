@@ -16,8 +16,8 @@ and fire a storm of spurious connection-lost hooks into the master,
 which is neither what a long-lived agent process does nor what these
 drills mean to measure.
 
-Used by the tier-1 swarm smoke tests (small worlds), the ``swarm``-marked
-1000+-agent storm tests, and bench.py's ``control_plane`` section.
+Used by the tier-1 swarm smoke tests (small worlds) and the
+``swarm``-marked 1000+-agent storm tests.
 
 Typical use::
 

@@ -64,7 +64,7 @@ def test_chaos_kill_shrink_resume_rejoin():
     # not the heartbeat timeout: 1.2s measured, ~30% CI headroom
     assert result["detect_s"] <= 1.6, result["detect_s"]
     # kill -> world-1 training resumed (detect + restart + re-rendezvous +
-    # re-init + restore + recompile): 3.2s recorded in BENCH_r04 with the
+    # re-init + restore + recompile): 3.2s on the CPU sandbox with the
     # warm spawn pool (4.6-4.8s before it); bound = r4-verdict-prescribed
     # 5.0 — ~55% over the warm-pool median
     assert result["shrink_detect_s"] <= 5.0, result["shrink_detect_s"]

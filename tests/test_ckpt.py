@@ -568,10 +568,11 @@ def test_packed_restore_many_small_leaves(tmp_path, mesh):
         unlink_shared_memory(shm_name(engine.job_name, 0, 0))
 
 
-def test_load_in_place_fills_numpy_targets(tmp_path):
-    """in_place=True restores writable numpy leaves where they sit (no
-    fresh allocation — the host-resident fast path) and still returns a
-    correct tree; non-matching leaves fall back to the regular path."""
+@pytest.mark.parametrize("writable", [True, False])
+def test_load_rebuilds_numpy_targets(tmp_path, writable):
+    """A numpy target leaf, writable or read-only, comes back as a
+    writable array of its own holding the saved bytes; the target's
+    buffer is left as it was."""
     rng = np.random.default_rng(0)
     state = {
         "big": rng.standard_normal((256, 1024)).astype(np.float32),
@@ -579,7 +580,7 @@ def test_load_in_place_fills_numpy_targets(tmp_path):
         "step_no": 7,
     }
     engine = CheckpointEngine(
-        str(tmp_path), job_name=f"inplace{os.getpid()}", node_rank=0,
+        str(tmp_path), job_name=f"nptarget{os.getpid()}", node_rank=0,
         local_rank=0, ipc_socket="/nonexistent", world_size=1, rank=0,
     )
     try:
@@ -589,24 +590,14 @@ def test_load_in_place_fills_numpy_targets(tmp_path):
             "small": np.zeros((16,), np.float32),
             "step_no": 0,
         }
-        restored, step = engine.load(target, in_place=True)
+        target["big"].flags.writeable = writable
+        restored, step = engine.load(target)
         assert step == 5
-        # the in-place path reused the target's own buffer...
-        assert restored["big"] is target["big"]
-        # ...and filled it with the saved bytes
-        np.testing.assert_array_equal(restored["big"], state["big"])
-        np.testing.assert_array_equal(restored["small"], state["small"])
         assert restored["step_no"] == 7
-        # read-only targets must NOT be written in place
-        ro_target = {
-            "big": np.zeros((256, 1024), np.float32),
-            "small": np.zeros((16,), np.float32),
-            "step_no": 0,
-        }
-        ro_target["big"].flags.writeable = False
-        restored2, step2 = engine.load(ro_target, in_place=True)
-        assert step2 == 5
-        assert restored2["big"] is not ro_target["big"]
-        np.testing.assert_array_equal(restored2["big"], state["big"])
+        for name in ("big", "small"):
+            np.testing.assert_array_equal(restored[name], state[name])
+            assert restored[name].flags.writeable, name
+            assert not np.shares_memory(restored[name], target[name]), name
+            assert not target[name].any(), name
     finally:
         unlink_shared_memory(shm_name(engine.job_name, 0, 0))

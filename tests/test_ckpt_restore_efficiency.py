@@ -1,9 +1,8 @@
 """Restore scheduling efficiency against a SYNTHETIC constant-rate link.
 
-The bench's restore_link_efficiency (bench.py ckpt section) is judged
-against link probes taken in the same run, so a miss there can be the
-probe. This test pins the link: device transfers are
-throttled to an exclusive constant-rate channel and shm reads to a
+A restore's efficiency judged against link probes taken in the same run
+can miss because of the probe. This test pins the link: device transfers
+are throttled to an exclusive constant-rate channel and shm reads to a
 concurrent per-stream rate, then the engine's restore must keep the
 channel >=90% busy — i.e. wall time within 1/0.9 of the link floor.
 A scheduler regression that serializes reads after transfers (instead of

@@ -343,9 +343,9 @@ def test_host_leaves_are_written_and_never_counted_on_the_link(tmp_path):
     np.testing.assert_array_equal(restored["a"], state["a"])
 
 
-@pytest.mark.parametrize("snapshot", [True, False])
+@pytest.mark.parametrize("mesh", ["one", "4x2"])
 def test_the_plan_issues_no_copy_and_splits_every_shard_over_a_chunk(
-        tmp_path, small_chunk, monkeypatch, snapshot):
+        tmp_path, small_chunk, monkeypatch, mesh):
     from jax._src.array import ArrayImpl
 
     issued = []
@@ -356,7 +356,7 @@ def test_the_plan_issues_no_copy_and_splits_every_shard_over_a_chunk(
         return real(self)
 
     monkeypatch.setattr(ArrayImpl, "copy_to_host_async", recorded)
-    put = placement("4x2")
+    put = placement(mesh)
     state = {
         "big": put(make_leaf("f32", 3 * CHUNK + 128, (4, 2))),
         "deep": put(np.arange(16 * 2 * 8 * 40, dtype=np.float32).reshape(
@@ -366,14 +366,11 @@ def test_the_plan_issues_no_copy_and_splits_every_shard_over_a_chunk(
         "count": jnp.asarray(2, jnp.int32),
     }
     engine = engine_for(tmp_path)
-    engine._device_snapshot = snapshot
     meta, pending = engine._plan_state(9, state)
     assert issued == []
     for shard, pieces in pending:
-        over = shard["nbytes"] > CHUNK
-        assert (len(pieces) > 1) == (over and snapshot), shard
-        if snapshot:
-            assert all(array.nbytes <= CHUNK for _, array, _ in pieces)
+        assert (len(pieces) > 1) == (shard["nbytes"] > CHUNK), shard
+        assert all(array.nbytes <= CHUNK for _, array, _ in pieces)
         # the pieces tile the shard, in order
         at = 0
         for offset, array, skip in pieces:
@@ -385,15 +382,16 @@ def test_the_plan_issues_no_copy_and_splits_every_shard_over_a_chunk(
     ours = {array.unsafe_buffer_pointer()
             for _, pieces in pending for _, array, _ in pieces}
     # a private copy each, never the caller's buffer handed through
-    assert ours.isdisjoint(theirs) == snapshot
+    assert ours.isdisjoint(theirs)
 
     fetched = engine._fetch_and_write(meta, pending)
     assert sum(issued) >= sum(s["nbytes"] for leaf in meta["leaves"]
                               for s in leaf.get("shards", []))
     assert len(issued) == fetched["blocks"]
-    if snapshot:
-        assert fetched["inflight_peak_bytes"] <= 2 * CHUNK
-        assert fetched["split_leaves"] == 8 + 8 + 1
+    assert fetched["inflight_peak_bytes"] <= 2 * CHUNK
+    # "big" and "deep" over a chunk on every device that holds a shard,
+    # "flat" on its one
+    assert fetched["split_leaves"] == (8 + 8 + 1 if mesh == "4x2" else 3)
     restored, step = engine.load(jax.tree.map(lambda x: x, state))
     assert step == 9
     for name in state:
