@@ -2,18 +2,31 @@
 
 The reference delegates MoE entirely to Megatron/DeepSpeed (SURVEY.md §2.7:
 EP "absent — delegated to frameworks"); a from-scratch TPU stack owns it.
-Design for the MXU/GSPMD:
+Design for the MXU:
 
-- **einsum dispatch/combine** (GShard-style): routing becomes two dense
-  einsums against a (tokens, experts, capacity) one-hot tensor — static
-  shapes, no gather/scatter, XLA shards it cleanly. Capacity-dropped
-  tokens fall through the residual connection (standard Switch behavior);
+- **the experts compute the rows that were routed and no others**: the
+  (token, choice) pairs of a microbatch are sorted by expert, their rows
+  gathered into one buffer of static size, and each projection is one
+  grouped matmul over it whose ``group_sizes`` say which rows belong to
+  which expert. Rows past the last kept pair cost no MXU time. (Until
+  PR 30 routing was two dense einsums against a (tokens, experts,
+  capacity) one-hot, which computes every slot an expert might fill:
+  four times the useful rows where no token may be dropped);
+- **capacity is a mask, not a shape**: a pair beyond its expert's
+  capacity in its routing group is sorted last, counted in no group and
+  adds nothing to its token, which then falls through the residual
+  connection (standard Switch behavior). One path for every
+  ``capacity_factor``;
 - **expert-axis sharding**: every expert tensor carries a leading
   ``expert`` logical axis → the ``ep`` mesh axis (parallel/sharding.py
-  DEFAULT_RULES), so expert FFNs compute where their weights live and
-  GSPMD inserts the token all-to-alls;
+  DEFAULT_RULES). Under a mesh the sorted block runs in a ``shard_map``
+  (a Pallas call has no partitioning rule): a chip sorts its own
+  experts' pairs first and computes them against its local weights, and
+  one all-reduce joins what the chips computed for each token. Every
+  chip of an ``ep`` group holds the same rows, so the group waits for
+  the chip whose experts drew the most pairs;
 - **top-k routing with renormalized gates** (Mixtral) + Switch-style
-  load-balancing auxiliary loss, both in f32;
+  load-balancing auxiliary loss per routing group, both in f32;
 - attention/norms/RoPE are the Llama blocks (models/llama.py) unchanged —
   ring/Ulysses long-context paths compose with MoE layers;
 - scanned layers, bf16 params, remat: same compile-time story as llama.
@@ -22,13 +35,18 @@ Checkpoint shards fall out of the ``NamedSharding`` on each leaf — the
 engine needs no MoE-specific code (ckpt shard = mesh coords incl. ep).
 """
 
+import functools
+import math
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.models import llama as _llama
+from dlrover_tpu.parallel.sharding import valid_spec_for
 
 
 @dataclass(frozen=True)
@@ -42,10 +60,10 @@ class MoEConfig(_llama.AttentionConfigMixin):
     n_experts: int = 8
     top_k: int = 2
     capacity_factor: float = 1.25  # expert slots = g/E · top_k · this
-    # routing group size (GShard num_groups dual): tokens route within
-    # fixed-size groups so the (g, E, C) dispatch tensor stays O(g²) per
-    # group instead of O(T²) over the whole batch. None = one sequence
-    # per group (g = S), the standard choice.
+    # routing group size (GShard num_groups dual): capacity and the
+    # auxiliary loss are reckoned within fixed-size groups of tokens, so
+    # what a token may be dropped for does not depend on the rest of the
+    # batch. None = one sequence per group (g = S), the standard choice.
     route_group_size: Optional[int] = None
     router_aux_weight: float = 0.01
     max_seq_len: int = 4096
@@ -136,14 +154,14 @@ def expert_capacity(config: MoEConfig, batch: int, seq: int) -> int:
 
 
 def _route(x_grouped, router, config: MoEConfig, capacity: int):
-    """Top-k routing with capacity → dispatch/combine tensors + aux loss.
+    """Top-k routing with capacity → the pairs' experts, gates and mask.
 
     x_grouped: (G, g, D) — G routing groups of g tokens; capacity is
-    per-expert *per group*, so the dispatch tensor is (G, g, E, C) with
-    C ∝ g (bounded per group, not O(total²)). Returns dispatch 0/1,
-    combine f32 gate weights, aux scalar. Choice-major priority within a
-    group: every token's first choice claims capacity before any token's
-    second choice (GShard order).
+    per-expert *per group*. Returns, each (G, g, k): the expert of every
+    (token, choice) pair, its renormalized f32 gate, and ``keep``, false
+    where the pair's expert was full in its group; and the aux scalar.
+    Choice-major priority within a group: every token's first choice
+    claims capacity before any token's second choice (GShard order).
     """
     c = config
     G, g = x_grouped.shape[0], x_grouped.shape[1]
@@ -160,42 +178,192 @@ def _route(x_grouped, router, config: MoEConfig, capacity: int):
     positions = (
         jnp.cumsum(cm.reshape(G, k * g, E), axis=1).reshape(G, k, g, E) - 1.0
     )
-    keep = (positions < capacity) * cm                    # (G, k, g, E)
-    pos_in_expert = (positions * cm).sum(-1).astype(jnp.int32)  # (G, k, g)
-    slot = jax.nn.one_hot(pos_in_expert, capacity, dtype=jnp.float32)
-    # (G, k, g, E, C): expert one-hot × slot one-hot, overflow dropped
-    oh = keep[..., None] * slot[:, :, :, None, :]
-    dispatch = oh.sum(1)                                  # (G, g, E, C)
-    gates_km = gates.transpose(0, 2, 1)                   # (G, k, g)
-    combine = (oh * gates_km[..., None, None]).sum(1)     # (G, g, E, C)
+    pos_in_expert = (positions * cm).sum(-1)              # (G, k, g)
+    keep = (pos_in_expert < capacity).transpose(0, 2, 1)  # (G, g, k)
 
     # load-balancing loss over ALL k choices (ST-MoE/Mixtral style): a
     # router dumping second choices on one expert is penalized too.
     # E · Σ_e (choice fraction · mean router prob), averaged over groups
     frac = masks.mean(axis=(1, 2))                        # (G, E)
     aux = E * jnp.mean(jnp.sum(frac * probs.mean(axis=1), axis=-1))
-    return dispatch, combine, aux
+    return topi, gates, keep, aux
 
 
-def _moe_ffn(x, layer, config: MoEConfig):
+# megablox tiles (rows, contraction, columns), chosen by measurement at
+# the expert cell's shapes (8,192 rows of which 2,048 live, 4096 x 14336,
+# two groups; PERF.md §6, PR 30)
+_GMM_TILING = (512, 1024, 1024)
+
+
+def _tiles(k: int, n: int):
+    tm, tk, tn = _GMM_TILING
+    return tm, min(tk, k), min(tn, n)
+
+
+@jax.custom_vjp
+def _gmm(rows, w, group_sizes):
+    """The Pallas grouped matmul (megablox) and its two gradients, each a
+    kernel of the same family. One rule for the function and its forward
+    pass, so that tracing makes each kernel once (megablox's own
+    ``ops.gmm`` traces two of every forward kernel under remat: set-up
+    seconds, PERF.md §6, PR 30)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    return gmm(
+        rows, w, group_sizes, rows.dtype, _tiles(w.shape[1], w.shape[2]))
+
+
+def _gmm_fwd(rows, w, group_sizes):
+    return _gmm(rows, w, group_sizes), (rows, w, group_sizes)
+
+
+def _gmm_bwd(residuals, g):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    rows, w, group_sizes = residuals
+    tiles = _tiles(w.shape[1], w.shape[2])
+    d_rows = gmm(g, w, group_sizes, rows.dtype, tiles, transpose_rhs=True)
+    d_w = tgmm(rows.swapaxes(0, 1), g, group_sizes, w.dtype, tiles)
+    return d_rows, d_w, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def _grouped_matmul(rows, w, group_sizes):
+    """``rows[start_e:start_e + group_sizes[e]] @ w[e]`` for every group,
+    the groups back to back from row 0. rows (M, K), w (E, K, N) → (M, N).
+    Rows past the last group are not computed: what comes back there is
+    undefined and the caller's to mask. The Pallas kernel on TPU, XLA's
+    own lowering elsewhere."""
+    if jax.default_backend() != "tpu":
+        return jax.lax.ragged_dot(rows, w, group_sizes)
+    m = rows.shape[0]
+    rows = jnp.pad(rows, ((0, -m % _GMM_TILING[0]), (0, 0)))  # decode's few
+    return _gmm(rows, w, group_sizes)[:m]
+
+
+# what a remat policy that saves dots saves of the experts: a kernel call
+# is no dot to ``jax.checkpoint``, so the three projections are named.
+# The backward pass needs each again (the down projection's output for
+# the gradient of the gates): unsaved they are three of twelve grouped
+# matmuls a microbatch (PERF.md §6, PR 30)
+_SAVED = ("moe_gate", "moe_up", "moe_down")
+
+
+def _remat_policy(config):
+    """The llama policy, with the experts' named projections saved
+    wherever it saves dots."""
+    policy = _llama._remat_policy(config)
+    if policy is None:
+        return None
+    return jax.checkpoint_policies.save_from_both_policies(
+        policy, jax.checkpoint_policies.save_only_these_names(*_SAVED))
+
+
+def _expert_ffn(rows, group_sizes, w1, w3, w2):
+    """SwiGLU over the sorted buffer: rows (M, D) grouped by expert, the
+    first ``group_sizes.sum()`` of them live. Dead rows are zeroed going
+    in and coming out, so nothing they hold reaches a token or a weight
+    gradient."""
+    live = (jnp.arange(rows.shape[0]) < group_sizes.sum())[:, None]
+    rows = jnp.where(live, rows, 0)
+    gate = checkpoint_name(_grouped_matmul(rows, w1, group_sizes), _SAVED[0])
+    up = checkpoint_name(_grouped_matmul(rows, w3, group_sizes), _SAVED[1])
+    down = checkpoint_name(
+        _grouped_matmul(jax.nn.silu(gate) * up, w2, group_sizes), _SAVED[2])
+    return jnp.where(live, down, 0)
+
+
+@jax.custom_vjp
+def _permute(x, perm, inverse):
+    """``x[perm]`` for a permutation whose inverse is known: the gradient
+    is a gather too, where autodiff would scatter-add."""
+    return x[perm]
+
+
+def _permute_fwd(x, perm, inverse):
+    return x[perm], inverse
+
+
+def _permute_bwd(inverse, g):
+    return g[inverse], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def _sort_by_expert(expert, keep, n_local: int, first=0):
+    """Order of the pairs with those of experts ``first .. first +
+    n_local`` first, by expert, and every other pair (dropped, or another
+    chip's) last; and how many each of those experts keeps."""
+    local = expert - first
+    mine = keep & (local >= 0) & (local < n_local)
+    key = jnp.where(mine, local, n_local)
+    order = jnp.argsort(key, stable=True)
+    group_sizes = (key[:, None] == jnp.arange(n_local)).sum(
+        0, dtype=jnp.int32)
+    return order, group_sizes
+
+
+def _routed_rows(x, expert, keep, gates, w1, w3, w2, ep_axis=None):
+    """What the experts in w1, w3, w2 add to every token: all of them, or
+    under ``ep_axis`` this chip's. x (1, B, S, D), this chip's copy of
+    its tokens; expert, keep, gates (B, S, k). Returns the shape of x:
+    this chip's term of the sum over chips."""
+    k, D = expert.shape[-1], x.shape[-1]
+    # pairs choice-major, pair j·T + t: a choice's rows are one block, so
+    # no array is laid out (T, k, D) with k among the tiled dimensions
+    by_choice = lambda a: a.reshape(-1, k).T
+    n_local = w1.shape[0]
+    first = jax.lax.axis_index(ep_axis) * n_local if ep_axis else 0
+    order, group_sizes = _sort_by_expert(
+        by_choice(expert).reshape(-1), by_choice(keep).reshape(-1),
+        n_local, first)
+    inverse = jnp.argsort(order)
+    rows = _permute(jnp.tile(x.reshape(-1, D), (k, 1)), order, inverse)
+    out = _expert_ffn(rows, group_sizes, w1, w3, w2)
+    out = _permute(out, inverse, order).reshape(k, -1, D)
+    y = (out.astype(jnp.float32) * by_choice(gates)[..., None]).sum(0)
+    return y.astype(x.dtype).reshape(x.shape)
+
+
+def _moe_ffn(x, layer, config: MoEConfig, mesh=None):
     """Sparse expert FFN. x: (B, S, D) → (B, S, D), aux scalar."""
     c = config
     B, S, D = x.shape
     capacity = expert_capacity(c, B, S)
     g = _group_size(c, B, S)
-    x_grouped = x.reshape(B * S // g, g, D)
-    dispatch, combine, aux = _route(x_grouped, layer["router"], c, capacity)
-    # dispatch/compute/combine — three einsums, expert axis sharded over ep
-    expert_in = jnp.einsum(
-        "gtec,gtd->gecd", dispatch.astype(x.dtype), x_grouped
-    )
-    gate = jax.nn.silu(jnp.einsum("gecd,edf->gecf", expert_in, layer["w1"]))
-    up = jnp.einsum("gecd,edf->gecf", expert_in, layer["w3"])
-    expert_out = jnp.einsum("gecf,efd->gecd", gate * up, layer["w2"])
-    out = jnp.einsum(
-        "gtec,gecd->gtd", combine.astype(x.dtype), expert_out
-    )
-    return out.reshape(B, S, D), aux
+    pairs = (B, S, c.top_k)
+    expert, gates, keep, aux = _route(
+        x.reshape(B * S // g, g, D), layer["router"], c, capacity)
+    experts, terms = _routed_rows, 1
+    if mesh is not None:
+        # a Pallas call has no partitioning rule, so under a mesh the
+        # block is manual over all of it: tokens stay where the batch and
+        # sequence axes put them, the experts where ``expert`` and ``mlp``
+        # put their weights. The rows go in as one copy, and the terms
+        # come out one, for each chip whose weights differ, so that the
+        # sum below and its transpose in the backward pass are all-reduces
+        # that GSPMD inserts
+        tokens = valid_spec_for(mesh, (B, S), ("batch", "seq"))
+        up = valid_spec_for(mesh, layer["w1"].shape, ("expert", None, "mlp"))
+        down = P(up[0], up[2], None)
+        over = tuple(a for a in (up[0], up[2]) if a)
+        terms = math.prod(mesh.shape[a] for a in over)
+        experts = jax.shard_map(
+            functools.partial(_routed_rows, ep_axis=up[0]), mesh=mesh,
+            in_specs=(P(over, *tokens), P(*tokens), P(*tokens), P(*tokens),
+                      up, up, down),
+            out_specs=P(over, *tokens), check_vma=False,
+        )
+    with jax.named_scope("moe_experts"):
+        out = experts(
+            jnp.broadcast_to(x, (terms, B, S, D)),
+            *(a.reshape(pairs) for a in (expert, keep, gates)),
+            layer["w1"], layer["w3"], layer["w2"],
+        ).sum(0)
+    return out, aux
 
 
 def forward(
@@ -217,7 +385,8 @@ def forward(
             layer, c, positions, mesh,
         )
         ffn_out, aux = _moe_ffn(
-            _llama.rms_norm(h, layer["ffn_norm"], c.norm_eps), layer, c
+            _llama.rms_norm(h, layer["ffn_norm"], c.norm_eps), layer, c,
+            mesh,
         )
         return (h + ffn_out, aux_sum + aux), None
 
@@ -225,7 +394,7 @@ def forward(
     if c.remat:
         scan_fn = jax.checkpoint(
             layer_fn, prevent_cse=False,
-            policy=_llama._remat_policy(c),
+            policy=_remat_policy(c),
         )
     (x, aux_sum), _ = jax.lax.scan(
         scan_fn, (x, jnp.zeros((), jnp.float32)), params["layers"]
@@ -238,8 +407,12 @@ def forward(
     return logits, aux_sum / c.n_layers
 
 
+@functools.partial(jax.jit, static_argnames=("config", "mesh"))
 def next_token_loss(params, tokens, config: MoEConfig, mesh=None):
-    """Causal LM loss + router load-balancing aux term."""
+    """Causal LM loss + router load-balancing aux term. Jitted so that a
+    process traces and differentiates the model once, however many
+    programs hold the loss (a check of the gradient, then the train
+    step: a second or two of set-up with the experts' kernels)."""
     logits, aux = forward(params, tokens[:, :-1], config, mesh)
     return _llama.cross_entropy(logits, tokens[:, 1:]) \
         + config.router_aux_weight * aux

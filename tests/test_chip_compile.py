@@ -25,7 +25,7 @@ from jax.sharding import (
     SingleDeviceSharding,
 )
 
-from dlrover_tpu.models import llama
+from dlrover_tpu.models import llama, moe
 from dlrover_tpu.ops.flash_attention import (
     flash_attention,
     flash_decode_attention,
@@ -140,6 +140,46 @@ def test_restore_rebuilds_a_large_leaf_in_its_own_bytes(topo, shape, dtype):
     assert memory.argument_size_in_bytes == len(blocks) * block
     copies = engine._carrier(dtype) != engine._np_dtype(dtype)
     assert memory.temp_size_in_bytes <= block + copies * leaf
+
+
+def test_expert_layer_over_ep4_runs_the_grouped_kernel(topo, monkeypatch):
+    """``moe._moe_ffn`` at Mixtral-8x7B widths over ``ep`` 4, forward and
+    backward: every projection and each of its two gradients is one
+    grouped-matmul kernel over the ``T·k`` sorted rows, inside a
+    ``shard_map`` (a Mosaic call cannot be partitioned by GSPMD), and no
+    array of the program is laid out by expert slots."""
+    # the layer picks its grouped matmul from the default backend, which
+    # is the CPU here: steer it, as the chip would
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = moe.MoEConfig(
+        n_layers=1, capacity_factor=4.0, route_group_size=512,
+        max_seq_len=4096)
+    mesh = build_mesh(plan_mesh(4, ep=4), devices=list(topo.devices))
+    whole, by_expert = NamedSharding(mesh, P()), NamedSharding(mesh, P("ep"))
+    E, D, F, T = cfg.n_experts, cfg.dim, cfg.ffn_dim, 4096
+    layer = {
+        "router": _shape((D, E), jnp.float32, whole),
+        "w1": _shape((E, D, F), jnp.bfloat16, by_expert),
+        "w3": _shape((E, D, F), jnp.bfloat16, by_expert),
+        "w2": _shape((E, F, D), jnp.bfloat16, by_expert),
+    }
+    x = _shape((1, T, D), jnp.bfloat16, whole)
+
+    def loss(x, layer):
+        out, aux = moe._moe_ffn(x, layer, cfg, mesh)
+        return out.astype(jnp.float32).sum() + aux
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1)), x, layer)
+    calls = [line.split()[0] for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # three projections x (forward, gradient of the rows, of the weights)
+    assert len(calls) == 9, calls
+    assert sum("tgmm" in name for name in calls) == 3, calls
+    rows = T * cfg.top_k
+    assert f"bf16[{rows},{F}]" in text
+    slots = moe.expert_capacity(cfg, 1, T)
+    assert f"{F},{T // 512},{slots}]" not in text     # the old (e,f,g,c)
+    assert f"{slots},{F}]" not in text
 
 
 def test_train_step_one_layer_fits_the_chip(topo, monkeypatch):

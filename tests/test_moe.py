@@ -18,35 +18,117 @@ def _tiny(dtype=jnp.float32, **kw):
     return moe.MoEConfig(**base)
 
 
+def _old_route(x_grouped, router, config, capacity):
+    """The one-hot dispatch/combine routing ``models/moe.py`` had until
+    PR 30, kept as the reference the sorted path is held to."""
+    c = config
+    G, g = x_grouped.shape[0], x_grouped.shape[1]
+    E, k = c.n_experts, c.top_k
+    logits = jnp.einsum(
+        "gtd,de->gte", x_grouped.astype(jnp.float32), router
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    topv, topi = jax.lax.top_k(probs, k)
+    gates = topv / jnp.clip(topv.sum(-1, keepdims=True), 1e-9)
+    masks = jax.nn.one_hot(topi, E, dtype=jnp.float32)
+    cm = masks.transpose(0, 2, 1, 3)
+    positions = (
+        jnp.cumsum(cm.reshape(G, k * g, E), axis=1).reshape(G, k, g, E) - 1.0
+    )
+    keep = (positions < capacity) * cm
+    pos_in_expert = (positions * cm).sum(-1).astype(jnp.int32)
+    slot = jax.nn.one_hot(pos_in_expert, capacity, dtype=jnp.float32)
+    oh = keep[..., None] * slot[:, :, :, None, :]         # (G, k, g, E, C)
+    dispatch = oh.sum(1)
+    gates_km = gates.transpose(0, 2, 1)
+    combine = (oh * gates_km[..., None, None]).sum(1)
+    frac = masks.mean(axis=(1, 2))
+    aux = E * jnp.mean(jnp.sum(frac * probs.mean(axis=1), axis=-1))
+    return dispatch, combine, aux
+
+
+def _old_moe_ffn(x, layer, config):
+    """The three einsums over (group, expert, slot) of before PR 30."""
+    c = config
+    B, S, D = x.shape
+    capacity = moe.expert_capacity(c, B, S)
+    g = moe._group_size(c, B, S)
+    x_grouped = x.reshape(B * S // g, g, D)
+    dispatch, combine, aux = _old_route(
+        x_grouped, layer["router"], c, capacity)
+    expert_in = jnp.einsum(
+        "gtec,gtd->gecd", dispatch.astype(x.dtype), x_grouped
+    )
+    gate = jax.nn.silu(jnp.einsum("gecd,edf->gecf", expert_in, layer["w1"]))
+    up = jnp.einsum("gecd,edf->gecf", expert_in, layer["w3"])
+    expert_out = jnp.einsum("gecf,efd->gecd", gate * up, layer["w2"])
+    out = jnp.einsum(
+        "gtec,gecd->gtd", combine.astype(x.dtype), expert_out
+    )
+    return out.reshape(B, S, D), aux
+
+
+def _layer(c, key):
+    """One layer's router and expert leaves, float32."""
+    ks = jax.random.split(key, 4)
+    E, D, F = c.n_experts, c.dim, c.ffn_dim
+    return {
+        "router": jax.random.normal(ks[0], (D, E)) * D ** -0.5,
+        "w1": jax.random.normal(ks[1], (E, D, F)) * D ** -0.5,
+        "w3": jax.random.normal(ks[2], (E, D, F)) * D ** -0.5,
+        "w2": jax.random.normal(ks[3], (E, F, D)) * F ** -0.5,
+    }
+
+
+def _routed(c, G, g, router=None):
+    """The pairs of G groups of g tokens: expert, gates, keep (each
+    (G, g, k)), aux, and the capacity they were held to."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (G, g, c.dim))
+    if router is None:
+        router = jax.random.normal(
+            jax.random.PRNGKey(1), (c.dim, c.n_experts))
+    cap = moe.expert_capacity(c, G, g)
+    return moe._route(x, router, c, cap) + (cap,)
+
+
 class TestRouting:
-    def test_dispatch_combine_shapes_and_mass(self):
+    def test_kept_pairs_and_gate_mass(self):
         c = _tiny()
-        G, g, D = 2, 32, c.dim
-        x = jax.random.normal(jax.random.PRNGKey(0), (G, g, D))
-        router = jax.random.normal(jax.random.PRNGKey(1), (D, c.n_experts))
-        cap = moe.expert_capacity(c, G, g)
-        dispatch, combine, aux = moe._route(x, router, c, cap)
-        assert dispatch.shape == (G, g, c.n_experts, cap)
-        # each token occupies at most top_k slots, each slot ≤ 1 token
-        assert float(dispatch.sum(axis=(2, 3)).max()) <= c.top_k
-        assert float(dispatch.sum(axis=1).max()) <= 1.0 + 1e-6
-        # combine weights for a fully-dispatched token sum to ~1
-        per_tok = combine.sum(axis=(2, 3))
-        full = dispatch.sum(axis=(2, 3)) == c.top_k
+        G, g = 2, 32
+        expert, gates, keep, aux, cap = _routed(c, G, g)
+        assert expert.shape == gates.shape == keep.shape == (G, g, c.top_k)
+        assert keep.dtype == jnp.bool_
+        # each token is kept at most top_k times, by distinct experts
+        assert int(keep.sum(-1).max()) <= c.top_k
+        assert bool((expert[..., 0] != expert[..., 1]).all())
+        # each expert of a group keeps at most capacity pairs
+        per_expert = (
+            jax.nn.one_hot(expert, c.n_experts) * keep[..., None]
+        ).sum(axis=(1, 2))
+        assert float(per_expert.max()) <= cap
+        # the gates of a fully kept token sum to 1
+        full = np.asarray(keep.all(-1))
         np.testing.assert_allclose(
-            np.asarray(per_tok)[np.asarray(full)], 1.0, atol=1e-5
+            np.asarray(gates.sum(-1))[full], 1.0, atol=1e-5
         )
         assert float(aux) > 0.0
 
     def test_capacity_drops_overflow(self):
         c = _tiny(capacity_factor=0.25)
         g = 64
-        x = jax.random.normal(jax.random.PRNGKey(0), (1, g, c.dim))
         router = jnp.zeros((c.dim, c.n_experts))  # uniform: argmax ties
-        cap = moe.expert_capacity(c, 1, g)
-        dispatch, _, _ = moe._route(x, router, c, cap)
-        assert float(dispatch.sum(axis=1).max()) <= 1.0 + 1e-6
-        assert float(dispatch.sum()) <= c.n_experts * cap + 1e-6
+        expert, _, keep, _, cap = _routed(c, 1, g, router)
+        # ties send every pair to two experts: both fill, the rest drop
+        assert int(keep.sum()) == 2 * cap < g * c.top_k
+        order, group_sizes = moe._sort_by_expert(
+            expert.reshape(-1), keep.reshape(-1), c.n_experts)
+        assert int(group_sizes.sum()) == int(keep.sum())
+        assert int(group_sizes.max()) <= cap
+        # kept pairs come first, by expert; dropped pairs last
+        kept_sorted = np.asarray(keep.reshape(-1)[order])
+        assert kept_sorted[: 2 * cap].all() and not kept_sorted[2 * cap:].any()
+        experts_sorted = np.asarray(expert.reshape(-1)[order])[: 2 * cap]
+        assert (np.diff(experts_sorted) >= 0).all()
 
     def test_group_size_bounds_capacity(self):
         # capacity depends on the group size, not the total token count
@@ -54,6 +136,89 @@ class TestRouting:
         assert moe.expert_capacity(c, 8, 128) == moe.expert_capacity(c, 1, 32)
         with pytest.raises(ValueError, match="divide"):
             moe.expert_capacity(c, 1, 33)
+
+    def test_dead_rows_of_the_sorted_buffer_reach_nothing(self):
+        # rows past group_sizes.sum() belong to no expert: whatever they
+        # hold, the block's output is finite and zero there
+        c = _tiny()
+        layer = _layer(c, jax.random.PRNGKey(2))
+        group_sizes = jnp.array([5, 0, 9, 3], jnp.int32)
+        rows = jax.random.normal(jax.random.PRNGKey(3), (40, c.dim))
+        poisoned = rows.at[17:].set(jnp.nan)
+        out = moe._expert_ffn(
+            poisoned, group_sizes, layer["w1"], layer["w3"], layer["w2"])
+        assert bool(jnp.isfinite(out).all())
+        assert float(jnp.abs(out[17:]).max()) == 0.0
+        np.testing.assert_array_equal(
+            np.asarray(out),
+            np.asarray(moe._expert_ffn(
+                rows, group_sizes, layer["w1"], layer["w3"], layer["w2"])),
+        )
+        # and each live row went through its own expert's weights
+        h = jax.nn.silu(rows[5:14] @ layer["w1"][2]) * (
+            rows[5:14] @ layer["w3"][2])
+        np.testing.assert_allclose(
+            np.asarray(out[5:14]), np.asarray(h @ layer["w2"][2]),
+            atol=1e-5, rtol=1e-5,
+        )
+
+
+LEAVES = ("router", "w1", "w3", "w2")
+
+
+@pytest.mark.parametrize("axes", [None, {"ep": 4}, {"ep": 2, "tp": 2}],
+                         ids=["unsharded", "ep4xfsdp2", "ep2xtp2xfsdp2"])
+@pytest.mark.parametrize("group", [None, 16], ids=["seq-groups", "groups16"])
+@pytest.mark.parametrize("capacity_factor", [0.25, 1.25, 2.0],
+                         ids=["cf0.25", "cf1.25", "dropless"])
+def test_sorted_experts_equal_the_one_hot_dispatch(
+        capacity_factor, group, axes):
+    """The grouped matmul over sorted pairs computes what the einsums over
+    (group, expert, slot) computed: output, aux and every gradient, with
+    pairs dropped (0.25, 1.25) and with none (n_experts / top_k)."""
+    c = _tiny(capacity_factor=capacity_factor, route_group_size=group)
+    dropless = capacity_factor == c.n_experts / c.top_k
+    B, S = 4, 32
+    x = jax.random.normal(jax.random.PRNGKey(4), (B, S, c.dim))
+    layer = _layer(c, jax.random.PRNGKey(5))
+    probe = jax.random.normal(jax.random.PRNGKey(6), (B, S, c.dim))
+    mesh = build_mesh(plan_mesh(8, **axes)) if axes else None
+
+    def scalar(ffn):
+        def f(x, layer):
+            out, aux = ffn(x, layer)
+            return (out * probe).sum() + aux, (out, aux)
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))
+
+    (_, (ref_out, ref_aux)), (ref_dx, ref_dl) = scalar(
+        lambda x, layer: _old_moe_ffn(x, layer, c))(x, layer)
+    if mesh is not None:
+        logical = moe.param_logical_axes(c)["layers"]
+        layer = shard_tree(
+            mesh, layer, {k: logical[k][1:] for k in LEAVES})
+        x = jax.device_put(
+            x, NamedSharding(mesh, P(("dp", "fsdp"), None, None)))
+    (_, (out, aux)), (dx, dl) = scalar(
+        lambda x, layer: moe._moe_ffn(x, layer, c, mesh))(x, layer)
+
+    dropped = int((~_routed_keep(c, x, layer)).sum())
+    assert (dropped == 0) == dropless
+    tol = dict(atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out), **tol)
+    np.testing.assert_allclose(float(aux), float(ref_aux), **tol)
+    np.testing.assert_allclose(np.asarray(dx), np.asarray(ref_dx), **tol)
+    for name in LEAVES:
+        np.testing.assert_allclose(
+            np.asarray(dl[name]), np.asarray(ref_dl[name]), err_msg=name,
+            **tol)
+
+
+def _routed_keep(c, x, layer):
+    B, S, D = x.shape
+    g = moe._group_size(c, B, S)
+    return moe._route(
+        np.asarray(x).reshape(B * S // g, g, D), np.asarray(layer["router"]),
+        c, moe.expert_capacity(c, B, S))[2]
 
 
 class TestMoEModel:
@@ -67,6 +232,28 @@ class TestMoEModel:
         assert logits.shape == (2, 32, c.vocab_size)
         loss = moe.next_token_loss(params, tokens, c)
         assert bool(jnp.isfinite(loss)) and bool(jnp.isfinite(aux))
+
+    def test_the_loss_is_traced_once_a_process(self, monkeypatch):
+        # next_token_loss is jitted: a second program that holds it (the
+        # train step after a check of the gradient) reuses the first's
+        # trace and its differentiation
+        calls = []
+        real = moe.forward
+        monkeypatch.setattr(
+            moe, "forward",
+            lambda *a, **k: calls.append(1) or real(*a, **k))
+        c = _tiny(vocab_size=257)         # a signature no other test has
+        params = moe.init_params(c, jax.random.PRNGKey(0))
+        tokens = jax.random.randint(
+            jax.random.PRNGKey(1), (2, 17), 0, c.vocab_size)
+        first = jax.jit(jax.grad(
+            lambda p: moe.next_token_loss(p, tokens, c)))(params)
+        second = jax.jit(
+            lambda p: 2.0 * moe.next_token_loss(p, tokens, c))(params)
+        assert len(calls) == 1
+        assert bool(jnp.isfinite(second))
+        assert all(bool(jnp.isfinite(g).all())
+                   for g in jax.tree.leaves(first))
 
     def test_num_params_mixtral_scale(self):
         total, active = moe.num_params(moe.MoEConfig.mixtral8x7b())
@@ -152,3 +339,98 @@ def test_cross_entropy_matches_log_softmax_gather():
     logp = jax.nn.log_softmax(logits, axis=-1)
     ref = -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0].mean()
     np.testing.assert_allclose(float(ours), float(ref), rtol=1e-6)
+
+
+# -- the benchmark's reader of the layer (benchmarks/layer_metrics) ----------
+
+_T = "{3,2,1,0:T(8,128)(2,1)}"
+# HLO lines of the kept traces of PR 30 (mixtral-8x7b.train-steady, chip 0),
+# shortened: (line, is an op of the experts)
+SLOT_LINES = [   # the one-hot dispatch: activations laid out (e, f, g, c)
+    (f"%fusion.564 = (bf16[2,14336,8,512]{_T}, bf16[2,14336,8,512]{_T}) "
+     f"fusion(bf16[2,14336,8,512]{_T} %fusion.561, bf16[8,2,512,4096]{_T} "
+     f"%fusion.562, bf16[1,2,4096,14336]{_T} %get-tuple-element.1865), "
+     "kind=kOutput", True),
+    (f"%bitcast_add_fusion.20 = f32[1,2,14336,4096]{_T} fusion("
+     f"f32[1,2,14336,4096]{_T} %get-tuple-element.1803, "
+     f"bf16[8,2,512,14336]{_T} %bitcast_multiply_fusion.31, "
+     f"bf16[2,4096,8,512]{_T} %convolution_bitcast_fusion.4), kind=kOutput",
+     True),
+    (f"%fusion.566 = bf16[8,2,512,4096]{_T} fusion(bf16[2,8,512,4096]{_T} "
+     f"%fusion.565, bf16[1,2,4096,14336]{_T} %get-tuple-element.1865, "
+     f"bf16[2,14336,8,512]{_T} %get-tuple-element.1719), kind=kOutput", True),
+    (f"%fusion.545 = bf16[8,2,512,4096]{_T} fusion(bf16[8,512,4096,1]{_T} "
+     f"%bitcast.964, bf16[8,512,2,512]{_T} %multiply_reduce_fusion.15), "
+     "kind=kOutput", False),                       # the dispatch einsum
+]
+SORTED_LINES = [  # the sorted pairs: activations (T·k, f)
+    (f"%gmm.8 = bf16[8192,14336]{_T} custom-call(s32[] %fusion.634, "
+     f"s32[17] %copy-done.137, bf16[8192,4096]{_T} "
+     f"%broadcast_select_fusion.24, bf16[2,4096,14336]{_T} %bitcast.795), "
+     'custom_call_target="tpu_custom_call"', True),
+    (f"%tgmm.1 = bf16[2,4096,14336]{_T} custom-call(s32[] "
+     f"%get-tuple-element.5516, bf16[8192,4096]{_T} %copy-done.20, "
+     f"bf16[8192,14336]{_T} %get-tuple-element.5484), "
+     'custom_call_target="tpu_custom_call"', True),
+    (f"%multiply_multiply_fusion.21 = bf16[8192,14336]{_T} fusion("
+     f"bf16[8192,14336]{_T} %gmm.3, bf16[8192,14336]{_T} %gmm.4), kind=kLoop",
+     True),
+    (f"%bitcast_add_fusion.20 = f32[1,2,4096,14336]{_T} fusion("
+     f"f32[1,2,4096,14336]{_T} %get-tuple-element.5715, "
+     f"bf16[2,4096,14336]{_T} %tgmm.1, pred[] %compare.533), kind=kLoop",
+     False),                                       # sum into the f32 gradient
+    (f"%fusion.672 = bf16[8192,4096]{_T} fusion(bf16[8192,4096]{_T} "
+     "%get-tuple-element.5485, s32[8192] %copy-done.59), kind=kCustom",
+     False),                                       # a gather of rows
+]
+BOTH_LINES = [
+    (f"%add_convert_fusion.8 = (bf16[1,2,4096,14336]{_T}, "
+     f"f32[1,2,4096,14336]{_T}) fusion(bf16[1,2,4096,14336]{_T} %param.319, "
+     f"f32[1,2,4096,14336]{_T} %param.341), kind=kLoop", False),   # AdamW
+    (f"%while.311 = (s32[], f32[1,2,4096,14336]{_T}, bf16[8192,14336]{_T}) "
+     "while((s32[], f32[1,2,4096,14336]) %tuple.1), condition=%c, body=%b",
+     False),
+    (f"%flash_fwd.3 = bf16[1,32,4096,128]{_T} custom-call("
+     f"bf16[1,32,4096,128]{_T} %q), "
+     'custom_call_target="tpu_custom_call"', False),
+]
+
+
+@pytest.mark.parametrize("lines", [SLOT_LINES, SORTED_LINES],
+                         ids=["one-hot-slots", "sorted-pairs"])
+def test_expert_ms_reads_the_expert_ops_of_either_program(lines):
+    """``moe.expert_ms`` on a hand-made profile of three step programs,
+    the last cut by the profile's edge: the self times of the ops that
+    hold an expert activation, a whole step; the same rule reads the
+    program of before PR 30 and the one since."""
+    from benchmarks import run as bench_run
+
+    lines = lines + BOTH_LINES
+    events, want = [], 0
+    for step, start in enumerate((0, 10_000_000, 20_000_000)):
+        at = start + 1000
+        if any(" while(" in line for line, _ in lines):
+            # the accumulation loop holds the step's ops nested inside it
+            loop = next(line for line, _ in lines if " while(" in line)
+            events.append([loop, at, 9_000_000])
+        ops = [t for t in lines if " while(" not in t[0]]
+        for n, (line, expert) in enumerate(ops[:2] if step == 2 else ops):
+            dur = 100_000 * (n + 1)
+            events.append([line, at + 10, dur])
+            at += dur + 10
+            want += dur if expert and step < 2 else 0
+    trace = {"planes": [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Modules", "events": [
+            ["jit_step_fn(1)", s, 9_500_000]
+            for s in (0, 10_000_000, 20_000_000)]},
+        {"name": "XLA Ops", "events": events}]}]}
+    ctx = {"trace_raw": trace, "step_module": "step_fn", "job": {},
+           "fields": {"hidden_size": 4096, "intermediate_size": 14336,
+                      "num_local_experts": 8}}
+    reader = bench_run.load_reader("moe.expert_ms")
+    assert reader.read(ctx) == pytest.approx(want / 1e6 / 2)
+    # a dense configuration has no expert FFN to read
+    dense = {k: v for k, v in ctx["fields"].items()
+             if k != "num_local_experts"}
+    assert reader.read({**ctx, "fields": dense}) is None
+    assert reader.read({**ctx, "trace_raw": None}) is None
