@@ -24,8 +24,27 @@ REHEARSAL_FIELDS = {
     "reference_tolerance": {"loss_rel": 2e-3, "grad_norm_rel": 2e-2},
 }
 
-init_params = moe.init_params
 logical_axes = moe.param_logical_axes
+
+
+def init_params(config, key):
+    """``moe.init_params``, then the embedding rows brought to unit rms
+    (times ``dim ** 0.5``, in the leaf's type; nothing else of the tree
+    moves, and the reference reads the same tree). The published files
+    give no initialisation. The program's default draws the rows at rms
+    ``dim ** -0.5`` where its attention block's output on those weights
+    has 0.07: at depth 1 the router then reads four parts causal mean of
+    the value vectors, nearly one vector for every late token, to one
+    part token, and one chip's experts draw 1.6 to 1.9 times the mean
+    share of the pairs, by the seed. A trained model's residual stream
+    is the token's own and its experts draw near-equal shares
+    (arXiv:2401.04088, routing analysis): with unit rows the router sees
+    the token (``configs/mixtral-8x7b.json`` ``assumed``; PERF.md, PR 32).
+    """
+    params = moe.init_params(config, key)
+    rows = params["tok_embed"]
+    return {**params,
+            "tok_embed": rows * jnp.asarray(config.dim ** 0.5, rows.dtype)}
 
 
 def program_config(fields: dict, seq: int) -> moe.MoEConfig:

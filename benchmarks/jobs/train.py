@@ -435,8 +435,12 @@ def _run(env, cleanup) -> dict:
 
     finite = [n for n, v in losses.items() if not np.isfinite(v)]
     failed += len(finite)
+    tenth = max(1, len(intervals) // 10)
     note("window", steps=steps, saves=saves, window_s=window_s,
          step_samples=len(intervals), compiled_in_window=compiled_in_window,
+         # a step that slows through the window (a routing that drifts)
+         step_ms_first_tenth=1e3 * sum(intervals[:tenth]) / tenth,
+         step_ms_last_tenth=1e3 * sum(intervals[-tenth:]) / tenth,
          trace_overhead_s=tracer.overhead_s, non_finite_steps=finite,
          loss_step_20=losses.get(20), last_loss=losses[max(losses)],
          drains_s=drains_s, save_stalls_s=spans["save.block"],
