@@ -17,14 +17,17 @@ Design for the MXU:
   adds nothing to its token, which then falls through the residual
   connection (standard Switch behavior). One path for every
   ``capacity_factor``;
-- **expert-axis sharding**: every expert tensor carries a leading
-  ``expert`` logical axis → the ``ep`` mesh axis (parallel/sharding.py
-  DEFAULT_RULES). Under a mesh the sorted block runs in a ``shard_map``
-  (a Pallas call has no partitioning rule): a chip sorts its own
-  experts' pairs first and computes them against its local weights, and
-  one all-reduce joins what the chips computed for each token. Every
-  chip of an ``ep`` group holds the same rows, so the group waits for
-  the chip whose experts drew the most pairs;
+- **every expert on every chip, sliced by columns**: the three expert
+  leaves shard their ``ffn_dim`` over the ``ep`` and ``tp`` mesh axes
+  together (the ``expert_mlp`` logical axis, parallel/sharding.py
+  DEFAULT_RULES) and no other dimension but ``embed``. Under a mesh the
+  sorted block runs in a ``shard_map`` (a Pallas call has no
+  partitioning rule): every chip of the group holds the same rows, sorts
+  all pairs into all experts' groups and computes every routed pair over
+  its own columns, and the one all-reduce that sums SwiGLU's partial
+  down projections joins them. The work a chip does is the same
+  whatever the routing: with whole experts on chips the group waits for
+  the chip whose experts drew the most pairs (PERF.md §6, PR 33);
 - **top-k routing with renormalized gates** (Mixtral) + Switch-style
   load-balancing auxiliary loss per routing group, both in f32;
 - attention/norms/RoPE are the Llama blocks (models/llama.py) unchanged —
@@ -46,7 +49,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.models import llama as _llama
-from dlrover_tpu.parallel.sharding import valid_spec_for
+from dlrover_tpu.parallel.sharding import DEFAULT_RULES, valid_spec_for
 
 
 @dataclass(frozen=True)
@@ -95,16 +98,16 @@ class MoEConfig(_llama.AttentionConfigMixin):
 
 def param_logical_axes(config: MoEConfig) -> Dict:
     """Logical sharding axes per param (parallel/sharding.py rules;
-    ``expert`` → ep mesh axis)."""
+    ``expert_mlp`` → the ep and tp mesh axes together)."""
     return {
         "tok_embed": ("vocab", "embed"),
         "layers": {
             **_llama.attention_param_axes(),
             "ffn_norm": ("layers", "norm"),
             "router": ("layers", "embed", None),
-            "w1": ("layers", "expert", "embed", "mlp"),
-            "w3": ("layers", "expert", "embed", "mlp"),
-            "w2": ("layers", "expert", "mlp", "embed"),
+            "w1": ("layers", None, "embed", "expert_mlp"),
+            "w3": ("layers", None, "embed", "expert_mlp"),
+            "w2": ("layers", None, "expert_mlp", "embed"),
         },
         "final_norm": ("norm",),
         "lm_head": ("embed", "vocab"),
@@ -189,15 +192,25 @@ def _route(x_grouped, router, config: MoEConfig, capacity: int):
     return topi, gates, keep, aux
 
 
-# megablox tiles (rows, contraction, columns), chosen by measurement at
-# the expert cell's shapes (8,192 rows of which 2,048 live, 4096 x 14336,
-# two groups; PERF.md §6, PR 30)
+# megablox tiles: the most rows, contraction and columns a tile takes,
+# chosen by measurement at the expert cell's shapes (8,192 live rows in
+# two to eight groups, 4096 x 3584 and 3584 x 4096; PERF.md §6, PR 33)
 _GMM_TILING = (512, 1024, 1024)
 
 
+def _fit(dim: int, most: int) -> int:
+    """The widest tile of whole 128-lane registers, ``most`` at most, that
+    divides ``dim``: megablox masks a remainder tile of the contraction on
+    every visit. ``most`` where none does."""
+    if dim <= most:
+        return dim
+    return next((t for t in range(most, 0, -128) if dim % t == 0), most)
+
+
 def _tiles(k: int, n: int):
+    """Tiles of a grouped ``(m, k) @ (k, n)``."""
     tm, tk, tn = _GMM_TILING
-    return tm, min(tk, k), min(tn, n)
+    return tm, _fit(k, tk), _fit(n, tn)
 
 
 @jax.custom_vjp
@@ -221,9 +234,12 @@ def _gmm_bwd(residuals, g):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
     rows, w, group_sizes = residuals
-    tiles = _tiles(w.shape[1], w.shape[2])
-    d_rows = gmm(g, w, group_sizes, rows.dtype, tiles, transpose_rhs=True)
-    d_w = tgmm(rows.swapaxes(0, 1), g, group_sizes, w.dtype, tiles)
+    k, n = w.shape[1:]
+    # the gradient of the rows contracts over the columns of w
+    d_rows = gmm(
+        g, w, group_sizes, rows.dtype, _tiles(n, k), transpose_rhs=True)
+    d_w = tgmm(
+        rows.swapaxes(0, 1), g, group_sizes, w.dtype, _tiles(k, n))
     return d_rows, d_w, None
 
 
@@ -293,33 +309,29 @@ def _permute_bwd(inverse, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
-def _sort_by_expert(expert, keep, n_local: int, first=0):
-    """Order of the pairs with those of experts ``first .. first +
-    n_local`` first, by expert, and every other pair (dropped, or another
-    chip's) last; and how many each of those experts keeps."""
-    local = expert - first
-    mine = keep & (local >= 0) & (local < n_local)
-    key = jnp.where(mine, local, n_local)
+def _sort_by_expert(expert, keep, n_experts: int):
+    """Order of the pairs by expert, the dropped ones last; and how many
+    each expert keeps."""
+    key = jnp.where(keep, expert, n_experts)
     order = jnp.argsort(key, stable=True)
-    group_sizes = (key[:, None] == jnp.arange(n_local)).sum(
+    group_sizes = (key[:, None] == jnp.arange(n_experts)).sum(
         0, dtype=jnp.int32)
     return order, group_sizes
 
 
-def _routed_rows(x, expert, keep, gates, w1, w3, w2, ep_axis=None):
-    """What the experts in w1, w3, w2 add to every token: all of them, or
-    under ``ep_axis`` this chip's. x (1, B, S, D), this chip's copy of
-    its tokens; expert, keep, gates (B, S, k). Returns the shape of x:
-    this chip's term of the sum over chips."""
+def _routed_rows(x, expert, keep, gates, w1, w3, w2):
+    """What the experts add to every token, over the columns of
+    ``ffn_dim`` that w1, w3, w2 hold: all of them, or under a mesh this
+    chip's. x (1, B, S, D), this chip's copy of its tokens; expert, keep,
+    gates (B, S, k). Returns the shape of x: this chip's term of the sum
+    over the chips that slice the columns."""
     k, D = expert.shape[-1], x.shape[-1]
     # pairs choice-major, pair j·T + t: a choice's rows are one block, so
     # no array is laid out (T, k, D) with k among the tiled dimensions
     by_choice = lambda a: a.reshape(-1, k).T
-    n_local = w1.shape[0]
-    first = jax.lax.axis_index(ep_axis) * n_local if ep_axis else 0
     order, group_sizes = _sort_by_expert(
         by_choice(expert).reshape(-1), by_choice(keep).reshape(-1),
-        n_local, first)
+        w1.shape[0])
     inverse = jnp.argsort(order)
     rows = _permute(jnp.tile(x.reshape(-1, D), (k, 1)), order, inverse)
     out = _expert_ffn(rows, group_sizes, w1, w3, w2)
@@ -341,20 +353,27 @@ def _moe_ffn(x, layer, config: MoEConfig, mesh=None):
     if mesh is not None:
         # a Pallas call has no partitioning rule, so under a mesh the
         # block is manual over all of it: tokens stay where the batch and
-        # sequence axes put them, the experts where ``expert`` and ``mlp``
-        # put their weights. The rows go in as one copy, and the terms
-        # come out one, for each chip whose weights differ, so that the
-        # sum below and its transpose in the backward pass are all-reduces
-        # that GSPMD inserts
+        # sequence axes put them, every expert's columns where
+        # ``expert_mlp`` puts them. The rows go in as one copy, and the
+        # terms come out one, for each chip whose columns differ, so that
+        # the sum below and its transpose in the backward pass are
+        # all-reduces that GSPMD inserts
         tokens = valid_spec_for(mesh, (B, S), ("batch", "seq"))
-        up = valid_spec_for(mesh, layer["w1"].shape, ("expert", None, "mlp"))
-        down = P(up[0], up[2], None)
-        over = tuple(a for a in (up[0], up[2]) if a)
+        over = tuple(
+            a for a in DEFAULT_RULES["expert_mlp"] if a in mesh.shape)
         terms = math.prod(mesh.shape[a] for a in over)
+        if c.ffn_dim % terms:
+            # ``valid_spec_for`` would have replicated the expert leaves
+            # on every chip: a job that no longer fits, not a layout
+            raise ValueError(
+                f"ffn_dim {c.ffn_dim} is not divisible by the {terms} "
+                f"chips of mesh axes {over} that slice every expert's "
+                "columns")
+        up = P(None, None, over)
         experts = jax.shard_map(
-            functools.partial(_routed_rows, ep_axis=up[0]), mesh=mesh,
+            _routed_rows, mesh=mesh,
             in_specs=(P(over, *tokens), P(*tokens), P(*tokens), P(*tokens),
-                      up, up, down),
+                      up, up, P(None, over, None)),
             out_specs=P(over, *tokens), check_vma=False,
         )
     with jax.named_scope("moe_experts"):

@@ -12,7 +12,8 @@ collectives:
 - ``fsdp`` — data parallel with fully-sharded params/opt state (ZeRO-3)
 - ``sp``   — sequence/context parallel (ring attention axis, long context)
 - ``tp``   — tensor parallel (innermost: highest-bandwidth ICI neighbors)
-- ``ep``   — expert parallel for MoE layers (groups experts across hosts)
+- ``ep``   — expert parallel for MoE layers (the chips the experts' work is
+  spread over: each holds every expert at a slice of its columns)
 - ``pp``   — pipeline stages (outer: least traffic between stages)
 
 Elastic re-mesh policy: ``tp``/``pp``/``ep`` are fixed by the model shapes;

@@ -13,7 +13,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 # logical axis name → mesh axis (or None = replicate).
 # "batch" spreads over both data axes; "embed" (the hidden dim of params)
 # shards over fsdp (ZeRO-3-style); "heads"/"mlp" shard over tp; "vocab"
-# over tp (output projection all-gathers logits); "expert" over ep;
+# over tp (output projection all-gathers logits); "expert_mlp", the FFN
+# width of an expert leaf, over ep and tp together: every chip of an ep
+# group holds every expert at a slice of its columns, so the group's
+# work is even whatever the routing (models/moe.py);
 # "seq" over sp (ring attention axis); "layers"/"stage" over pp.
 DEFAULT_RULES: Dict[str, Optional[object]] = {
     "batch": ("dcn", "dp", "fsdp"),
@@ -23,7 +26,7 @@ DEFAULT_RULES: Dict[str, Optional[object]] = {
     "kv_heads": "tp",
     "mlp": "tp",
     "vocab": "tp",
-    "expert": "ep",
+    "expert_mlp": ("ep", "tp"),
     "stage": "pp",
     # depth-stacked layer params live stage-major: the leading layer dim
     # shards over pp so pipeline_apply's shard_map in_spec P("pp") is

@@ -145,9 +145,10 @@ def test_restore_rebuilds_a_large_leaf_in_its_own_bytes(topo, shape, dtype):
 def test_expert_layer_over_ep4_runs_the_grouped_kernel(topo, monkeypatch):
     """``moe._moe_ffn`` at Mixtral-8x7B widths over ``ep`` 4, forward and
     backward: every projection and each of its two gradients is one
-    grouped-matmul kernel over the ``T·k`` sorted rows, inside a
-    ``shard_map`` (a Mosaic call cannot be partitioned by GSPMD), and no
-    array of the program is laid out by expert slots."""
+    grouped-matmul kernel over the ``T·k`` sorted rows at a quarter of
+    every expert's columns, inside a ``shard_map`` (a Mosaic call cannot
+    be partitioned by GSPMD); no array of the program is laid out by
+    expert slots, and the chips exchange nothing but all-reduces."""
     # the layer picks its grouped matmul from the default backend, which
     # is the CPU here: steer it, as the chip would
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -155,13 +156,15 @@ def test_expert_layer_over_ep4_runs_the_grouped_kernel(topo, monkeypatch):
         n_layers=1, capacity_factor=4.0, route_group_size=512,
         max_seq_len=4096)
     mesh = build_mesh(plan_mesh(4, ep=4), devices=list(topo.devices))
-    whole, by_expert = NamedSharding(mesh, P()), NamedSharding(mesh, P("ep"))
+    whole = NamedSharding(mesh, P())
+    by_column = NamedSharding(mesh, P(None, None, "ep"))
     E, D, F, T = cfg.n_experts, cfg.dim, cfg.ffn_dim, 4096
     layer = {
         "router": _shape((D, E), jnp.float32, whole),
-        "w1": _shape((E, D, F), jnp.bfloat16, by_expert),
-        "w3": _shape((E, D, F), jnp.bfloat16, by_expert),
-        "w2": _shape((E, F, D), jnp.bfloat16, by_expert),
+        "w1": _shape((E, D, F), jnp.bfloat16, by_column),
+        "w3": _shape((E, D, F), jnp.bfloat16, by_column),
+        "w2": _shape((E, F, D), jnp.bfloat16,
+                     NamedSharding(mesh, P(None, "ep", None))),
     }
     x = _shape((1, T, D), jnp.bfloat16, whole)
 
@@ -175,11 +178,17 @@ def test_expert_layer_over_ep4_runs_the_grouped_kernel(topo, monkeypatch):
     # three projections x (forward, gradient of the rows, of the weights)
     assert len(calls) == 9, calls
     assert sum("tgmm" in name for name in calls) == 3, calls
-    rows = T * cfg.top_k
-    assert f"bf16[{rows},{F}]" in text
+    rows, columns = T * cfg.top_k, F // 4
+    assert f"bf16[{rows},{columns}]" in text
+    assert f"bf16[{E},{D},{columns}]" in text     # every expert, sliced
+    assert f"bf16[{rows},{F}]" not in text
     slots = moe.expert_capacity(cfg, 1, T)
     assert f"{F},{T // 512},{slots}]" not in text     # the old (e,f,g,c)
     assert f"{slots},{F}]" not in text
+    for other in ("all-gather", "all-to-all", "collective-permute",
+                  "reduce-scatter"):
+        assert f" {other}(" not in text and f" {other}-start(" not in text
+    assert " all-reduce" in text
 
 
 def test_train_step_one_layer_fits_the_chip(topo, monkeypatch):

@@ -322,6 +322,119 @@ class TestExpertParallel:
         )
 
 
+MESHES = [{"ep": 4}, {"ep": 2, "tp": 2}]
+MESH_IDS = ["ep4xfsdp2", "ep2xtp2xfsdp2"]
+
+
+class TestSlicedExperts:
+    """Every chip of an ``ep`` group holds every expert at a slice of its
+    columns (PR 33), where until then it held whole experts."""
+
+    @pytest.mark.parametrize("axes", MESHES, ids=MESH_IDS)
+    @pytest.mark.parametrize("leaf", ["w1", "w3", "w2"])
+    def test_a_device_holds_every_expert_at_its_columns(self, leaf, axes):
+        c = _tiny()
+        mesh = build_mesh(plan_mesh(8, **axes))
+        params = moe.init_params(c, jax.random.PRNGKey(0))
+        sharded = shard_tree(mesh, params, moe.param_logical_axes(c))
+        whole = params["layers"][leaf]
+        slices, fsdp = mesh.shape["ep"] * mesh.shape["tp"], mesh.shape["fsdp"]
+        columns, embed = c.ffn_dim // slices, c.dim // fsdp
+        local = ((c.n_layers, c.n_experts, columns, embed) if leaf == "w2"
+                 else (c.n_layers, c.n_experts, embed, columns))
+        # what a device held with whole experts on chips: n_experts / ep
+        # of them, at ffn_dim / tp columns
+        placed = (c.n_layers * (c.n_experts // mesh.shape["ep"]) * embed
+                  * (c.ffn_dim // mesh.shape["tp"]) * whole.dtype.itemsize)
+        shards = sharded["layers"][leaf].addressable_shards
+        assert len(shards) == 8
+        for shard in shards:
+            assert shard.data.shape == local, shard.device
+            assert shard.data.nbytes == placed
+        # and the slices are distinct columns: together the whole leaf
+        assert len({tuple((i.start, i.stop) for i in s.index)
+                    for s in shards}) == slices * fsdp
+        np.testing.assert_array_equal(
+            np.asarray(sharded["layers"][leaf]), np.asarray(whole))
+
+    def test_a_collapsed_routing_costs_every_chip_the_same(self, monkeypatch):
+        """The router rigged so that every token chooses the same two
+        experts (all logits equal: ``top_k`` takes the first two), which
+        with whole experts on chips left two chips of four idle: loss and
+        every gradient leaf equal the unsharded ones, and every device
+        computes the same ``group_sizes``."""
+        c = _tiny(capacity_factor=2.0)       # dropless: nothing is masked
+        mesh = build_mesh(plan_mesh(8, ep=4))
+        params = moe.init_params(c, jax.random.PRNGKey(0))
+        params["layers"]["router"] = jnp.zeros_like(
+            params["layers"]["router"])
+        tokens = jax.random.randint(
+            jax.random.PRNGKey(1), (2, 33), 0, c.vocab_size)
+        seen = []
+        real = moe._expert_ffn
+
+        def watched(rows, group_sizes, *weights):
+            jax.debug.callback(
+                lambda g: seen.append(tuple(int(n) for n in g)), group_sizes)
+            return real(rows, group_sizes, *weights)
+
+        ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+            lambda p, t: moe.next_token_loss(p, t, c)))(params, tokens)
+        monkeypatch.setattr(moe, "_expert_ffn", watched)
+        sharded = shard_tree(mesh, params, moe.param_logical_axes(c))
+        tok_s = jax.device_put(
+            tokens, NamedSharding(mesh, P(("dp", "fsdp"), None)))
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda p, t: moe.next_token_loss(p, t, c, mesh)))(sharded, tok_s)
+        jax.effects_barrier()
+        tol = dict(atol=2e-3, rtol=2e-3)    # test_ep_sharded_matches_unsharded
+        np.testing.assert_allclose(float(loss), float(ref_loss), **tol)
+        for (path, g), ref in zip(
+                jax.tree_util.tree_leaves_with_path(grads),
+                jax.tree.leaves(ref_grads)):
+            np.testing.assert_allclose(
+                np.asarray(g), np.asarray(ref),
+                err_msg=jax.tree_util.keystr(path), **tol)
+        # a layer's call on each of 8 devices, one sequence of 32 tokens
+        # a data replica: both choices of every token on experts 0 and 1
+        assert len(seen) >= 8 * c.n_layers
+        assert set(seen) == {(32, 32, 0, 0)}
+
+    @pytest.mark.parametrize("axes", MESHES, ids=MESH_IDS)
+    def test_columns_that_the_chips_do_not_divide_are_refused(self, axes):
+        c = _tiny(ffn_dim=90)               # 90 / 4 is no whole number
+        mesh = build_mesh(plan_mesh(8, **axes))
+        layer = _layer(c, jax.random.PRNGKey(5))
+        x = jnp.zeros((4, 32, c.dim))
+        with pytest.raises(ValueError, match=r"ffn_dim 90 .*ep.*tp"):
+            moe._moe_ffn(x, layer, c, mesh)
+        # two chips divide it
+        moe._moe_ffn(x, layer, c, build_mesh(plan_mesh(8, ep=2)))
+
+    @pytest.mark.parametrize("k,n", [
+        (4096, 3584),     # w1, w3 of Mixtral-8x7B over ep 4; tgmm
+        (3584, 4096),     # w2, where the sliced width is contracted
+        (4096, 14336),    # the unsliced widths, as decode on one chip
+        (14336, 4096),
+        (4096, 7168),     # over ep 2
+        (7168, 4096),
+        (64, 24),         # smaller than a tile: the whole dimension
+    ])
+    def test_the_tiles_divide_the_widths_of_the_grouped_matmuls(self, k, n):
+        """A contraction tile that does not divide its width is masked on
+        every visit of the kernel: the tiles are whole 128-lane registers
+        that divide, as a function of the shapes alone."""
+        tm, tk, tn = moe._tiles(k, n)
+        most = moe._GMM_TILING
+        assert tm == most[0]
+        for tile, dim, cap in ((tk, k, most[1]), (tn, n, most[2])):
+            assert dim % tile == 0 and tile <= cap
+            assert tile == dim or tile % 128 == 0
+            # and no wider tile under the cap divides
+            assert not any(dim % t == 0
+                           for t in range(tile + 128, min(cap, dim) + 1, 128))
+
+
 def test_cross_entropy_matches_log_softmax_gather():
     """The logsumexp-gather formulation (llama.cross_entropy) is the
     log_softmax+gather NLL with the (B,S,V) logp intermediate elided —
@@ -401,8 +514,11 @@ BOTH_LINES = [
 def test_expert_ms_reads_the_expert_ops_of_either_program(lines):
     """``moe.expert_ms`` on a hand-made profile of three step programs,
     the last cut by the profile's edge: the self times of the ops that
-    hold an expert activation, a whole step; the same rule reads the
-    program of before PR 30 and the one since."""
+    hold an expert activation, a whole step, on the one chip profiled
+    (the reader gives the largest over the chips); the same rule reads
+    the program of before PR 30 and the one of PR 30 to PR 32. Since
+    PR 33 a chip's arrays are ``intermediate_size / (ep x tp)`` wide and
+    the rule finds none: ``moe.gmm_ms`` reads the kernels by name."""
     from benchmarks import run as bench_run
 
     lines = lines + BOTH_LINES
@@ -434,3 +550,81 @@ def test_expert_ms_reads_the_expert_ops_of_either_program(lines):
              if k != "num_local_experts"}
     assert reader.read({**ctx, "fields": dense}) is None
     assert reader.read({**ctx, "trace_raw": None}) is None
+
+
+# the kernels' own lines under either layout of the expert leaves over
+# ep 4: whole experts on chips (two of eight, every column) and every
+# expert at a quarter of its columns (PR 33)
+_PALLAS = 'custom_call_target="tpu_custom_call"'
+KERNEL_LINES = {
+    "whole-experts": (
+        f"%gmm.8 = bf16[8192,14336]{_T} custom-call(s32[17] %copy-done.137, "
+        f"bf16[8192,4096]{_T} %fusion.24, bf16[2,4096,14336]{_T} "
+        f"%bitcast.795), {_PALLAS}",
+        f"%tgmm.1 = bf16[2,4096,14336]{_T} custom-call(bf16[8192,4096]{_T} "
+        f"%copy-done.20, bf16[8192,14336]{_T} %get-tuple-element.5484), "
+        f"{_PALLAS}"),
+    "sliced-columns": (
+        f"%gmm.8 = bf16[8192,3584]{_T} custom-call(s32[17] %copy-done.137, "
+        f"bf16[8192,4096]{_T} %fusion.24, bf16[8,4096,3584]{_T} "
+        f"%bitcast.795), {_PALLAS}",
+        f"%tgmm.1 = bf16[8,4096,3584]{_T} custom-call(bf16[8192,4096]{_T} "
+        f"%copy-done.20, bf16[8192,3584]{_T} %get-tuple-element.5484), "
+        f"{_PALLAS}"),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(KERNEL_LINES))
+def test_gmm_ms_reads_the_grouped_matmuls_by_name(layout, capsys):
+    """``moe.gmm_ms`` on a hand-made profile of four chips, three step
+    programs each, the last cut by the profile's edge: the ``gmm`` and
+    ``tgmm`` kernels' milliseconds a whole step on the chip where they
+    take longest, whatever the width of a chip's arrays; not the op that
+    takes a kernel's result, not another Pallas call."""
+    import json
+
+    from benchmarks import run as bench_run
+
+    gmm, tgmm = KERNEL_LINES[layout]
+    others = [BOTH_LINES[2][0],                    # the flash kernel
+              f"%multiply_fusion.2 = bf16[8192,3584]{_T} fusion("
+              f"bf16[8192,3584]{_T} %gmm.8), kind=kLoop"]
+    gmm_ns = (500_000, 1_500_000, 1_000_000, 1_000_000)     # by chip
+    planes = []
+    for chip, ns in enumerate(gmm_ns):
+        events = []
+        for step, start in enumerate((0, 10_000_000, 20_000_000)):
+            at = start + 1000
+            ops = [(gmm, ns), (gmm, ns), (tgmm, 2 * ns)] + [
+                (line, 400_000) for line in others]
+            for line, dur in ops[:1] if step == 2 else ops:
+                events.append([line, at, dur])
+                at += dur + 10
+        planes.append({"name": f"/device:TPU:{chip}", "lines": [
+            {"name": "XLA Modules", "events": [
+                ["jit_step_fn(1)", s, 9_500_000]
+                for s in (0, 10_000_000, 20_000_000)]},
+            {"name": "XLA Ops", "events": events}]})
+    ctx = {"trace_raw": {"planes": planes}, "step_module": "step_fn",
+           "job": {}, "fields": {"hidden_size": 4096,
+                                 "intermediate_size": 14336,
+                                 "num_local_experts": 8}}
+    read = bench_run.load_reader("moe.gmm_ms").read
+    by_chip = [4 * ns / 1e6 for ns in gmm_ns]
+    assert read(ctx) == pytest.approx(max(by_chip))
+    note = next(n for n in map(
+        json.loads, capsys.readouterr().out.splitlines())
+        if n["note"] == "gmm_ms_by_chip")
+    assert note["chips"] == pytest.approx(by_chip)
+    assert note["calls_a_step"] == [3] * 4
+    assert note["largest_over_smallest"] == pytest.approx(3.0)
+    # the readers of PR 30 and PR 32 find the arrays of the older layout
+    # alone: this one is what keeps the layer in sight
+    wide = bench_run.load_reader("moe.expert_ms").read(ctx)
+    assert (wide is None) == (layout == "sliced-columns")
+    # nothing to read: no trace, or a program without the kernels
+    assert read({**ctx, "trace_raw": None}) is None
+    for plane in planes:
+        plane["lines"][1]["events"] = [
+            e for e in plane["lines"][1]["events"] if "gmm." not in e[0][:8]]
+    assert read(ctx) is None
