@@ -66,7 +66,7 @@ class DLRMConfig:
 def param_logical_axes(config: DLRMConfig) -> Dict:
     """Logical sharding axes (parallel/sharding.py rules).
 
-    The table's row axis maps to ``vocab`` (→ tp) — the mesh-sharded
+    The table's row axis maps to ``vocab`` (→ ep and tp) — the mesh-sharded
     stand-in for the reference's PS partitioner; MLP widths map to
     ``mlp``/``embed`` like the LM FFNs so fsdp/tp lay them out the same
     way.

@@ -26,6 +26,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.common.log import log_once
 from dlrover_tpu.ops.flash_attention import flash_attention
@@ -33,6 +34,11 @@ from dlrover_tpu.parallel.ring_attention import (
     full_causal_attention,
     ring_attention,
     sharded_flash_attention,
+)
+from dlrover_tpu.parallel.sharding import (
+    valid_spec_for,
+    vocab_shards_gauge,
+    vocab_split,
 )
 from dlrover_tpu.parallel.ulysses import ulysses_attention
 
@@ -331,6 +337,16 @@ def lm_head(x, weight):
     )
 
 
+def hidden_states(params: Dict, tokens, config: LlamaConfig, mesh=None):
+    """tokens (B, S) int32 → the normed last hidden states (B, S, D)."""
+    c = config
+    B, S = tokens.shape
+    x = params["tok_embed"][tokens]
+    positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+    x = decoder_stack(x, params["layers"], c, positions, mesh)
+    return _rms_norm(x, params["final_norm"], c.norm_eps)
+
+
 def forward(
     params: Dict,
     tokens,
@@ -338,13 +354,8 @@ def forward(
     mesh=None,
 ):
     """tokens (B, S) int32 → logits (B, S, vocab) f32."""
-    c = config
-    B, S = tokens.shape
-    x = params["tok_embed"][tokens]
-    positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-    x = decoder_stack(x, params["layers"], c, positions, mesh)
-    x = _rms_norm(x, params["final_norm"], c.norm_eps)
-    return lm_head(x, params["lm_head"])
+    return lm_head(
+        hidden_states(params, tokens, config, mesh), params["lm_head"])
 
 
 def token_nll(logits, targets):
@@ -363,20 +374,66 @@ def cross_entropy(logits, targets):
     return token_nll(logits, targets).mean()
 
 
+def _nll_on_shard(x, weight, targets, over):
+    """:func:`token_nll` on one chip of the ``over`` group, which holds
+    the columns ``weight`` (D, V / n) of the head and x (1, B, S, D), its
+    copy of the group's hidden states: f32 logits of its own columns, the
+    log-sum-exp from the group's maximum and its summed exponentials, the
+    target's logit from the one chip whose columns hold it."""
+    logits = lm_head(x[0], weight)                       # (B, S, V / n)
+    top = jax.lax.pmax(
+        jax.lax.stop_gradient(logits.max(axis=-1)), over)
+    lse = top + jnp.log(jax.lax.psum(
+        jnp.exp(logits - top[..., None]).sum(axis=-1), over))
+    columns = weight.shape[1]
+    local = targets - jax.lax.axis_index(over) * columns
+    mine = (local >= 0) & (local < columns)
+    tgt = jnp.take_along_axis(
+        logits, jnp.clip(local, 0, columns - 1)[..., None], axis=-1)[..., 0]
+    return lse - jax.lax.psum(jnp.where(mine, tgt, 0.0), over)
+
+
+def head_nll(x, weight, targets, mesh=None):
+    """Per-token NLL (B, S) f32 of ``targets`` under the output head:
+    ``token_nll(lm_head(x, weight), targets)``. Where the mesh spreads
+    the vocabulary over chips (:func:`vocab_split`) each of them makes
+    the logits of its own columns only, and the (B, S, vocab) logits exist
+    nowhere: the group exchanges two f32 statistics a token for the
+    log-sum-exp and one for the target's logit, and the sum of the
+    hidden states' gradient over its chips (the Megatron-LM
+    vocabulary-parallel cross-entropy, in this file's precision)."""
+    over, chips = vocab_split(mesh, weight.shape[1])
+    vocab_shards_gauge().set(chips)
+    if chips == 1:
+        return token_nll(lm_head(x, weight), targets)
+    # manual over the whole mesh, as the experts and the flash kernel
+    # are: tokens stay where the batch and sequence axes put them. The
+    # hidden states go in as one copy for each chip of the group, so that
+    # the sum of their gradients is an all-reduce that GSPMD inserts
+    tokens = valid_spec_for(mesh, targets.shape, ("batch", "seq"))
+    on_shards = jax.shard_map(
+        functools.partial(_nll_on_shard, over=over), mesh=mesh,
+        in_specs=(P(over, *tokens), P(None, over), P(*tokens)),
+        out_specs=P(*tokens), check_vma=False,
+    )
+    return on_shards(
+        jnp.broadcast_to(x, (chips, *x.shape)), weight, targets)
+
+
 def next_token_loss(params, tokens, config: LlamaConfig, mesh=None):
     """Causal LM loss: predict tokens[1:] from tokens[:-1]."""
-    logits = forward(params, tokens[:, :-1], config, mesh)
-    return cross_entropy(logits, tokens[:, 1:])
+    x = hidden_states(params, tokens[:, :-1], config, mesh)
+    return head_nll(x, params["lm_head"], tokens[:, 1:], mesh).mean()
 
 
-def forward_pp(
+def hidden_states_pp(
     params: Dict,
     tokens,
     config: LlamaConfig,
     mesh,
     n_microbatches: int = 0,
 ):
-    """Pipeline-parallel forward over the mesh's ``pp`` axis
+    """:func:`hidden_states` pipeline-parallel over the mesh's ``pp`` axis
     (parallel/pipeline.py — shard_map + ppermute GPipe schedule).
 
     Stage layout: the cheap, replicable ends (embedding lookup, final
@@ -397,7 +454,7 @@ def forward_pp(
     c = config
     S_pp = mesh.shape["pp"]
     if S_pp <= 1:
-        return forward(params, tokens, config, mesh)
+        return hidden_states(params, tokens, config, mesh)
     B, S = tokens.shape
     M = n_microbatches
     if not M:
@@ -421,17 +478,24 @@ def forward_pp(
         axis="pp", checkpoint_ticks=not c.remat,
         batch_axes=("dcn", "dp", "fsdp"),
     )
-    y = unmicrobatch(ym)
-    y = _rms_norm(y, params["final_norm"], c.norm_eps)
-    return lm_head(y, params["lm_head"])
+    return _rms_norm(unmicrobatch(ym), params["final_norm"], c.norm_eps)
+
+
+def forward_pp(params: Dict, tokens, config: LlamaConfig, mesh,
+               n_microbatches: int = 0):
+    """tokens (B, S) int32 → logits (B, S, vocab) f32, the layers staged
+    over ``pp`` (:func:`hidden_states_pp`)."""
+    return lm_head(
+        hidden_states_pp(params, tokens, config, mesh, n_microbatches),
+        params["lm_head"])
 
 
 def next_token_loss_pp(params, tokens, config: LlamaConfig, mesh,
                        n_microbatches: int = 0):
     """Causal LM loss through the pipeline-parallel forward."""
-    logits = forward_pp(params, tokens[:, :-1], config, mesh,
-                        n_microbatches)
-    return cross_entropy(logits, tokens[:, 1:])
+    x = hidden_states_pp(params, tokens[:, :-1], config, mesh,
+                         n_microbatches)
+    return head_nll(x, params["lm_head"], tokens[:, 1:], mesh).mean()
 
 
 def num_params(config: LlamaConfig) -> int:

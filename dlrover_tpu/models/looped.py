@@ -26,6 +26,7 @@ shared weights' cotangents are summed over the passes in float32
 (``_summed_in_f32``).
 """
 
+import functools
 from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
 
@@ -97,13 +98,13 @@ def _as_stored(shared32, shared):
                         shared32, shared)
 
 
-def _exit_head(h, head, gate, targets):
+def _exit_head(h, head, gate, targets, mesh):
     """One pass's head on the normed state ``h`` (B, S, D): per-token NLL
     of ``targets`` under ``h W_head`` and the exit gate's logit, both f32
     (B, S). Under ``jax.checkpoint`` in the loop: the (B, S, vocab) f32
     logits are made again in the backward pass, never kept."""
     with jax.named_scope("loop_head"):
-        nll = _llama.token_nll(_llama.lm_head(h, head), targets)
+        nll = _llama.head_nll(h, head, targets, mesh)
     with jax.named_scope("loop_gate"):
         logit = jnp.einsum(
             "bsd,d->bs", h.astype(jnp.float32), gate["w"].astype(jnp.float32),
@@ -123,7 +124,8 @@ def loss_and_stats(params, tokens, config: LoopedConfig,
     shared = {k: params[k]
               for k in ("layers", "final_norm", "lm_head", "exit_gate")}
     shared32 = _summed_in_f32(shared)
-    head = jax.checkpoint(_exit_head, prevent_cse=False)
+    head = jax.checkpoint(
+        functools.partial(_exit_head, mesh=mesh), prevent_cse=False)
 
     def one_pass(carry, t):
         h, log_stay, loss, plogp = carry

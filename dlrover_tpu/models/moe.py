@@ -385,13 +385,9 @@ def _moe_ffn(x, layer, config: MoEConfig, mesh=None):
     return out, aux
 
 
-def forward(
-    params: Dict,
-    tokens,
-    config: MoEConfig,
-    mesh=None,
-) -> Tuple[Any, Any]:
-    """tokens (B, S) int32 → (logits (B, S, vocab) f32, aux loss scalar)."""
+def _hidden_states(params: Dict, tokens, config: MoEConfig, mesh=None):
+    """tokens (B, S) int32 → (the normed last hidden states (B, S, D),
+    aux loss scalar)."""
     c = config
     B, S = tokens.shape
     x = params["tok_embed"][tokens]
@@ -419,11 +415,18 @@ def forward(
         scan_fn, (x, jnp.zeros((), jnp.float32)), params["layers"]
     )
     x = _llama.rms_norm(x, params["final_norm"], c.norm_eps)
-    logits = jnp.einsum(
-        "bsd,dv->bsv", x, params["lm_head"],
-        preferred_element_type=jnp.float32,
-    )
-    return logits, aux_sum / c.n_layers
+    return x, aux_sum / c.n_layers
+
+
+def forward(
+    params: Dict,
+    tokens,
+    config: MoEConfig,
+    mesh=None,
+) -> Tuple[Any, Any]:
+    """tokens (B, S) int32 → (logits (B, S, vocab) f32, aux loss scalar)."""
+    x, aux = _hidden_states(params, tokens, config, mesh)
+    return _llama.lm_head(x, params["lm_head"]), aux
 
 
 @functools.partial(jax.jit, static_argnames=("config", "mesh"))
@@ -432,9 +435,9 @@ def next_token_loss(params, tokens, config: MoEConfig, mesh=None):
     process traces and differentiates the model once, however many
     programs hold the loss (a check of the gradient, then the train
     step: a second or two of set-up with the experts' kernels)."""
-    logits, aux = forward(params, tokens[:, :-1], config, mesh)
-    return _llama.cross_entropy(logits, tokens[:, 1:]) \
-        + config.router_aux_weight * aux
+    x, aux = _hidden_states(params, tokens[:, :-1], config, mesh)
+    nll = _llama.head_nll(x, params["lm_head"], tokens[:, 1:], mesh)
+    return nll.mean() + config.router_aux_weight * aux
 
 
 def num_params(config: MoEConfig) -> Tuple[int, int]:

@@ -10,13 +10,19 @@ from typing import Dict, Optional, Sequence, Tuple
 
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from dlrover_tpu.common.log import log_once
+
 # logical axis name → mesh axis (or None = replicate).
 # "batch" spreads over both data axes; "embed" (the hidden dim of params)
-# shards over fsdp (ZeRO-3-style); "heads"/"mlp" shard over tp; "vocab"
-# over tp (output projection all-gathers logits); "expert_mlp", the FFN
-# width of an expert leaf, over ep and tp together: every chip of an ep
-# group holds every expert at a slice of its columns, so the group's
-# work is even whatever the routing (models/moe.py);
+# shards over fsdp (ZeRO-3-style); "heads"/"mlp" shard over tp; "vocab",
+# the columns of the output head and the rows of the embedding, over ep
+# and tp together: the hidden states are replicated over both, so each
+# chip makes the logits of its own columns and the loss is taken over
+# them where they are, never gathered (models/llama.py head_nll);
+# "expert_mlp", the FFN width of an expert leaf, over the same two:
+# every chip of an ep group holds every expert at a slice of its
+# columns, so the group's work is even whatever the routing
+# (models/moe.py);
 # "seq" over sp (ring attention axis); "layers"/"stage" over pp.
 DEFAULT_RULES: Dict[str, Optional[object]] = {
     "batch": ("dcn", "dp", "fsdp"),
@@ -25,7 +31,7 @@ DEFAULT_RULES: Dict[str, Optional[object]] = {
     "heads": "tp",
     "kv_heads": "tp",
     "mlp": "tp",
-    "vocab": "tp",
+    "vocab": ("ep", "tp"),
     "expert_mlp": ("ep", "tp"),
     "stage": "pp",
     # depth-stacked layer params live stage-major: the leading layer dim
@@ -66,7 +72,7 @@ def tree_shardings(mesh, logical_tree, rules: Optional[Dict] = None):
     )
 
 
-def _axis_size(mesh, axis) -> int:
+def axis_size(mesh, axis) -> int:
     if axis is None:
         return 1
     if isinstance(axis, (tuple, list)):
@@ -87,7 +93,7 @@ def valid_spec_for(mesh, shape, logical_axes: Sequence[Optional[str]],
     spec = clamp_spec(mesh, spec_for(logical_axes, rules))
     cleaned = []
     for dim, axis in zip(shape, spec):
-        size = _axis_size(mesh, axis)
+        size = axis_size(mesh, axis)
         cleaned.append(axis if (size > 1 and dim % size == 0) else
                        (axis if size == 1 else None))
     return P(*cleaned)
@@ -110,6 +116,38 @@ def clamp_spec(mesh, spec: P) -> P:
         return entry if entry in mesh.shape else None
 
     return P(*[keep(e) for e in spec])
+
+
+def vocab_split(mesh, vocab_size: int):
+    """(mesh axes, chips) that the ``vocab`` rule spreads a vocabulary of
+    this size over on this mesh: ``(None, 1)`` without a mesh, on a mesh
+    whose ``vocab`` axes all have size 1, and, said once, where their
+    product does not divide the vocabulary (``valid_spec_for`` then keeps
+    the head and the embedding whole on every chip)."""
+    if mesh is None:
+        return None, 1
+    over = valid_spec_for(mesh, (vocab_size,), ("vocab",))[0]
+    chips, wanted = (axis_size(mesh, axes)
+                     for axes in (over, DEFAULT_RULES["vocab"]))
+    if chips != wanted:
+        log_once(
+            "output head: vocabulary %s is not divisible by the %s chips "
+            "of mesh axes %s — head, embedding and logits stay whole on "
+            "every chip", vocab_size, wanted, DEFAULT_RULES["vocab"],
+        )
+    return (over, chips) if chips > 1 else (None, 1)
+
+
+def vocab_shards_gauge():
+    """``dlrover_head_vocab_shards``: set by the loss as it is traced
+    (models/llama.py ``head_nll``), read by the trainer into the
+    ``train.step`` span once its step is built."""
+    from dlrover_tpu.observability.registry import get_registry
+
+    return get_registry().gauge(
+        "dlrover_head_vocab_shards",
+        "Chips the output head's vocabulary is split over in the loss "
+        "traced last (1: every chip makes the whole logits)")
 
 
 def shard_tree(mesh, state, logical_tree, rules: Optional[Dict] = None):

@@ -25,6 +25,7 @@ from dlrover_tpu.observability import tracing
 from dlrover_tpu.observability.compile_watch import get_watcher
 from dlrover_tpu.observability.memory import get_accountant
 from dlrover_tpu.parallel.mesh import ElasticMeshManager, MeshPlan, plan_mesh
+from dlrover_tpu.parallel.sharding import vocab_shards_gauge
 
 
 class TrainStepResult(NamedTuple):  # NamedTuple ⇒ a pytree, jit can return it
@@ -224,7 +225,8 @@ class ElasticTrainer:
                      accum_b)
 
     def train_step(self, state, batch):
-        if self._train_step is None:
+        built = self._train_step is None
+        if built:
             self._train_step = self._build_step()
             self._register_state(state)
         shape = tuple(getattr(batch, "shape", ()) or ())
@@ -245,6 +247,13 @@ class ElasticTrainer:
             # the counter is read only to fill the span's attribute
             requests = watcher.compile_requests() if traced else 0
             out = self._train_step(state, batch)
+            if built:
+                # what the loss said as it was traced (models/llama.py
+                # head_nll); 0 where no loss with a head ever was
+                shards = int(vocab_shards_gauge().value)
+                if shards:
+                    self._span_attrs["vocab_shards"] = \
+                        sp.attrs["vocab_shards"] = shards
             if traced:
                 compiles = watcher.compile_requests() - requests
                 if compiles:  # what the backend was asked, cached or not

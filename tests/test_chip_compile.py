@@ -237,3 +237,102 @@ def test_train_step_one_layer_fits_the_chip(topo, monkeypatch):
     assert mem.alias_size_in_bytes > 0.99 * mem.argument_size_in_bytes, (
         "the step must alias its whole donated state")
     assert peak < HBM_BYTES, f"{peak / 2**30:.1f} GiB"
+
+
+COLLECTIVES = ("all-gather", "all-reduce", "all-to-all",
+               "collective-permute", "reduce-scatter")
+
+
+def _collectives(text):
+    """``op result-shape`` of every collective of a compiled program."""
+    found = []
+    for line in text.splitlines():
+        for op in COLLECTIVES:
+            if f" {op}(" in line or f" {op}-start(" in line:
+                found.append(op + " " + line.split(" = ")[1].split(
+                    f" {op}")[0])
+    return found
+
+
+def test_expert_cell_step_never_holds_the_logits_whole(topo, monkeypatch):
+    """The expert cell's whole step (``benchmarks/configs/mixtral-8x7b``:
+    published widths, depth 1, ``ep`` 4, two microbatches of one row of
+    4,096 tokens) with the vocabulary of head and embedding spread over
+    the group: no array of the program is logits-shaped at the whole
+    vocabulary, none has a dimension of ``vocab_size`` at all, nothing
+    vocabulary-sized is gathered, and what the chips exchange is
+    all-reduces only: of hidden states (the experts' sum, the embedding's
+    rows, the head's gradient), of per-token f32 statistics, of the
+    attention gradients and of scalars."""
+    import json
+    import math
+    import os
+    import re
+
+    from benchmarks.families import mixtral_moe as family
+    from dlrover_tpu.parallel.sharding import valid_spec_for
+
+    fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmarks", "configs", "mixtral-8x7b.json")) as f:
+        fields = json.load(f)
+    seq, vocab, hidden = 4096, fields["vocab_size"], fields["hidden_size"]
+    plan = plan_mesh(4, **fields["mesh"])
+    mesh = build_mesh(plan, devices=list(topo.devices))
+    cfg = family.program_config(fields, seq)
+    optimizer = optax.adamw(3e-4)
+    shapes = jax.eval_shape(
+        lambda: family.init_params(cfg, jax.random.PRNGKey(0)))
+    is_axes = lambda x: isinstance(x, tuple) and all(  # noqa: E731
+        isinstance(n, (str, type(None))) for n in x)
+    on_mesh = jax.tree.map(
+        lambda axes, leaf: NamedSharding(
+            mesh, valid_spec_for(mesh, leaf.shape, axes)),
+        family.logical_axes(cfg), shapes, is_leaf=is_axes)
+    assert on_mesh["lm_head"].shard_shape((hidden, vocab)) \
+        == (hidden, vocab // 4)
+    whole = NamedSharding(mesh, P())
+    state = jax.eval_shape(lambda: make_train_state(shapes, optimizer))
+    # the moments lie as their parameters do, the counters on every chip
+    state = jax.tree.map(
+        lambda x: _shape(x.shape, x.dtype, whole), state)
+    like = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x, s: _shape(x.shape, x.dtype, s), tree, on_mesh)
+    adam = state["opt_state"][0]
+    state["opt_state"] = (
+        adam._replace(mu=like(adam.mu), nu=like(adam.nu)),
+        *state["opt_state"][1:])
+    state["params"] = like(state["params"])
+    trainer = ElasticTrainer(
+        loss_fn=family.loss_fn(cfg, mesh), optimizer=optimizer,
+        global_batch_size=2, micro_batch_per_replica=1)
+    trainer.configure_for_world(plan)
+    tokens = _shape((2, 1, seq + 1), jnp.int32, whole)
+    text = trainer._build_step().lower(state, tokens).compile().as_text()
+
+    found = _collectives(text)
+    said = "collectives of the step:\n  " + "\n  ".join(found)
+    logits = re.findall(rf"\w+\[(?:\d+,)*{seq},{vocab}\]", text)
+    assert not logits, f"logits-shaped arrays {sorted(set(logits))}\n{said}"
+    wide = re.findall(rf"\w+\[(?:\d+,)*{vocab}(?:,\d+)*\]", text)
+    assert not wide, f"vocabulary-sized arrays {sorted(set(wide))}\n{said}"
+    assert f"f32[1,{seq},{vocab // 4}]" in text, said   # a chip's logits
+    assert all(c.startswith("all-reduce ") for c in found), said
+    # hidden states: the experts' sum forward and backward, the
+    # embedding's rows, the head's gradient
+    hidden_sums = [c for c in found
+                   if c.startswith(f"all-reduce bf16[1,{seq},{hidden}]")]
+    assert len(hidden_sums) == 4, said
+    # the head's statistics: maximum, summed exponentials, target's logit
+    assert sum(c.count(f"f32[1,{seq}]") for c in found) >= 3, said
+    # and nothing else as large as a token's hidden state but the
+    # attention gradients that the flash kernel's shard_map sums
+    for c in found:
+        if c in hidden_sums or f"bf16[1,32,{seq},128]" in c:
+            continue
+        sizes = [math.prod(int(d) for d in dims.split(",") if d)
+                 for dims in re.findall(r"\[([\d,]*)\]", c)]
+        assert max(sizes, default=1) <= 2 * seq, f"{c}\n{said}"

@@ -1,5 +1,7 @@
 """MoE model + expert-parallel tests on the virtual 8-device mesh."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,7 +9,8 @@ import optax
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from dlrover_tpu.models import moe
+from dlrover_tpu.models import llama, moe
+from dlrover_tpu.parallel import sharding
 from dlrover_tpu.parallel.mesh import build_mesh, plan_mesh
 from dlrover_tpu.parallel.sharding import shard_tree
 
@@ -238,9 +241,9 @@ class TestMoEModel:
         # train step after a check of the gradient) reuses the first's
         # trace and its differentiation
         calls = []
-        real = moe.forward
+        real = moe._hidden_states
         monkeypatch.setattr(
-            moe, "forward",
+            moe, "_hidden_states",
             lambda *a, **k: calls.append(1) or real(*a, **k))
         c = _tiny(vocab_size=257)         # a signature no other test has
         params = moe.init_params(c, jax.random.PRNGKey(0))
@@ -433,6 +436,185 @@ class TestSlicedExperts:
             # and no wider tile under the cap divides
             assert not any(dim % t == 0
                            for t in range(tile + 128, min(cap, dim) + 1, 128))
+
+
+# -- the output head, its vocabulary spread over ep and tp (PR 34) -----------
+
+HEAD_MESHES = {"ep4xfsdp2": {"ep": 4}, "ep2xtp2xfsdp2": {"ep": 2, "tp": 2},
+               "tp2xfsdp4": {"tp": 2}}
+HEAD_MESH_IDS = sorted(HEAD_MESHES)
+MODEL_LEAVES = ["tok_embed", "lm_head", "final_norm"] + [
+    f"layers/{name}" for name in (
+        "attn_norm", "wq", "wk", "wv", "wo", "ffn_norm", "router",
+        "w1", "w3", "w2")]
+_HEAD_TOKENS = jax.random.randint(jax.random.PRNGKey(1), (4, 33), 0, 256)
+
+
+def _loss_and_grads(c, params, tokens, mesh=None):
+    if mesh is not None:
+        params = shard_tree(mesh, params, moe.param_logical_axes(c))
+        tokens = jax.device_put(
+            tokens, NamedSharding(mesh, P(("dp", "fsdp"), None)))
+    return jax.jit(jax.value_and_grad(
+        lambda p, t: moe.next_token_loss(p, t, c, mesh)))(params, tokens)
+
+
+@functools.lru_cache(maxsize=None)
+def _head_case(mesh_id):
+    """(loss, gradients) of one seeded model without a mesh, and on the
+    mesh of that id: computed once for all the leaves' cases."""
+    c = _tiny(capacity_factor=2.0)       # dropless: nothing is masked
+    params = moe.init_params(c, jax.random.PRNGKey(0))
+    mesh = build_mesh(plan_mesh(8, **HEAD_MESHES[mesh_id]))
+    return (_loss_and_grads(c, params, _HEAD_TOKENS),
+            _loss_and_grads(c, params, _HEAD_TOKENS, mesh))
+
+
+class TestVocabShardedHead:
+    """``DEFAULT_RULES["vocab"]`` spreads the head's columns and the
+    embedding's rows over ``ep`` and ``tp``; ``llama.head_nll`` takes the
+    loss over the logits where they are."""
+
+    @pytest.mark.parametrize("mesh_id", HEAD_MESH_IDS)
+    def test_a_device_holds_its_share_of_the_vocabulary(self, mesh_id):
+        c = _tiny()
+        mesh = build_mesh(plan_mesh(8, **HEAD_MESHES[mesh_id]))
+        sharded = shard_tree(
+            mesh, moe.init_params(c, jax.random.PRNGKey(0)),
+            moe.param_logical_axes(c))
+        share = c.vocab_size // (mesh.shape["ep"] * mesh.shape["tp"])
+        embed = c.dim // mesh.shape["fsdp"]
+        for shard in sharded["lm_head"].addressable_shards:
+            assert shard.data.shape == (embed, share)
+        for shard in sharded["tok_embed"].addressable_shards:
+            assert shard.data.shape == (share, embed)
+        assert sharding.vocab_split(mesh, c.vocab_size)[1] \
+            == c.vocab_size // share
+
+    @pytest.mark.parametrize("mesh_id", HEAD_MESH_IDS)
+    @pytest.mark.parametrize("leaf", ["loss"] + MODEL_LEAVES)
+    def test_loss_and_every_gradient_leaf_equal_the_meshless(
+            self, leaf, mesh_id):
+        (ref_loss, ref_grads), (loss, grads) = _head_case(mesh_id)
+        tol = dict(atol=2e-3, rtol=2e-3)    # test_ep_sharded_matches_unsharded
+        if leaf == "loss":
+            np.testing.assert_allclose(float(loss), float(ref_loss), **tol)
+            return
+        got, want = grads, ref_grads
+        for key in leaf.split("/"):
+            got, want = got[key], want[key]
+        assert float(jnp.abs(want).max()) > 0
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), **tol)
+
+    @pytest.mark.parametrize("mesh_id", HEAD_MESH_IDS)
+    @pytest.mark.parametrize("where", ["first-column", "last-column",
+                                       "shard-edges", "random"])
+    def test_the_targets_logit_comes_from_the_shard_that_holds_it(
+            self, where, mesh_id):
+        mesh = build_mesh(plan_mesh(8, **HEAD_MESHES[mesh_id]))
+        B, S, D, V = 4, 16, 32, 64
+        shards = sharding.vocab_split(mesh, V)[1]
+        edges = jnp.arange(B * S).reshape(B, S) % shards * (V // shards)
+        targets = {
+            "first-column": jnp.zeros((B, S), jnp.int32),
+            "last-column": jnp.full((B, S), V - 1, jnp.int32),
+            # the first column of every shard, and the last of the one
+            # before it
+            "shard-edges": (edges - (jnp.arange(S) % 2)) % V,
+            "random": jax.random.randint(
+                jax.random.PRNGKey(2), (B, S), 0, V),
+        }[where]
+        x = jax.random.normal(jax.random.PRNGKey(3), (B, S, D), jnp.float32)
+        w = jax.random.normal(jax.random.PRNGKey(4), (D, V), jnp.float32)
+
+        def nll(x, w, mesh):
+            return llama.head_nll(x, w, targets, mesh)
+
+        want = nll(x, w, None)
+        got = jax.jit(lambda x, w: nll(x, w, mesh))(x, w)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5)
+        # and so does its gradient: one column of the head a token
+        g_want = jax.grad(lambda w: nll(x, w, None).sum())(w)
+        g_got = jax.jit(jax.grad(lambda w: nll(x, w, mesh).sum()))(w)
+        np.testing.assert_allclose(
+            np.asarray(g_got), np.asarray(g_want), atol=1e-4, rtol=1e-4)
+
+    def test_a_vocabulary_the_chips_do_not_divide_stays_whole(
+            self, monkeypatch):
+        """258 / 4 is no whole number: the head and the embedding stay
+        whole on every chip, the loss is the mesh-less one's, and the log
+        says so once however often the loss is traced."""
+        c = _tiny(vocab_size=258)
+        mesh = build_mesh(plan_mesh(8, ep=4))
+        params = moe.init_params(c, jax.random.PRNGKey(0))
+        sharded = shard_tree(mesh, params, moe.param_logical_axes(c))
+        for name in ("lm_head", "tok_embed"):
+            for shard in sharded[name].addressable_shards:
+                assert c.vocab_size in shard.data.shape
+        from dlrover_tpu.common.log import logger
+
+        lines = []
+        monkeypatch.setattr(
+            logger, "info", lambda msg, *a: lines.append(msg % a))
+        assert sharding.vocab_split(mesh, c.vocab_size) == (None, 1)
+        tokens = _HEAD_TOKENS[:2]
+        ref_loss, ref_grads = _loss_and_grads(c, params, tokens)
+        loss, grads = _loss_and_grads(c, params, tokens, mesh)
+        moe.next_token_loss(sharded, tokens[:, :17], c, mesh)  # traced anew
+        tol = dict(atol=2e-3, rtol=2e-3)
+        np.testing.assert_allclose(float(loss), float(ref_loss), **tol)
+        np.testing.assert_allclose(
+            np.asarray(grads["lm_head"]), np.asarray(ref_grads["lm_head"]),
+            **tol)
+        said = [line for line in lines if "vocabulary 258" in line]
+        assert len(said) == 1, lines
+        assert "4 chips" in said[0] and "stay whole" in said[0]
+
+    def test_the_step_says_how_many_chips_share_the_vocabulary(self):
+        """``dlrover_head_vocab_shards`` and the ``train.step`` span's
+        ``vocab_shards``: 4 once a step is built on ``ep`` 4, 1 on no
+        mesh."""
+        from dlrover_tpu.common.constants import SpanName
+        from dlrover_tpu.observability import tracing
+        from dlrover_tpu.observability.registry import get_registry
+        from dlrover_tpu.trainer.elastic import (
+            ElasticTrainer,
+            make_train_state,
+        )
+
+        c = _tiny(vocab_size=264)         # a signature no other test has
+        optimizer = optax.sgd(0.1)
+        plan = plan_mesh(8, ep=4)
+        tracing.reset_tracer()
+        try:
+            for mesh, rows, want in ((build_mesh(plan), 2, 4), (None, 1, 1)):
+                params = moe.init_params(c, jax.random.PRNGKey(0))
+                if mesh is not None:
+                    params = shard_tree(
+                        mesh, params, moe.param_logical_axes(c))
+                trainer = ElasticTrainer(
+                    loss_fn=lambda p, t, mesh=mesh: moe.next_token_loss(
+                        p, t, c, mesh),
+                    optimizer=optimizer, global_batch_size=2 * rows,
+                    micro_batch_per_replica=1)
+                trainer.configure_for_world(
+                    plan if mesh is not None else plan_mesh(1))
+                state = make_train_state(params, optimizer)
+                batch = _HEAD_TOKENS[:2 * rows, :17].reshape(2, rows, 17)
+                for _ in range(2):
+                    state, result = trainer.train_step(state, batch)
+                assert bool(jnp.isfinite(result.loss))
+                assert get_registry().gauge(
+                    "dlrover_head_vocab_shards").value == want
+                assert f"dlrover_head_vocab_shards {want}" \
+                    in get_registry().render()
+                spans = [sp for sp in tracing.get_tracer().finished_spans()
+                         if sp.name == SpanName.TRAIN_STEP][-2:]
+                assert [sp.attrs["vocab_shards"] for sp in spans] \
+                    == [want, want]
+        finally:
+            tracing.reset_tracer()
 
 
 def test_cross_entropy_matches_log_softmax_gather():
@@ -628,3 +810,22 @@ def test_gmm_ms_reads_the_grouped_matmuls_by_name(layout, capsys):
         plane["lines"][1]["events"] = [
             e for e in plane["lines"][1]["events"] if "gmm." not in e[0][:8]]
     assert read(ctx) is None
+
+
+@pytest.mark.parametrize("layout", ["whole", "quarter"])
+def test_head_ms_reads_the_head_of_either_layout(layout, capsys):
+    """``head.ms`` (PR 34) on ``benchmarks/tests/test_head_ms.py``'s
+    hand-made profile of four chips: the ops whose line holds the logits
+    of ``vocab_size / n`` columns, where every chip makes all of them
+    (``n`` 1) and where each makes a quarter (``n`` 4); the chip where
+    they take longest; nothing for a shape that is no share of this
+    cell's vocabulary."""
+    from benchmarks.tests import test_head_ms as made
+
+    read = made.read
+    assert read(made.ctx_for(made.trace(layout))) == pytest.approx(4400e-6)
+    capsys.readouterr()
+    even = made.trace(layout, slow_chip=None)
+    assert read(made.ctx_for(even)) == pytest.approx(2400e-6)
+    assert read(made.ctx_for(even, vocab_size=made.VOCAB * 8)) is None
+    assert read({**made.ctx_for(even), "trace_raw": None}) is None
