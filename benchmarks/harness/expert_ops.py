@@ -2,12 +2,19 @@
 
 Over the whole step programs of a chip's plane, the self times of the
 ops whose own HLO line (result and operands) holds an expert activation:
-an array with a dimension of ``intermediate_size`` and none of
-``hidden_size``. Every array of the three expert leaves, their gradients
+an array with a dimension of the expert width a chip holds and none of
+``hidden_size``. That width is ``intermediate_size`` over the product of
+the mesh axes that ``DEFAULT_RULES["expert_mlp"]`` names, which
+``jobs/train.py`` puts into ``job["expert_mlp_shards"]`` (PR 35): 14336
+where whole experts sit on chips or there is no mesh, 3584 since PR 33
+spread every expert's columns over ``ep`` 4. A ``job`` without the count
+(the hand-made traces of the older layouts) reads the whole width. Every
+array of the three expert leaves, their gradients
 and their moments holds both widths; a buffer of routed rows or of slots
-holds rows by ``intermediate_size`` (``bf16[2,14336,8,512]`` where the
-program dispatches through one-hot slots, ``bf16[8192,14336]`` where it
-sorts the pairs: rule fixed from a kept trace of each, PR 30). So the ops
+holds rows by that width (``bf16[2,14336,8,512]`` where the program
+dispatches through one-hot slots, ``bf16[8192,14336]`` where it sorts the
+pairs, ``bf16[8192,3584]`` where it sorts them into sliced experts: rule
+fixed from a kept trace of each, PR 30, PR 35). So the ops
 are the expert matmuls, forward, remade under remat and backward, as XLA
 fusions or as grouped-matmul kernels, with the SiLU and the product
 fused into or standing between them; not AdamW over the expert leaves,
@@ -20,10 +27,12 @@ not ``hidden_size``. A step program the profile's edge cut holds fewer
 such ops than the others and is left out, as in
 ``named_kernels.kernel_seconds``.
 
-Since PR 30 a chip computes the pairs routed to its own experts, so the
-chips' readings differ by the routing and the step waits for the largest
-(``moe.expert_ms``); the largest over their mean is
-``moe.hot_chip_ratio``."""
+From PR 30 to PR 32 a chip computed the pairs routed to its own experts,
+so the chips' readings differed by the routing and the step waited for
+the largest (``moe.expert_ms``); the largest over their mean is
+``moe.hot_chip_ratio``. Since PR 33 every chip computes every routed pair
+over its columns, and the ratio guards against a layout in which a chip
+waits again."""
 
 import functools
 import re
@@ -66,7 +75,8 @@ def per_chip_step_ms(ctx) -> Optional[List[List[float]]]:
 
 
 def _cut_into_steps(ctx):
-    ffn = ctx["fields"]["intermediate_size"]
+    ffn = (ctx["fields"]["intermediate_size"]
+           // (ctx.get("job") or {}).get("expert_mlp_shards", 1))
     hidden = ctx["fields"]["hidden_size"]
 
     @functools.lru_cache(maxsize=None)   # a name recurs in every step

@@ -11,8 +11,10 @@ traced or not); what a span has to be set against on the device is read
 from the annotations (the traced steps only), with the ring's help for
 the spans that outlast the profile (``on_profilers_clock``).
 
-The benchmark runs the program in its own process, so the ring is at
-hand. A reader gets nothing (``None``) where there is nothing to read:
+Where the benchmark runs the program in its own process the ring is at
+hand; a job that runs it in child processes (``jobs/resume.py``) hands
+the worker's ring back as ``ctx["ring"]``, each span as
+``Span.to_dict`` gives it. A reader gets nothing (``None``) where there is nothing to read:
 an empty ``ctx["job"]``, tracing switched off, a program that has no
 such span (the parent of the PR that added it), or a ring that has
 dropped spans -- a median over what is left would be over an unknown part
@@ -20,6 +22,7 @@ of the run.
 """
 
 import json
+import types
 from typing import Dict, List, Optional, Tuple
 
 from benchmarks.harness import stats, trace_reduce
@@ -41,6 +44,10 @@ def ring(ctx) -> Optional[list]:
     Each has ``name``, ``start_t``, ``end_t``, ``attrs``, ``trace_id``."""
     if not ctx.get("job"):
         return None
+    if "ring" in ctx:  # a worker's, handed back; None where it had none
+        return ctx["ring"] and sorted(
+            (types.SimpleNamespace(**sp) for sp in ctx["ring"]),
+            key=lambda sp: sp.start_t)
     from dlrover_tpu.observability import tracing
 
     tracer = tracing.get_tracer()

@@ -1,10 +1,13 @@
-"""How unevenly the routing spreads the experts' work over the chips of
-the ``ep`` group: the largest over the chips of ``moe.expert_ms``'s
-per-chip reading (``harness/expert_ops.py``) over their mean. 1.0 where
-every chip's experts drew the same share of the pairs; the step waits
-for the largest, so ``1 - 1 / ratio`` of ``moe.expert_ms`` is what an
-even split would give back. None without a trace, such an op, or a
-second chip.
+"""How unevenly the experts' work lies over the chips of the ``ep``
+group: the largest over the chips of ``moe.expert_ms``'s per-chip reading
+(``harness/expert_ops.py``) over their mean. 1.0 where every chip does
+the same share; the step waits for the largest, so ``1 - 1 / ratio`` of
+``moe.expert_ms`` is what an even split would give back. With whole
+experts on chips (PR 30 to PR 32) it followed the routing and read 1.61;
+since PR 33 every chip computes every routed pair over its own columns,
+so it reads about 1.00 whatever the routing does, and guards against a
+layout in which a chip waits again. None without a trace, such an op, or
+a second chip.
 
 Also prints the note ``expert_ms_by_chip``: each chip's milliseconds a
 step, and the ratio in the first and in the last whole step traced

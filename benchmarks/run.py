@@ -85,6 +85,21 @@ def note(kind, **fields):
     print(json.dumps({"note": kind, **fields}), flush=True)
 
 
+def no_chip(device, cell, args):
+    """Exit code 3 and why, on standard error, where JAX found no TPU (and
+    this is no rehearsal) or fewer chips than the cell asks for; else 0."""
+    if device["platform"] != "tpu" and not args.rehearsal:
+        print(f"no TPU: JAX found {device['platform']!r}. A measurement "
+              "needs the chip; --rehearsal runs the control flow on the "
+              "CPU", file=sys.stderr)
+        return 3
+    if device["found"] < cell["chips"]:
+        print(f"{cell['name']} needs {cell['chips']} chips, JAX found "
+              f"{device['found']}", file=sys.stderr)
+        return 3
+    return 0
+
+
 def main(argv=None):
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--workload", required=True)
@@ -107,49 +122,62 @@ def main(argv=None):
         fields = json.load(f)
     traffic = load_json("traffic", cell["traffic"] + ".json")
 
+    # a job whose traffic says that the program runs in child processes
+    # (an agent and its workers) keeps this process off the chip: a
+    # process that has touched it keeps it from every child. Platform,
+    # device, trace, spans and registry are then the worker's own, handed
+    # back in the job's result
+    in_children = traffic.get("program_runs_in") == "child processes"
+
     if args.rehearsal:
         os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "") +
             f" --xla_force_host_platform_device_count={cell['chips']}")
     sys.path.insert(0, ROOT)
-    try:
-        family = load_family(fields["family"])
-    except FamilyContractError as e:
-        print(e, file=sys.stderr)
-        return 2
-    import jax
+    family = device = None
+    if not in_children:
+        try:
+            family = load_family(fields["family"])
+        except FamilyContractError as e:
+            print(e, file=sys.stderr)
+            return 2
+        import jax
 
-    found = jax.devices()
-    device = {"platform": found[0].platform, "kind": found[0].device_kind,
-              "count": cell["chips"]}
-    if device["platform"] != "tpu" and not args.rehearsal:
-        print(f"no TPU: JAX found {device['platform']!r}. A measurement "
-              "needs the chip; --rehearsal runs the control flow on the "
-              "CPU", file=sys.stderr)
-        return 3
-    if len(found) < cell["chips"]:
-        print(f"{args.workload} needs {cell['chips']} chips, JAX found "
-              f"{len(found)}", file=sys.stderr)
-        return 3
+        found = jax.devices()
+        device = {"platform": found[0].platform,
+                  "kind": found[0].device_kind, "found": len(found)}
+        refused = no_chip(device, cell, args)
+        if refused:
+            return refused
+        if args.rehearsal:
+            fields = {**fields, **family.REHEARSAL_FIELDS}
 
-    if args.rehearsal:
-        fields = {**fields, **family.REHEARSAL_FIELDS}
     job = importlib.import_module("benchmarks.jobs." + traffic["job"])
     result = job.run({
         "args": args, "cell": cell, "fields": fields, "traffic": traffic,
         "family": family, "t_start": T_START, "note": note, "root": ROOT,
     })
+    if in_children:
+        device = result["device"]  # as the first worker's JAX reported it
+        refused = no_chip(device, cell, args)
+        if refused:
+            return refused
+        fields = result["fields"]  # with the rehearsal's widths laid over
 
     peaks = None
     from benchmarks.harness import peaks as peaks_table, trace_reduce
 
     if device["platform"] == "tpu":
         peaks = peaks_table.lookup(device["kind"])  # unknown: an error
-    used = found[:cell["chips"]]
-    device["memory_peak_bytes"] = max(
-        int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
-        for d in used)
+    device = {"platform": device["platform"], "kind": device["kind"],
+              "count": cell["chips"]}
+    if in_children:
+        device["memory_peak_bytes"] = result["memory_peak_bytes"]
+    else:
+        device["memory_peak_bytes"] = max(
+            int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in found[:cell["chips"]])
 
     out = {"correct": result["correct"], "attempted": result["attempted"],
            "failed": result["failed"], "metrics": {}, "device": device}
@@ -194,6 +222,12 @@ def main(argv=None):
             print("the traced run holds no device operation",
                   file=sys.stderr)
             return 4
+    # every number compared beside its limit: the last lines of standard
+    # error, and the result's last key
+    out["compared"] = result.get("compared", {})
+    for name, (value, limit) in out["compared"].items():
+        print(f"compared {name}: {value!r} limit {limit!r}",
+              file=sys.stderr)
     print(json.dumps(out), flush=True)
     return 0
 

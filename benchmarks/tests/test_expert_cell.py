@@ -226,6 +226,59 @@ def test_an_even_split_reads_one_and_no_trace_reads_nothing():
     assert bench_run.load_reader("moe.expert_ms").read(dense) is None
 
 
+def sliced(trace):
+    """The same profile as the program of PR 33 writes it: every chip
+    holds all eight experts at a quarter of their columns, so a buffer of
+    routed rows is ``bf16[8192,3584]`` and an expert leaf
+    ``bf16[8,4096,3584]``."""
+    import json
+
+    return json.loads(json.dumps(trace).replace(
+        "[1,2,4096,14336]", "[8,4096,3584]").replace("14336", "3584"))
+
+
+@pytest.mark.parametrize("shards,layout,reads", [
+    (4, "sliced", True),     # the expert cell since PR 33
+    (None, "sliced", False),  # the rule of PR 30 to PR 34 on it: silent
+    (4, "whole", False),     # the local width finds no whole-width array
+    (1, "whole", True),      # one chip, or whole experts on chips
+], ids=["local-width", "whole-width-rule-on-sliced-columns",
+        "local-width-rule-on-whole-experts", "no-mesh"])
+def test_the_expert_rule_takes_the_width_a_chip_holds(shards, layout, reads):
+    """``harness/expert_ops.py`` looks for ``intermediate_size`` over
+    ``job["expert_mlp_shards"]`` (``jobs/train.py``: the product of the
+    mesh axes ``DEFAULT_RULES["expert_mlp"]`` names): both expert readers
+    read ``bf16[8192,3584]`` rows in a four-plane trace of the sliced
+    layout, and AdamW over ``bf16[8,4096,3584]`` stays no expert op."""
+    trace = four_planes()
+    ctx = reader_ctx(sliced(trace) if layout == "sliced" else trace)
+    if shards is not None:
+        ctx["job"] = {"expert_mlp_shards": shards}
+    by_chip = [(ns + 200_000) / 1e6 for ns in GMM_NS]
+    expert_ms = bench_run.load_reader("moe.expert_ms").read(ctx)
+    ratio = bench_run.load_reader("moe.hot_chip_ratio").read(ctx)
+    if reads:
+        assert expert_ms == pytest.approx(max(by_chip))
+        assert ratio == pytest.approx(max(by_chip) / (sum(by_chip) / CHIPS))
+    else:
+        assert expert_ms is None and ratio is None
+
+
+def test_the_job_says_how_many_chips_share_an_experts_width():
+    """The count ``jobs/train.py`` hands the readers: 4 on the expert
+    cell's ``ep`` 4 mesh, 1 where the mesh has no axis over 1."""
+    from dlrover_tpu.parallel.sharding import DEFAULT_RULES, axis_size
+
+    class Mesh:
+        def __init__(self, **shape):
+            self.shape = shape
+
+    axes = DEFAULT_RULES["expert_mlp"]
+    assert axis_size(Mesh(ep=4, tp=1, fsdp=1), axes) == 4
+    assert axis_size(Mesh(ep=2, tp=2), axes) == 4
+    assert axis_size(Mesh(ep=1, tp=1, fsdp=4), axes) == 1
+
+
 # -- which cell reports what --------------------------------------------------
 
 
