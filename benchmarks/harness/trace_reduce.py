@@ -140,17 +140,6 @@ def step_events(plane: dict, step_module: str) -> List[Event]:
             if step_module in e[0]]
 
 
-def kernel_seconds(plane: dict, needle: str, step_module: str):
-    """(summed device seconds, calls, whole steps) of the ops whose name
-    holds ``needle`` and that lie inside a whole step program, so that
-    the time can be set against what that many steps need."""
-    steps = step_events(plane, step_module)
-    hit = [e for e in line_events(plane, OPS_LINE) if needle in e[0]
-           and any(s[1] <= e[1] and e[1] + e[2] <= s[1] + s[2]
-                   for s in steps)]
-    return sum(e[2] for e in hit) / 1e9, len(hit), len(steps)
-
-
 def reduce_device(plane: dict, *, step_module: str,
                   host: Optional[List[Event]] = None) -> Optional[dict]:
     """One chip's numbers. ``step_module`` is a substring of the step

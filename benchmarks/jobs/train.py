@@ -563,7 +563,8 @@ def result(j) -> dict:
             "tokens_per_s_untraced":
                 tokens / (j.window_s - tracer.overhead_s),
             "tokens_per_step": j.tokens_per_step, "seq": j.seq,
-            "rows": j.rows, "grad_accum": j.accum, "steps": j.steps,
+            "rows": j.rows, "rows_per_replica": j.traffic["rows_per_replica"],
+            "grad_accum": j.accum, "steps": j.steps,
             "saves": j.saves, "state_bytes": j.state_bytes,
             "chips": j.chips, "expert_mlp_shards": j.expert_mlp_shards,
             "train_flops_per_token": j.flops_per_token,

@@ -16,7 +16,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path.insert(0, ROOT)
 
 from benchmarks import run as bench_run  # noqa: E402
-from benchmarks.harness import flops, peaks, stats, trace_reduce  # noqa: E402
+from benchmarks.harness import (  # noqa: E402
+    flops, named_kernels, peaks, stats, trace_reduce)
 
 BENCH = bench_run.load_benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -202,9 +203,11 @@ def test_flash_rooflines_divide_by_what_the_family_counted():
     ``jobs/train.py`` put the family's count: twice the FLOPs (a stack
     run twice) is twice the share; a family that counts none has no
     roofline, never 0 %."""
-    job = {"grad_accum": 2, "flash_fwd_flops": 10.0, "flash_bwd_flops": 35.0}
+    job = {"grad_accum": 2, "flash_fwd_flops": 10.0, "flash_bwd_flops": 35.0,
+           "rows_per_replica": 1, "seq": 128}
     ctx = {"trace_raw": KERNELS, "peaks": {"bf16_flops_per_s": 1e9},
-           "step_module": "step_fn", "job": job}
+           "step_module": "step_fn", "job": job,
+           "fields": {"num_attention_heads": 2}}
     read = {n: bench_run.load_reader(n).read for n in (
         "flash_fwd_roofline", "flash_bwd_roofline", "flash_attn_roofline")}
     # one whole step: least = 1 step x 2 microbatches x FLOPs / 1e9 /s
@@ -212,9 +215,10 @@ def test_flash_rooflines_divide_by_what_the_family_counted():
         100 * (2 * 10.0 / 1e9) / 200e-9)
     assert read["flash_bwd_roofline"](ctx) == pytest.approx(
         100 * (2 * 35.0 / 1e9) / 700e-9)
-    # the older rule counts the cut program as a step, and its call
+    # every Pallas call by the same rule: the cut program is no step,
+    # its one call no time (until PR 36 it was counted with both)
     assert read["flash_attn_roofline"](ctx) == pytest.approx(
-        100 * (2 * 2 * 45.0 / 1e9) / 1000e-9)
+        100 * (2 * 45.0 / 1e9) / 900e-9)
     twice = {**ctx, "job": {**job, "flash_fwd_flops": 20.0,
                             "flash_bwd_flops": 70.0}}
     for name, reader in read.items():
@@ -242,9 +246,9 @@ SYNTHETIC = {"planes": [
         {"name": "XLA Modules", "events": [
             ["jit_step_fn(1)", 0, 400], ["jit_step_fn(1)", 500, 400]]},
         {"name": "XLA Ops", "events": [
-            ["while.1", 0, 300], ["_fwd_kernel.2", 0, 100],
+            ["while.1", 0, 300], ["%_fwd_kernel.2" + PALLAS_CALL, 0, 100],
             ["fusion.3", 100, 150], ["all-reduce-start.1", 300, 100],
-            ["while.1", 500, 300], ["_fwd_kernel.2", 500, 100],
+            ["while.1", 500, 300], ["%_fwd_kernel.2" + PALLAS_CALL, 500, 100],
             ["fusion.3", 600, 150], ["all-reduce-start.1", 800, 100]]}]},
     {"name": "/host:CPU", "lines": [{"name": "python", "events": [
         ["bench:wait_loss", 350, 200], ["other", 0, 1000]]}]},
@@ -259,8 +263,8 @@ def test_trace_reduce_on_a_hand_made_trace():
     assert chip["steps"] == 2
     assert chip["step_gap_s"] == [pytest.approx(100e-9)]
     plane = trace_reduce.device_planes(SYNTHETIC)[0]
-    assert trace_reduce.kernel_seconds(plane, "_fwd_kernel", "step_fn") == (
-        pytest.approx(200e-9), 2, 2)
+    assert named_kernels.kernel_seconds(
+        plane, ("_fwd_kernel.",), "step_fn") == (pytest.approx(200e-9), 1, 2)
     assert chip["collective_s"] == pytest.approx(200e-9)
     own = dict(chip["device_ops"])
     # the while's 300 ns hold 250 ns of its body's ops: 50 ns are its own
@@ -283,10 +287,25 @@ def test_trace_reduce_on_the_recorded_chip_trace():
     assert chip["steps"] == expect["steps"]
     assert 100 * (1 - chip["busy_s"] / chip["window_s"]) == pytest.approx(
         expect["idle_pct"], rel=1e-9)
-    seconds, calls, steps = trace_reduce.kernel_seconds(
-        trace_reduce.device_planes(trace)[0], expect["kernel"], "step_fn")
-    assert (calls, steps) == (expect["kernel_calls"], expect["steps"])
+    assert expect["kernel"] == named_kernels.PALLAS
+    plane = trace_reduce.device_planes(trace)[0]
+    seconds, calls, steps = named_kernels.kernel_seconds(
+        plane, named_kernels.ANY_KERNEL, "step_fn")
+    assert (calls * steps, steps) == (expect["kernel_calls"], expect["steps"])
     assert seconds == pytest.approx(expect["kernel_s"], rel=1e-9)
+    # real lines: the kernels carry no name yet (PR 23), their operands do.
+    # Through the share's rule the 18 calls read 32 of 32 heads, whole
+    whole, _ = named_kernels.whole_step_calls(
+        plane, named_kernels.ANY_KERNEL, "step_fn")
+    calls = [e for step in whole for e in step]
+    assert {named_kernels.query_shape(e[0]) for e in calls} == {
+        (1, 32, 4096, 128)}
+    fields = config_fields("mistral-7b")
+    job = {"rows_per_replica": 1, "seq": 4096}
+    assert named_kernels.kernel_share(calls, fields, job) == (
+        1.0, (1, 32, 4096, 128), None)
+    assert named_kernels.kernel_share(
+        calls, fields, {**job, "rows_per_replica": 4})[0] == 0.25
 
 
 # -- the reference against the program, tiny widths --------------------------
