@@ -36,6 +36,8 @@ from dlrover_tpu.parallel.ring_attention import (
     sharded_flash_attention,
 )
 from dlrover_tpu.parallel.sharding import (
+    head_shards_gauge,
+    head_split,
     valid_spec_for,
     vocab_shards_gauge,
     vocab_split,
@@ -193,14 +195,14 @@ def _rope(x, positions, theta: float):
     return out.astype(x.dtype)
 
 
-def _flash_shardable(mesh, batch: int, n_heads: int) -> bool:
+def _flash_shardable(mesh, batch: int) -> bool:
     """Whether the short-context flash layout (batch over dp/fsdp, heads
-    over tp, sequence resident) divides the mesh evenly."""
+    where :func:`head_split` puts them, sequence resident) divides the
+    mesh evenly."""
     dp = (mesh.shape.get("dcn", 1) * mesh.shape.get("dp", 1)
           * mesh.shape.get("fsdp", 1))
-    tp = mesh.shape.get("tp", 1)
     sp = mesh.shape.get("sp", 1)
-    return sp == 1 and batch % dp == 0 and n_heads % tp == 0
+    return sp == 1 and batch % dp == 0
 
 
 def _attention(x, layer, config: LlamaConfig, positions, mesh):
@@ -215,6 +217,11 @@ def _attention(x, layer, config: LlamaConfig, positions, mesh):
     q = _rope(q, positions, c.rope_theta)
     k = _rope(k, positions, c.rope_theta)
     q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # (B,H,S,D)
+    # the chips the heads split over, as the projections' leaves are laid
+    # out by the same rule: no gather before the kernel, no sum after it
+    # but the one that follows wo
+    head_shards_gauge().set(
+        head_split(mesh, c.n_heads)[1] if mesh is not None else 1)
     strategy = c.sp_strategy
     if strategy not in (None, "ring", "ulysses"):
         raise ValueError(
@@ -253,14 +260,13 @@ def _attention(x, layer, config: LlamaConfig, positions, mesh):
             )
     elif use_flash and mesh is None:
         out = flash_attention(q, k, v, causal=True)
-    elif use_flash and _flash_shardable(mesh, B, c.n_heads):
+    elif use_flash and _flash_shardable(mesh, B):
         out = sharded_flash_attention(q, k, v, mesh)
     else:
         if use_flash:
             log_once(
                 "attention: flash kernel wanted but mesh %s does not divide "
-                "batch=%s heads=%s — dense XLA path",
-                str(dict(mesh.shape)), B, c.n_heads,
+                "batch=%s — dense XLA path", str(dict(mesh.shape)), B,
             )
         out = full_causal_attention(q, k, v)
     out = out.transpose(0, 2, 1, 3).reshape(B, S, c.n_heads * c.head_dim)

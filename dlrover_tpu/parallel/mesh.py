@@ -12,8 +12,9 @@ collectives:
 - ``fsdp`` — data parallel with fully-sharded params/opt state (ZeRO-3)
 - ``sp``   — sequence/context parallel (ring attention axis, long context)
 - ``tp``   — tensor parallel (innermost: highest-bandwidth ICI neighbors)
-- ``ep``   — expert parallel for MoE layers (the chips the experts' work is
-  spread over: each holds every expert at a slice of its columns)
+- ``ep``   — the expert group: the chips the experts' work is spread over
+  (each holds every expert at a slice of its columns), and with it the
+  vocabulary and attention's heads, as over ``tp``
 - ``pp``   — pipeline stages (outer: least traffic between stages)
 
 Elastic re-mesh policy: ``tp``/``pp``/``ep`` are fixed by the model shapes;

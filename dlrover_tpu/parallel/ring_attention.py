@@ -28,7 +28,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from dlrover_tpu.common.log import log_once
 from dlrover_tpu.ops.flash_attention import flash_attention
-from dlrover_tpu.parallel.sharding import clamp_spec
+from dlrover_tpu.parallel.sharding import clamp_spec, head_split
 
 
 def _block_attend(q, k, v, mask, m, l, o, scale):
@@ -184,7 +184,9 @@ def ring_attention(
     if batch_spec is None:
         # library default, clamped to the mesh's axes; an explicit caller
         # spec is passed through verbatim so typos still fail loudly
-        batch_spec = clamp_spec(mesh, P(("dcn", "dp", "fsdp"), "tp", "sp", None))
+        batch_spec = clamp_spec(mesh, P(
+            ("dcn", "dp", "fsdp"), head_split(mesh, q.shape[1])[0], "sp",
+            None))
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
@@ -220,17 +222,19 @@ def sharded_flash_attention(
     block_k: int = 1024,
 ):
     """Causal flash attention with batch sharded over dp/fsdp and heads
-    over tp (sequence resident per device — the short-context layout).
+    over the ``heads`` rule's axes, ep and tp (sequence resident per
+    device — the short-context layout).
 
     pallas_call has no GSPMD partitioning rule, so calling the kernel on
     sharded arrays inside jit would force replication; shard_map pins the
-    per-device block the kernel sees. Callers must ensure the batch/head
-    dims divide the mesh axes (see models/llama.py:_attention).
+    per-device block the kernel sees. Callers must ensure the batch dim
+    divides the data axes (see models/llama.py:_attention); heads the
+    rule's axes do not divide stay whole (:func:`head_split`).
     """
     if batch_spec is None:
-        batch_spec = clamp_spec(
-            mesh, P(("dcn", "dp", "fsdp"), "tp", None, None)
-        )
+        batch_spec = clamp_spec(mesh, P(
+            ("dcn", "dp", "fsdp"), head_split(mesh, q.shape[1])[0], None,
+            None))
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     fn = functools.partial(
         flash_attention, causal=True, scale=scale,

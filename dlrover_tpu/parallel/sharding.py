@@ -14,22 +14,29 @@ from dlrover_tpu.common.log import log_once
 
 # logical axis name → mesh axis (or None = replicate).
 # "batch" spreads over both data axes; "embed" (the hidden dim of params)
-# shards over fsdp (ZeRO-3-style); "heads"/"mlp" shard over tp; "vocab",
-# the columns of the output head and the rows of the embedding, over ep
-# and tp together: the hidden states are replicated over both, so each
-# chip makes the logits of its own columns and the loss is taken over
-# them where they are, never gathered (models/llama.py head_nll);
-# "expert_mlp", the FFN width of an expert leaf, over the same two:
-# every chip of an ep group holds every expert at a slice of its
-# columns, so the group's work is even whatever the routing
-# (models/moe.py);
+# shards over fsdp (ZeRO-3-style); "mlp" over tp. The hidden states
+# outside the experts are replicated over ep and tp, so three logical
+# axes split over the two together, and a chip computes its share of
+# each where it is:
+# - "heads"/"kv_heads", attention's query and key/value heads (the
+#   output columns of wq, wk, wv, the input rows of wo): a chip projects,
+#   attends and un-projects its own heads, and one all-reduce after wo
+#   joins them (models/llama.py _attention). Where ep has one device the
+#   rule reads tp alone (:func:`rule_for`);
+# - "vocab", the columns of the output head and the rows of the
+#   embedding: a chip makes the logits of its own columns and the loss
+#   is taken over them where they are, never gathered (models/llama.py
+#   head_nll);
+# - "expert_mlp", the FFN width of an expert leaf: every chip of an ep
+#   group holds every expert at a slice of its columns, so the group's
+#   work is even whatever the routing (models/moe.py).
 # "seq" over sp (ring attention axis); "layers"/"stage" over pp.
 DEFAULT_RULES: Dict[str, Optional[object]] = {
     "batch": ("dcn", "dp", "fsdp"),
     "seq": "sp",
     "embed": "fsdp",
-    "heads": "tp",
-    "kv_heads": "tp",
+    "heads": ("ep", "tp"),
+    "kv_heads": ("ep", "tp"),
     "mlp": "tp",
     "vocab": ("ep", "tp"),
     "expert_mlp": ("ep", "tp"),
@@ -45,20 +52,38 @@ DEFAULT_RULES: Dict[str, Optional[object]] = {
 }
 
 
+# the logical axes whose rule leaves ep out where it has one device: on a
+# mesh without an expert group attention is laid out, to the annotation,
+# as by a rule that names tp alone, so such a mesh's lowered programs
+# (and the compile-cache entries keyed on them) do not change with ep
+_EP_WHERE_SPLIT = ("heads", "kv_heads")
+
+
+def rule_for(name: str, rules: Optional[Dict] = None, mesh=None):
+    """The mesh axes that logical axis ``name`` maps to, on ``mesh`` where
+    one is given."""
+    rule = (rules or DEFAULT_RULES).get(name)
+    if (mesh is not None and name in _EP_WHERE_SPLIT
+            and isinstance(rule, tuple) and mesh.shape.get("ep", 1) == 1):
+        rule = tuple(a for a in rule if a != "ep")
+        rule = rule[0] if len(rule) == 1 else (rule or None)
+    return rule
+
+
 def spec_for(
     logical_axes: Sequence[Optional[str]],
     rules: Optional[Dict] = None,
+    mesh=None,
 ) -> P:
-    rules = rules or DEFAULT_RULES
     return P(*[
-        rules.get(name) if name is not None else None
+        rule_for(name, rules, mesh) if name is not None else None
         for name in logical_axes
     ])
 
 
 def sharding_for(mesh, logical_axes: Sequence[Optional[str]],
                  rules: Optional[Dict] = None) -> NamedSharding:
-    return NamedSharding(mesh, spec_for(logical_axes, rules))
+    return NamedSharding(mesh, spec_for(logical_axes, rules, mesh))
 
 
 def tree_shardings(mesh, logical_tree, rules: Optional[Dict] = None):
@@ -90,7 +115,7 @@ def valid_spec_for(mesh, shape, logical_axes: Sequence[Optional[str]],
     re-mesh landing on fsdp=3 with a dim of 64 replicates that dim instead
     of failing. GSPMD would need padding for uneven shards; replication is
     always-correct and the planner keeps axes power-of-two in practice."""
-    spec = clamp_spec(mesh, spec_for(logical_axes, rules))
+    spec = clamp_spec(mesh, spec_for(logical_axes, rules, mesh))
     cleaned = []
     for dim, axis in zip(shape, spec):
         size = axis_size(mesh, axis)
@@ -138,6 +163,24 @@ def vocab_split(mesh, vocab_size: int):
     return (over, chips) if chips > 1 else (None, 1)
 
 
+def head_split(mesh, n_heads: int):
+    """(mesh axes, chips) that the ``heads`` rule splits ``n_heads``
+    attention heads over on this mesh: the rule as :func:`valid_spec_for`
+    reads it, named even where its axes all have size 1 (``tp`` on one
+    chip); ``(None, 1)``, said once, where their product does not divide
+    the heads, which then stay whole on every chip."""
+    over = valid_spec_for(mesh, (n_heads,), ("heads",))[0]
+    wanted = rule_for("heads", mesh=mesh)
+    chips = axis_size(mesh, over)
+    if chips != axis_size(mesh, wanted):
+        log_once(
+            "attention: %s heads are not divisible by the %s chips of mesh "
+            "axes %s — every chip computes every head", n_heads,
+            axis_size(mesh, wanted), wanted,
+        )
+    return over, chips
+
+
 def vocab_shards_gauge():
     """``dlrover_head_vocab_shards``: set by the loss as it is traced
     (models/llama.py ``head_nll``), read by the trainer into the
@@ -148,6 +191,18 @@ def vocab_shards_gauge():
         "dlrover_head_vocab_shards",
         "Chips the output head's vocabulary is split over in the loss "
         "traced last (1: every chip makes the whole logits)")
+
+
+def head_shards_gauge():
+    """``dlrover_attn_head_shards``: set by the attention block as it is
+    traced (models/llama.py ``_attention``), read by the trainer into the
+    ``train.step`` span once its step is built."""
+    from dlrover_tpu.observability.registry import get_registry
+
+    return get_registry().gauge(
+        "dlrover_attn_head_shards",
+        "Chips attention's heads are split over in the attention traced "
+        "last (1: every chip computes every head)")
 
 
 def shard_tree(mesh, state, logical_tree, rules: Optional[Dict] = None):

@@ -21,7 +21,9 @@ point-to-point traffic). GQA keeps K/V *unrepeated* through the transform
 bytes of the q leg. Versus ring's sp ppermute hops the total volume is
 comparable, but Ulysses materializes the full sequence per device, so S is
 bounded by HBM; ring streams K/V and is not. Head counts must divide:
-(H / tp) % sp == 0 for q, and for unrepeated GQA also (H_kv / tp) % sp.
+(H / heads) % sp == 0 for q, and for unrepeated GQA also
+(H_kv / heads) % sp, where ``heads`` is the chips the heads' rule splits
+them over (ep x tp).
 
 Chunk order: ``all_to_all(tiled=True)`` concatenates received blocks in
 ring-index order, which is global sequence order (contiguous chunks laid
@@ -37,7 +39,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from dlrover_tpu.common.log import log_once
 from dlrover_tpu.ops.flash_attention import flash_attention
-from dlrover_tpu.parallel.sharding import clamp_spec
+from dlrover_tpu.parallel.sharding import clamp_spec, head_split
 
 
 def _ulysses_local(q, k, v, axis_name: str, scale: float, use_pallas: bool,
@@ -113,9 +115,11 @@ def ulysses_attention(
     if batch_spec is None:
         # library default, clamped to the mesh's axes; explicit caller
         # specs pass through verbatim so typos still fail loudly
-        batch_spec = clamp_spec(
-            mesh, P(("dcn", "dp", "fsdp"), "tp", "sp", None)
-        )
+        # split as the unrepeated K/V heads allow: they divide the
+        # query heads (GQA)
+        batch_spec = clamp_spec(mesh, P(
+            ("dcn", "dp", "fsdp"), head_split(mesh, k.shape[1])[0], "sp",
+            None))
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"

@@ -261,9 +261,11 @@ def test_expert_cell_step_never_holds_the_logits_whole(topo, monkeypatch):
     the group: no array of the program is logits-shaped at the whole
     vocabulary, none has a dimension of ``vocab_size`` at all, nothing
     vocabulary-sized is gathered, and what the chips exchange is
-    all-reduces only: of hidden states (the experts' sum, the embedding's
-    rows, the head's gradient), of per-token f32 statistics, of the
-    attention gradients and of scalars."""
+    all-reduces only: of hidden states (the experts' sum, the heads'
+    sum after ``wo`` and before the projections' input gradient, the
+    embedding's rows, the head's gradient), of per-token f32 statistics
+    and of scalars. None sums q, k or v: attention's heads are split
+    over the group as the projections made them."""
     import json
     import math
     import os
@@ -321,17 +323,17 @@ def test_expert_cell_step_never_holds_the_logits_whole(topo, monkeypatch):
     assert not wide, f"vocabulary-sized arrays {sorted(set(wide))}\n{said}"
     assert f"f32[1,{seq},{vocab // 4}]" in text, said   # a chip's logits
     assert all(c.startswith("all-reduce ") for c in found), said
-    # hidden states: the experts' sum forward and backward, the
-    # embedding's rows, the head's gradient
+    # hidden states: the experts' sum and the heads' sum, forward and
+    # backward, the embedding's rows, the head's gradient
     hidden_sums = [c for c in found
-                   if c.startswith(f"all-reduce bf16[1,{seq},{hidden}]")]
-    assert len(hidden_sums) == 4, said
+                   if re.match(rf"all-reduce bf16\[(1,)?{seq},{hidden}\]", c)]
+    assert len(hidden_sums) == 6, said
     # the head's statistics: maximum, summed exponentials, target's logit
     assert sum(c.count(f"f32[1,{seq}]") for c in found) >= 3, said
-    # and nothing else as large as a token's hidden state but the
-    # attention gradients that the flash kernel's shard_map sums
+    # and nothing else as large as a token's hidden state: no attention
+    # gradient, bf16[1,32,seq,128] where the heads were replicated
     for c in found:
-        if c in hidden_sums or f"bf16[1,32,{seq},128]" in c:
+        if c in hidden_sums:
             continue
         sizes = [math.prod(int(d) for d in dims.split(",") if d)
                  for dims in re.findall(r"\[([\d,]*)\]", c)]

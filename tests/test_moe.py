@@ -1,5 +1,6 @@
 """MoE model + expert-parallel tests on the virtual 8-device mesh."""
 
+import dataclasses
 import functools
 
 import jax
@@ -615,6 +616,237 @@ class TestVocabShardedHead:
                     == [want, want]
         finally:
             tracing.reset_tracer()
+
+
+# -- attention's heads over ep and tp ------------------------------------------
+
+# Mixtral's 32 query and 8 key/value heads at a tiny width (head_dim 8)
+ATTN = dict(dim=256, n_heads=32, n_kv_heads=8, capacity_factor=2.0)
+ATTN_LEAVES = ("wq", "wk", "wv", "wo")
+
+
+def _attn_tiny(**kw):
+    return _tiny(**{**ATTN, **kw})
+
+
+@functools.lru_cache(maxsize=None)
+def _heads_case(mesh_id, flash):
+    """(loss, gradients) of the 32-head model without a mesh (dense
+    attention) and on the mesh of that id, through the flash kernel's
+    ``shard_map`` (interpret mode here) or GSPMD's dense attention."""
+    c = _attn_tiny()
+    params = moe.init_params(c, jax.random.PRNGKey(0))
+    mesh = build_mesh(plan_mesh(8, **HEAD_MESHES[mesh_id]))
+    return (_loss_and_grads(c, params, _HEAD_TOKENS),
+            _loss_and_grads(_attn_tiny(use_flash_attention=flash), params,
+                            _HEAD_TOKENS, mesh))
+
+
+def _step(loss_fn, params, mesh, rows, seq=17):
+    """``ElasticTrainer``'s step and its arguments: two microbatches of
+    ``rows`` rows of ``seq`` tokens a data replica."""
+    from dlrover_tpu.trainer.elastic import ElasticTrainer, make_train_state
+
+    optimizer = optax.adamw(3e-4)
+    dp = mesh.shape["dp"] * mesh.shape["fsdp"] * mesh.shape["dcn"]
+    trainer = ElasticTrainer(
+        loss_fn=loss_fn, optimizer=optimizer,
+        global_batch_size=2 * rows * dp, micro_batch_per_replica=rows)
+    trainer.configure_for_world(plan_mesh(
+        mesh.devices.size, **{a: n for a, n in mesh.shape.items()
+                              if a in ("ep", "tp", "sp", "pp") and n > 1}))
+    state = make_train_state(params, optimizer)
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(7), (2, rows * dp, seq), 0, 256)
+    return trainer, state, tokens
+
+
+class TestHeadsOverTheGroup:
+    """``DEFAULT_RULES["heads"]`` and ``["kv_heads"]`` name ``ep`` and
+    ``tp`` together, as ``expert_mlp`` and ``vocab`` do: each chip of an
+    expert group projects, attends and un-projects its share of the
+    heads."""
+
+    @pytest.mark.parametrize("axes", MESHES, ids=MESH_IDS)
+    @pytest.mark.parametrize("leaf", ATTN_LEAVES)
+    def test_a_device_holds_its_share_of_the_heads(self, leaf, axes):
+        c = _attn_tiny()
+        mesh = build_mesh(plan_mesh(8, **axes))
+        params = moe.init_params(c, jax.random.PRNGKey(0))
+        sharded = shard_tree(mesh, params, moe.param_logical_axes(c))
+        chips, fsdp = mesh.shape["ep"] * mesh.shape["tp"], mesh.shape["fsdp"]
+        heads = c.n_kv_heads if leaf in ("wk", "wv") else c.n_heads
+        width, embed = heads // chips * c.head_dim, c.dim // fsdp
+        local = ((c.n_layers, width, embed) if leaf == "wo"
+                 else (c.n_layers, embed, width))
+        shards = sharded["layers"][leaf].addressable_shards
+        assert len(shards) == 8
+        for shard in shards:
+            assert shard.data.shape == local, shard.device
+        assert len({tuple((i.start, i.stop) for i in s.index)
+                    for s in shards}) == chips * fsdp
+        assert sharding.head_split(mesh, c.n_heads) == (("ep", "tp"), chips)
+
+    @pytest.mark.parametrize("mesh_id", ["ep4xfsdp2", "ep2xtp2xfsdp2"])
+    @pytest.mark.parametrize("flash", [True, False], ids=["flash", "dense"])
+    @pytest.mark.parametrize("leaf", ["loss"] + MODEL_LEAVES)
+    def test_loss_and_every_gradient_leaf_equal_the_meshless(
+            self, leaf, flash, mesh_id):
+        (ref_loss, ref_grads), (loss, grads) = _heads_case(mesh_id, flash)
+        tol = dict(atol=2e-3, rtol=2e-3)    # test_ep_sharded_matches_unsharded
+        if leaf == "loss":
+            np.testing.assert_allclose(float(loss), float(ref_loss), **tol)
+            return
+        got, want = grads, ref_grads
+        for key in leaf.split("/"):
+            got, want = got[key], want[key]
+        assert float(jnp.abs(want).max()) > 0
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), **tol)
+
+    @pytest.mark.parametrize("mesh_id", ["ep4xfsdp2", "ep2xtp2xfsdp2",
+                                         "tp2xfsdp4", "one-device"])
+    def test_the_step_says_how_many_chips_split_the_heads(self, mesh_id):
+        """``dlrover_attn_head_shards`` and the ``train.step`` span's
+        ``head_shards``: ``ep x tp`` once a step is built on the mesh, 1
+        on one device."""
+        from dlrover_tpu.common.constants import SpanName
+        from dlrover_tpu.observability import tracing
+        from dlrover_tpu.observability.registry import get_registry
+
+        c = _attn_tiny(vocab_size=272)    # a signature no other test has
+        axes = HEAD_MESHES.get(mesh_id)
+        mesh = (build_mesh(plan_mesh(8, **axes)) if axes
+                else build_mesh(plan_mesh(1)))
+        want = mesh.shape["ep"] * mesh.shape["tp"]
+        params = shard_tree(mesh, moe.init_params(c, jax.random.PRNGKey(0)),
+                            moe.param_logical_axes(c))
+        tracing.reset_tracer()
+        try:
+            trainer, state, tokens = _step(
+                lambda p, t: moe.next_token_loss(p, t, c, mesh), params,
+                mesh, rows=1)
+            for _ in range(2):
+                state, result = trainer.train_step(state, tokens)
+            assert bool(jnp.isfinite(result.loss))
+            assert get_registry().gauge(
+                "dlrover_attn_head_shards").value == want
+            assert f"dlrover_attn_head_shards {want}" \
+                in get_registry().render()
+            spans = [sp for sp in tracing.get_tracer().finished_spans()
+                     if sp.name == SpanName.TRAIN_STEP][-2:]
+            assert [sp.attrs["head_shards"] for sp in spans] == [want] * 2
+        finally:
+            tracing.reset_tracer()
+
+    def test_the_expert_step_sums_no_query_key_or_value(self):
+        """The step on ``ep`` 4 through the flash kernel's ``shard_map``:
+        q, k and v come in split by heads as the projections made them,
+        so the transpose sums none of their cotangents over the group
+        (with the heads replicated it all-reduced the three, each
+        ``[B, H, S, D]``); what joins the heads is the all-reduce of the
+        ``[B, S, D]`` hidden states after ``wo``."""
+        import re
+
+        c = _attn_tiny(use_flash_attention=True)
+        mesh = build_mesh(plan_mesh(8, ep=4))
+        params = shard_tree(mesh, moe.init_params(c, jax.random.PRNGKey(0)),
+                            moe.param_logical_axes(c))
+        seq = 32
+        trainer, state, tokens = _step(
+            lambda p, t: moe.next_token_loss(p, t, c, mesh), params, mesh,
+            rows=1, seq=seq + 1)
+        text = trainer._build_step().lower(state, tokens).compile().as_text()
+
+        def moves(line):
+            # XLA:CPU keeps a sum over axes of size 1: one device a group
+            listed = re.search(r"replica_groups=\{\{([\d,]*)\}", line)
+            if listed:
+                return len(listed.group(1).split(",")) > 1
+            return int(re.search(
+                r"replica_groups=\[[\d,]*?(\d+)\]", line).group(1)) > 1
+
+        sums = [line for line in text.splitlines()
+                if re.search(r" all-reduce(-start)?\(", line) and moves(line)]
+        shapes = [tuple(int(d) for d in dims.split(","))
+                  for line in sums
+                  for dims in re.findall(r"\w+\[([\d,]+)\]",
+                                         line.split(" all-reduce")[0])]
+        said = "all-reduces:\n  " + "\n  ".join(
+            line.strip()[:160] for line in sums)
+        assert not [s for s in shapes
+                    if len(s) == 4 and s[2:] == (seq, c.head_dim)], said
+        assert (1, seq, c.dim) in shapes, said
+
+    @pytest.mark.parametrize("model", ["llama", "looped", "moe"])
+    def test_one_device_lowers_the_step_of_a_tp_rule(
+            self, model, monkeypatch):
+        """On one device the rules lower the training step to the text the
+        table that named ``tp`` alone for the heads lowered: the one-chip
+        programs are unchanged, to the annotation."""
+        from dlrover_tpu.models import looped
+
+        make = {
+            "llama": (llama.LlamaConfig.tiny, llama),
+            "looped": (looped.LoopedConfig.tiny, looped),
+            "moe": (moe.MoEConfig.tiny, moe),
+        }
+        tiny, module = make[model]
+        c = dataclasses.replace(tiny(), use_flash_attention=True)
+        mesh = build_mesh(plan_mesh(1))
+
+        def lowered():
+            jax.clear_caches()       # the jitted losses trace anew
+            params = shard_tree(mesh, module.init_params(
+                c, jax.random.PRNGKey(0)), module.param_logical_axes(c))
+            trainer, state, tokens = _step(
+                lambda p, t: module.next_token_loss(p, t, c, mesh), params,
+                mesh, rows=1)
+            return trainer._build_step().lower(state, tokens).as_text()
+
+        now = lowered()
+        for name in ("heads", "kv_heads"):
+            monkeypatch.setitem(sharding.DEFAULT_RULES, name, "tp")
+        assert lowered() == now
+        assert '{"tp"}' in now
+
+    def test_heads_the_group_does_not_divide_stay_whole(self, monkeypatch):
+        """Five heads over ``ep`` 4: every chip computes every head, the
+        loss and gradients are the meshless ones, the gauge reads 1, and
+        the log says so once however often attention is traced."""
+        from dlrover_tpu.common.log import logger
+        from dlrover_tpu.observability.registry import get_registry
+
+        c = llama.LlamaConfig(
+            vocab_size=256, dim=40, n_layers=2, n_heads=5, n_kv_heads=5,
+            ffn_dim=64, max_seq_len=64, remat=False, dtype=jnp.float32,
+            use_flash_attention=True)
+        mesh = build_mesh(plan_mesh(8, ep=4))
+        lines = []
+        monkeypatch.setattr(
+            logger, "info", lambda msg, *a: lines.append(msg % a))
+        assert sharding.head_split(mesh, c.n_heads) == (None, 1)
+        params = llama.init_params(c, jax.random.PRNGKey(0))
+        tokens = _HEAD_TOKENS[:2]
+        ref_loss, ref_grads = jax.value_and_grad(
+            lambda p: llama.next_token_loss(
+                p, tokens, dataclasses.replace(c, use_flash_attention=False)
+            ))(params)
+        sharded = shard_tree(mesh, params, llama.param_logical_axes(c))
+        tok_s = jax.device_put(
+            tokens, NamedSharding(mesh, P(("dp", "fsdp"), None)))
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda p, t: llama.next_token_loss(p, t, c, mesh)))(sharded, tok_s)
+        llama.next_token_loss(sharded, tok_s[:, :17], c, mesh)  # traced anew
+        assert get_registry().gauge("dlrover_attn_head_shards").value == 1
+        tol = dict(atol=2e-3, rtol=2e-3)
+        np.testing.assert_allclose(float(loss), float(ref_loss), **tol)
+        for name in ATTN_LEAVES:
+            np.testing.assert_allclose(
+                np.asarray(grads["layers"][name]),
+                np.asarray(ref_grads["layers"][name]), err_msg=name, **tol)
+        said = [line for line in lines if "5 heads" in line]
+        assert len(said) == 1, lines
+        assert "4 chips" in said[0] and "every head" in said[0]
 
 
 def test_cross_entropy_matches_log_softmax_gather():

@@ -65,7 +65,16 @@ class TestMeshPlan:
 
 class TestShardingRules:
     def test_spec_mapping(self):
-        assert spec_for(("embed", "heads")) == P("fsdp", "tp")
+        assert spec_for(("embed", "heads")) == P("fsdp", ("ep", "tp"))
+        # on a mesh without an expert group the heads read tp alone
+        one = build_mesh(plan_mesh(1))
+        assert spec_for(("embed", "heads", "kv_heads", "vocab"), mesh=one) \
+            == P("fsdp", "tp", "tp", ("ep", "tp"))
+        tp2 = build_mesh(plan_mesh(8, tp=2))
+        assert spec_for(("heads",), mesh=tp2) == P("tp")
+        ep2 = build_mesh(plan_mesh(8, ep=2))
+        assert spec_for(("heads", "kv_heads"), mesh=ep2) \
+            == P(("ep", "tp"), ("ep", "tp"))
         # layers are stage-major (pp) so pipeline shard_map needs no
         # repartition; on pp=1 meshes the axis is size 1 — a no-op
         assert spec_for(("layers", "norm")) == P("pp", None)
