@@ -20,8 +20,9 @@ from typing import List, Optional
 from dlrover_tpu.agent.config import ElasticLaunchConfig
 from dlrover_tpu.agent.master_client import MasterClient
 from dlrover_tpu.agent.training import ElasticTrainingAgent
-from dlrover_tpu.common.constants import NodeStatus, RendezvousName
+from dlrover_tpu.common.constants import NodeStatus, RendezvousName, SpanName
 from dlrover_tpu.common.log import logger
+from dlrover_tpu.observability import tracing
 
 
 def parse_nnodes(value: str):
@@ -152,7 +153,14 @@ def _run_network_check(config: ElasticLaunchConfig,
                        client: MasterClient) -> bool:
     from dlrover_tpu.diagnosis.node_check_agent import run_node_check
 
-    return run_node_check(config, client)
+    # in the agent's ring, so the exit bundle carries it: a node check
+    # starts a backend of its own, seconds of every job's set-up
+    begun = time.monotonic()
+    with tracing.span(SpanName.AGENT_NODE_CHECK,
+                      source=f"agent_{config.node_rank}") as sp:
+        ok = run_node_check(config, client)
+        sp.attrs.update(ok=ok, elapsed_s=time.monotonic() - begun)
+    return ok
 
 
 def _apply_master_run_config(client: MasterClient,

@@ -138,12 +138,20 @@ class _Worker:
     local_rank: int
     global_rank: int
     proc: subprocess.Popen
+    # time.monotonic() of the last poll that found it running (its spawn
+    # until the first)
+    alive_t: float = 0.0
 
 
 class RunResult:
-    def __init__(self, state: WorkerState, failures: Optional[Dict] = None):
+    def __init__(self, state: WorkerState, failures: Optional[Dict] = None,
+                 seen_t: Optional[float] = None, poll_gap_s: float = 0.0):
         self.state = state
         self.failures = failures or {}
+        # a failure's: the poll that saw it, and how long before that the
+        # previous poll had seen the worker running
+        self.seen_t = seen_t
+        self.poll_gap_s = poll_gap_s
 
 
 class ElasticTrainingAgent:
@@ -228,7 +236,8 @@ class ElasticTrainingAgent:
         # worker-published device-memory ledger snapshots re-keyed by
         # global rank (master's FleetMemoryMonitor consumes them)
         self._mem_snapshots = MemorySnapshotCollector(self._ipc_server)
-        self._events = get_emitter(f"agent_{config.node_rank}")
+        self._source = f"agent_{config.node_rank}"
+        self._events = get_emitter(self._source)
         self._training_monitor = None
         self._replica_service = None
         self._reshard_service = None
@@ -257,7 +266,7 @@ class ElasticTrainingAgent:
         from dlrover_tpu.observability.flight_recorder import FlightRecorder
 
         self._flight_recorder = FlightRecorder(
-            source=f"agent_{config.node_rank}", registry=reg
+            source=self._source, registry=reg
         )
         self._metrics_server = self._maybe_start_metrics_server()
 
@@ -300,8 +309,7 @@ class ElasticTrainingAgent:
         # world-wait RPC spans (master_client.py) and the master-side
         # join/world-cut spans all nest under this trace
         with tracing.span(
-            SpanName.RDZV_CLIENT_ROUND,
-            source=f"agent_{self._config.node_rank}",
+            SpanName.RDZV_CLIENT_ROUND, source=self._source,
             node_rank=self._config.node_rank,
             restart_count=self._restart_count,
         ), self._events.span(AgentEvent.RENDEZVOUS):
@@ -333,6 +341,10 @@ class ElasticTrainingAgent:
         coordinator: str,
     ) -> Dict[str, str]:
         env = self._config.base_worker_env()
+        # the spawn's context: the worker's first restore joins its trace
+        parent = tracing.inject_env()
+        if parent is not None:
+            env[EnvKey.TRACE_PARENT] = parent
         env.update({
             EnvKey.JOB_NAME: self._config.job_name,
             EnvKey.MASTER_ADDR: self._client.master_addr,
@@ -360,23 +372,29 @@ class ElasticTrainingAgent:
         """(reference ``_initialize_workers``:856)"""
         coordinator, base_rank, world_size = self._rendezvous()
         self._workers = []
-        for local_rank in range(self._config.nproc_per_node):
-            global_rank = base_rank + local_rank
-            env = self._worker_env(
-                local_rank, global_rank, world_size, coordinator
-            )
-            proc = None
-            if self._warm_pool is not None:
-                proc = self._warm_pool.take(
-                    env, self._config.entrypoint, self._config.args
+        with tracing.span(SpanName.AGENT_SPAWN, source=self._source) as sp:
+            warm = []
+            for local_rank in range(self._config.nproc_per_node):
+                global_rank = base_rank + local_rank
+                env = self._worker_env(
+                    local_rank, global_rank, world_size, coordinator
                 )
-            if proc is None:  # pool disabled/empty: cold spawn
-                cmd = [
-                    sys.executable, self._config.entrypoint,
-                    *self._config.args,
-                ]
-                proc = subprocess.Popen(cmd, env=env)  # noqa: S603
-            self._workers.append(_Worker(local_rank, global_rank, proc))
+                proc = None
+                if self._warm_pool is not None:
+                    proc = self._warm_pool.take(
+                        env, self._config.entrypoint, self._config.args
+                    )
+                warm.append(proc is not None)
+                if proc is None:  # pool disabled/empty: cold spawn
+                    cmd = [
+                        sys.executable, self._config.entrypoint,
+                        *self._config.args,
+                    ]
+                    proc = subprocess.Popen(cmd, env=env)  # noqa: S603
+                self._workers.append(_Worker(
+                    local_rank, global_rank, proc, time.monotonic()))
+            sp.attrs.update(warm=warm,
+                            pids=[w.proc.pid for w in self._workers])
         logger.info(
             "node %s spawned %s worker(s): pids=%s",
             self._config.node_rank,
@@ -389,17 +407,22 @@ class ElasticTrainingAgent:
     def _monitor_workers(self) -> RunResult:
         states = []
         failures = {}
+        seen_t, poll_gap_s = None, 0.0
         for w in self._workers:
             code = w.proc.poll()
+            polled_t = time.monotonic()
             if code is None:
+                w.alive_t = polled_t
                 states.append(WorkerState.RUNNING)
             elif code == 0:
                 states.append(WorkerState.SUCCEEDED)
             else:
                 states.append(WorkerState.FAILED)
                 failures[w.global_rank] = code
+                seen_t = seen_t or polled_t
+                poll_gap_s = max(poll_gap_s, polled_t - w.alive_t)
         if failures:
-            return RunResult(WorkerState.FAILED, failures)
+            return RunResult(WorkerState.FAILED, failures, seen_t, poll_gap_s)
         if all(s == WorkerState.SUCCEEDED for s in states):
             return RunResult(WorkerState.SUCCEEDED)
         return RunResult(WorkerState.RUNNING)
@@ -418,52 +441,56 @@ class ElasticTrainingAgent:
             from dlrover_tpu.common.config import get_context
 
             grace_s = get_context().worker_stop_grace_s
-        for w in self._workers:
-            if w.proc.poll() is None:
+        with tracing.span(SpanName.AGENT_STOP_WORKERS, source=self._source):
+            for w in self._workers:
+                if w.proc.poll() is None:
+                    try:
+                        w.proc.send_signal(sig)
+                    except ProcessLookupError:
+                        pass
+            deadline = time.monotonic() + grace_s
+            for w in self._workers:
+                remaining = max(0.1, deadline - time.monotonic())
                 try:
-                    w.proc.send_signal(sig)
-                except ProcessLookupError:
-                    pass
-        deadline = time.monotonic() + grace_s
-        for w in self._workers:
-            remaining = max(0.1, deadline - time.monotonic())
-            try:
-                w.proc.wait(timeout=remaining)
-            except subprocess.TimeoutExpired:
-                w.proc.kill()
-                w.proc.wait()
+                    w.proc.wait(timeout=remaining)
+                except subprocess.TimeoutExpired:
+                    w.proc.kill()
+                    w.proc.wait()
 
     def _restart_workers(self, reason: str,
                          grace_s: Optional[float] = None) -> None:
         """Soft restart: same host, new rendezvous round
-        (reference ``_restart_workers``:1225)."""
+        (reference ``_restart_workers``:1225), as one span in the current
+        trace: the worker failure's, the master's action's, or a new one."""
         logger.info("restarting workers on node %s: %s",
                     self._config.node_rank, reason)
         self._events.instant(AgentEvent.RESTART, reason=reason)
         self._restarts_counter.labels(reason=reason).inc()
-        # stop first: shm survives the workers, and persisting after they
-        # die removes any chance of reading a frame mid-write
-        self._stop_workers(grace_s=grace_s)
-        self._save_breakpoint_checkpoint(reason)
-        # the dead workers' unacked shard leases go back to TODO now —
-        # relaunched workers (or any survivor) re-pull them immediately
-        # instead of waiting out shard_lease_timeout_s; acked shards stay
-        # retired in the master ledger, so nothing double-trains
-        try:
-            self._client.recover_shard_tasks()
-        except (ConnectionError, OSError) as e:
-            # best-effort fast path: lease expiry remains the backstop
-            logger.warning("shard-lease recovery skipped: %r", e)
-        self._restart_count += 1
-        # drop the stale step observation: heartbeats must not re-populate
-        # the master's PerfMonitor with pre-restart timestamps (that would
-        # immediately re-arm the hang detector after a hang restart), and
-        # restored workers may legitimately resume from an earlier step
-        self._last_global_step = 0
-        self._last_step_ts = 0.0
-        if getattr(self, "_training_monitor", None) is not None:
-            self._training_monitor.reset()
-        self._initialize_workers()
+        with tracing.span(SpanName.AGENT_RESTART_WORKERS,
+                          source=self._source, reason=reason):
+            # stop first: shm survives the workers, and persisting after they
+            # die removes any chance of reading a frame mid-write
+            self._stop_workers(grace_s=grace_s)
+            self._save_breakpoint_checkpoint(reason)
+            # the dead workers' unacked shard leases go back to TODO now —
+            # relaunched workers (or any survivor) re-pull them immediately
+            # instead of waiting out shard_lease_timeout_s; acked shards stay
+            # retired in the master ledger, so nothing double-trains
+            try:
+                self._client.recover_shard_tasks()
+            except (ConnectionError, OSError) as e:
+                # best-effort fast path: lease expiry remains the backstop
+                logger.warning("shard-lease recovery skipped: %r", e)
+            self._restart_count += 1
+            # drop the stale step observation: heartbeats must not re-populate
+            # the master's PerfMonitor with pre-restart timestamps (that would
+            # immediately re-arm the hang detector after a hang restart), and
+            # restored workers may legitimately resume from an earlier step
+            self._last_global_step = 0
+            self._last_step_ts = 0.0
+            if getattr(self, "_training_monitor", None) is not None:
+                self._training_monitor.reset()
+            self._initialize_workers()
 
     def _save_breakpoint_checkpoint(self, reason: str) -> None:
         """Persist whatever checkpoint state is in shm before losing workers
@@ -590,8 +617,7 @@ class ElasticTrainingAgent:
         def _capture():
             try:
                 with tracing.activate(carried), tracing.span(
-                    SpanName.AGENT_STACK_DUMP,
-                    source=f"agent_{self._config.node_rank}",
+                    SpanName.AGENT_STACK_DUMP, source=self._source,
                     rank=action_data.get("rank", -1),
                 ):
                     self._diagnosis._request_worker_profiles()
@@ -804,6 +830,14 @@ class ElasticTrainingAgent:
             if self._warm_pool is not None:
                 self._warm_pool.stop()
             self._ipc_server.stop()
+            if self._restart_count:
+                # the fault arcs this agent ran, on the host's monotonic
+                # clock (spans.json), once the saver's late commits are in
+                from dlrover_tpu.observability.flight_recorder import (
+                    REASON_AGENT_EXIT,
+                )
+
+                self._flight_recorder.capture(REASON_AGENT_EXIT)
 
     def _monitor_loop(self) -> int:
         interval = self._config.monitor_interval_s
@@ -843,11 +877,7 @@ class ElasticTrainingAgent:
                 carried = tracing.extract_wire(
                     action_data.get(tracing.WIRE_KEY)
                 )
-                with tracing.activate(carried), tracing.span(
-                    SpanName.AGENT_RESTART_WORKERS,
-                    source=f"agent_{self._config.node_rank}",
-                    reason=action_data.get("reason", ""),
-                ):
+                with tracing.activate(carried):
                     self._restart_workers(
                         f"diagnosis action {action} "
                         f"({action_data.get('reason', '')})",
@@ -944,41 +974,55 @@ class ElasticTrainingAgent:
         The DiagnosisAgent decides RESTART_WORKER (in place) vs
         RELAUNCH_WORKER (this agent exits non-zero; the master's relaunch
         ladder replaces the pod) — reference diagnose_training_failure:137."""
-        logger.warning(
-            "node %s worker failure(s): %s",
-            self._config.node_rank, result.failures,
-        )
-        self._events.instant(
-            AgentEvent.WORKER_FAIL, failures=result.failures,
+        # the fault arc's root, from the poll that saw the death: what
+        # follows (report, verdict, restart, persist, rendezvous, spawn
+        # and, through the environment, the new worker's restore) is one
+        # trace. ``poll_gap_s`` bounds the agent's own share of detection
+        with tracing.span(
+            SpanName.AGENT_WORKER_FAILURE, source=self._source,
+            start_t=result.seen_t,
+            failures=dict(result.failures),
             restart_count=self._restart_count,
-        )
-        self._worker_failures_counter.inc()
-        try:
-            self._client.report_failure(
-                error_data=str(result.failures),
-                level=TrainingExceptionLevel.PROCESS_ERROR,
+            poll_gap_s=result.poll_gap_s,
+        ):
+            logger.warning(
+                "node %s worker failure(s): %s",
+                self._config.node_rank, result.failures,
+            )
+            self._events.instant(
+                AgentEvent.WORKER_FAIL, failures=result.failures,
                 restart_count=self._restart_count,
             )
-        except ConnectionError:
-            pass
-        # the budget counts only failure-driven restarts (_restart_count
-        # also grows on membership changes); the verdict is the single
-        # decision point for giving up in place
-        verdict = self._diagnosis.diagnose_training_failure(
-            result.failures, self._remaining_restarts
-        )
-        if verdict == DiagnosisActionType.RELAUNCH_WORKER:
-            logger.error(
-                "giving up in-place restarts on node %s (verdict=%s, "
-                "remaining=%s)", self._config.node_rank, verdict,
-                self._remaining_restarts,
-            )
-            self._save_breakpoint_checkpoint("relaunch")
-            self._client.update_node_status(
-                NodeStatus.FAILED, exit_reason="relaunched",
-                restart_count=self._restart_count,
-            )
-            return False
-        self._remaining_restarts -= 1
-        self._restart_workers(f"worker failure {result.failures}")
-        return True
+            self._worker_failures_counter.inc()
+            with tracing.span(SpanName.AGENT_FAILURE_REPORT,
+                              source=self._source) as sp:
+                try:
+                    self._client.report_failure(
+                        error_data=str(result.failures),
+                        level=TrainingExceptionLevel.PROCESS_ERROR,
+                        restart_count=self._restart_count,
+                    )
+                except ConnectionError:
+                    pass
+                # the budget counts only failure-driven restarts
+                # (_restart_count also grows on membership changes); the
+                # verdict is the single decision point for giving up in place
+                verdict = self._diagnosis.diagnose_training_failure(
+                    result.failures, self._remaining_restarts
+                )
+                sp.attrs["verdict"] = verdict
+            if verdict == DiagnosisActionType.RELAUNCH_WORKER:
+                logger.error(
+                    "giving up in-place restarts on node %s (verdict=%s, "
+                    "remaining=%s)", self._config.node_rank, verdict,
+                    self._remaining_restarts,
+                )
+                self._save_breakpoint_checkpoint("relaunch")
+                self._client.update_node_status(
+                    NodeStatus.FAILED, exit_reason="relaunched",
+                    restart_count=self._restart_count,
+                )
+                return False
+            self._remaining_restarts -= 1
+            self._restart_workers(f"worker failure {result.failures}")
+            return True

@@ -347,14 +347,19 @@ class AsyncCheckpointSaver:
                 # a single-writer job's commit must wait for its one frame,
                 # not one per host
                 meta = persisted[0].read_meta() or {}
-                with tracing.span(
-                    SpanName.CKPT_COMMIT,
-                    source=f"saver_{self._node_rank}", step=step,
-                ):
-                    self.commit_checkpoint(
-                        path, step,
-                        expected_frames=meta.get("expected_frames"),
-                    )
+                self._traced_commit(
+                    tracing.current_context(), path, step,
+                    expected_frames=meta.get("expected_frames"),
+                )
+
+    def _traced_commit(self, ctx, path: str, step: int, **kwargs) -> None:
+        """``commit_checkpoint`` under a ``ckpt.commit`` span in the trace
+        of ``ctx`` (captured before a commit thread starts)."""
+        with tracing.activate(ctx), tracing.span(
+            SpanName.CKPT_COMMIT, source=f"saver_{self._node_rank}",
+            step=step,
+        ):
+            self.commit_checkpoint(path, step, **kwargs)
 
     def _frame_lock(self, shm: SharedMemoryHandler):
         """The per-frame lock the worker writes under — the agent takes it
@@ -368,32 +373,46 @@ class AsyncCheckpointSaver:
     def _persist_one(
         self, shm: SharedMemoryHandler, path: str, step: int,
         lock_timeout: float = CheckpointConstant.SAVE_TIMEOUT_S,
-    ) -> bool:
+    ) -> int:
+        """Persist ``shm``'s frame of ``step`` as a chain link. Returns the
+        frame's bytes, 0 where nothing was persisted."""
+        source = f"saver_{self._node_rank}"
         lock = self._frame_lock(shm)
+        # opened before the lock is asked for: the wait is the read's
+        read = tracing.span(SpanName.CKPT_PERSIST_READ, source=source)
         if lock is not None and not lock.acquire(timeout=lock_timeout):
+            read.end(status="error")
             logger.warning(
                 "could not take frame lock for %s in %.0fs — skipping to "
                 "avoid a torn read", shm.name, lock_timeout,
             )
-            return False
+            return 0
         try:
-            meta = shm.read_meta()
-            if meta is None or meta["step"] != step:
-                return False
-            blob = shm.read_frame_bytes()
-            if blob is None:
-                return False
+            with read:
+                meta = shm.read_meta()
+                if meta is None or meta["step"] != step:
+                    return 0
+                blob = shm.read_frame_bytes()
+                if blob is None:
+                    return 0
+                read.attrs["bytes"] = len(blob)
             # never persist bytes that already fail their shard CRCs: a
             # corrupt frame on disk outlives the replica copies that could
             # repair it (restore-time checks would only catch it later,
             # after the good copies are gone)
-            bad = shm.verify_frame()
+            with tracing.span(
+                SpanName.CKPT_PERSIST_VERIFY, source=source,
+                shards=sum(len(leaf.get("shards", []))
+                           for leaf in meta["leaves"]),
+            ) as verify:
+                bad = shm.verify_frame()
+                verify.attrs["bad"] = len(bad)
             if bad:
                 logger.error(
                     "refusing to persist %s step %s: corrupt shard(s) %s",
                     shm.name, step, bad,
                 )
-                return False
+                return 0
         finally:
             if lock is not None:
                 lock.release()
@@ -401,16 +420,21 @@ class AsyncCheckpointSaver:
         with self._lock:
             prev = self._chain_state.get(chain_key)
         try:
-            state = manifest.persist_frame(
-                self._storage, path, step, meta, blob,
-                prev_state=prev, executor=self._executor,
-            )
+            with tracing.span(
+                SpanName.CKPT_PERSIST_WRITE, source=source,
+            ) as write:
+                state = manifest.persist_frame(
+                    self._storage, path, step, meta, blob,
+                    prev_state=prev, executor=self._executor,
+                )
+                write.attrs.update(bytes_written=state["bytes_written"],
+                                   kind=state["kind"])
         except Exception:  # noqa: BLE001 — a failed persist holds the quorum open
             logger.exception(
                 "persist of %s for step %s failed — no done marker, the "
                 "commit quorum stays open", shm.name, step,
             )
-            return False
+            return 0
         with self._lock:
             self._chain_state[chain_key] = state
             # a frame counts as "persisted at step N" only once its
@@ -420,7 +444,7 @@ class AsyncCheckpointSaver:
         self._persist_bytes.labels(kind=state["kind"]).inc(
             state["bytes_written"])
         self._persist_frames.labels(kind=state["kind"]).inc()
-        return True
+        return len(blob)
 
     def _write_done_files(
         self, path: str, step: int, handlers: List[SharedMemoryHandler]
@@ -517,34 +541,41 @@ class AsyncCheckpointSaver:
         synchronous — the process is about to die."""
         if not self.ckpt_dir:
             return 0
-        persisted = 0
-        handlers = self._local_shm_handlers()
-        steps = set()
-        for shm in handlers:
-            if workers_dead:
-                lock = self._frame_lock(shm)
-                if lock is not None:
-                    lock.release()
-            meta = shm.read_meta()
-            if meta is None and not workers_dead:
-                # the worker's async drain may still be landing the frame
-                # (engine.py save_to_memory holds the frame lock until the
-                # shm write completes) — wait for it, then re-read
-                lock = self._frame_lock(shm)
-                if lock is not None and lock.acquire(timeout=10.0):
-                    lock.release()
-                    meta = shm.read_meta()
-            if meta is None:
-                continue
-            step = meta["step"]
-            with self._lock:
-                already = self._persisted_steps.get(shm.name, -1)
-            if step <= already:
-                continue
-            if self._persist_one(shm, self.ckpt_dir, step, lock_timeout=10.0):
-                persisted += 1
-                steps.add(step)
-        if persisted:
+        with tracing.span(
+            SpanName.CKPT_PERSIST, source=f"saver_{self._node_rank}",
+            trigger=trigger,
+        ) as sp:
+            persisted, nbytes = 0, 0
+            handlers = self._local_shm_handlers()
+            steps = set()
+            for shm in handlers:
+                if workers_dead:
+                    lock = self._frame_lock(shm)
+                    if lock is not None:
+                        lock.release()
+                meta = shm.read_meta()
+                if meta is None and not workers_dead:
+                    # the worker's async drain may still be landing the
+                    # frame (engine.py save_to_memory holds the frame lock
+                    # until the shm write completes) — wait for it, then
+                    # re-read
+                    lock = self._frame_lock(shm)
+                    if lock is not None and lock.acquire(timeout=10.0):
+                        lock.release()
+                        meta = shm.read_meta()
+                if meta is None:
+                    continue
+                step = meta["step"]
+                with self._lock:
+                    already = self._persisted_steps.get(shm.name, -1)
+                if step <= already:
+                    continue
+                frame_bytes = self._persist_one(shm, self.ckpt_dir, step,
+                                                lock_timeout=10.0)
+                if frame_bytes:
+                    persisted += 1
+                    nbytes += frame_bytes
+                    steps.add(step)
             for step in steps:
                 done = [
                     h for h in handlers
@@ -557,23 +588,28 @@ class AsyncCheckpointSaver:
                 # host's partial save leaves the tracker untouched (correct
                 # — a partial step must never become the restore point).
                 if self._is_commit_leader:
+                    kwargs = {"timeout_s": 30.0, "trigger": trigger}
                     if async_commit:
+                        # its ckpt.commit joins this persist's trace
                         threading.Thread(
-                            target=self.commit_checkpoint,
-                            args=(self.ckpt_dir, step),
-                            kwargs={"timeout_s": 30.0,
-                                    "trigger": trigger},
-                            name=f"bp-commit-{step}", daemon=True,
+                            target=self._traced_commit,
+                            args=(tracing.current_context(), self.ckpt_dir,
+                                  step),
+                            kwargs=kwargs, name=f"bp-commit-{step}",
+                            daemon=True,
                         ).start()
                     else:
-                        self.commit_checkpoint(
-                            self.ckpt_dir, step, timeout_s=30.0,
-                            trigger=trigger,
-                        )
-            logger.info(
-                "breakpoint save (%s): persisted %s frame(s) to %s",
-                reason, persisted, self.ckpt_dir,
-            )
+                        self._traced_commit(tracing.current_context(),
+                                            self.ckpt_dir, step, **kwargs)
+            if persisted:
+                # benchmarks/harness/resume_path.py PERSISTED parses this
+                # line for resume.persist_s: keep it word for word
+                logger.info(
+                    "breakpoint save (%s): persisted %s frame(s) to %s",
+                    reason, persisted, self.ckpt_dir,
+                )
+            sp.attrs.update(step=max(steps, default=-1), frames=persisted,
+                            bytes=nbytes)
         return persisted
 
     @classmethod

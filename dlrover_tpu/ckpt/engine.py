@@ -853,8 +853,11 @@ class CheckpointEngine:
 
         Returns (state, step); step == -1 when nothing was restored.
         """
+        # the process's first restore joins the trace of the agent's
+        # spawn that started it (worker.init() handed it over)
         with tracing.span(
             SpanName.CKPT_RESTORE, source=self._source,
+            parent=tracing.get_tracer().take_handed(),
         ) as sp:
             rung = functools.partial(tracing.span, source=self._source)
             # an in-flight async snapshot must land before we read the frame

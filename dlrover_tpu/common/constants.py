@@ -238,6 +238,9 @@ class EnvKey:
     # grace window (seconds) the agent keeps training on cached shard
     # assignments while the master is unreachable (partition-degraded mode)
     PARTITION_GRACE_S = "DLROVER_TPU_PARTITION_GRACE_S"
+    # "<trace_id>:<span_id>" of the agent's spawn (observability/tracing.py
+    # inject_env): the worker's first ``ckpt.restore`` joins that trace
+    TRACE_PARENT = "DLROVER_TPU_TRACE_PARENT"
 
 
 class ConfigKey:
@@ -420,6 +423,13 @@ class SpanName:
     CKPT_PERSIST = "ckpt.persist"
     CKPT_COMMIT = "ckpt.commit"
     CKPT_RESTORE = "ckpt.restore"
+    # a frame's persist from inside (ckpt_saver._persist_one, both the
+    # save-event and the breakpoint path): the frame lock held and the
+    # frame read out of shm, the CRC pass over it, the stripes and the
+    # manifest link written
+    CKPT_PERSIST_READ = "ckpt.persist.read"
+    CKPT_PERSIST_VERIFY = "ckpt.persist.verify"
+    CKPT_PERSIST_WRITE = "ckpt.persist.write"
     # the same three from inside (ckpt/engine.py), one span a phase and
     # none a leaf on the save's blocking path. Save block: readiness
     # (drain-alive check, lock, peer exchange), the planning pass
@@ -506,6 +516,16 @@ class SpanName:
     FAULT_RELAUNCH = "fault.relaunch"
     AGENT_RESTART_WORKERS = "agent.restart_workers"
     AGENT_STACK_DUMP = "agent.stack_dump"
+    # the same arc from inside the agent (agent/training.py): the root
+    # opened at the poll that saw a worker die, the failure report and
+    # verdict, then under ``agent.restart_workers`` the stop, the
+    # breakpoint ``ckpt.persist``, ``rdzv.client_round`` and the spawn
+    AGENT_WORKER_FAILURE = "agent.worker_failure"
+    AGENT_FAILURE_REPORT = "agent.failure_report"
+    AGENT_STOP_WORKERS = "agent.stop_workers"
+    AGENT_SPAWN = "agent.spawn"
+    # the launcher's node health check before training (agent/run.py)
+    AGENT_NODE_CHECK = "agent.node_check"
     # span events (retry plane, chaos plane, serving reroutes)
     EVT_RPC_RETRY = "rpc.retry"
     EVT_BREAKER_OPEN = "rpc.breaker_open"

@@ -35,13 +35,7 @@ from dlrover_tpu.parallel.ring_attention import (
     ring_attention,
     sharded_flash_attention,
 )
-from dlrover_tpu.parallel.sharding import (
-    head_shards_gauge,
-    head_split,
-    valid_spec_for,
-    vocab_shards_gauge,
-    vocab_split,
-)
+from dlrover_tpu.parallel.sharding import valid_spec_for, vocab_split
 from dlrover_tpu.parallel.ulysses import ulysses_attention
 
 
@@ -217,11 +211,6 @@ def _attention(x, layer, config: LlamaConfig, positions, mesh):
     q = _rope(q, positions, c.rope_theta)
     k = _rope(k, positions, c.rope_theta)
     q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # (B,H,S,D)
-    # the chips the heads split over, as the projections' leaves are laid
-    # out by the same rule: no gather before the kernel, no sum after it
-    # but the one that follows wo
-    head_shards_gauge().set(
-        head_split(mesh, c.n_heads)[1] if mesh is not None else 1)
     strategy = c.sp_strategy
     if strategy not in (None, "ring", "ulysses"):
         raise ValueError(
@@ -409,7 +398,6 @@ def head_nll(x, weight, targets, mesh=None):
     hidden states' gradient over its chips (the Megatron-LM
     vocabulary-parallel cross-entropy, in this file's precision)."""
     over, chips = vocab_split(mesh, weight.shape[1])
-    vocab_shards_gauge().set(chips)
     if chips == 1:
         return token_nll(lm_head(x, weight), targets)
     # manual over the whole mesh, as the experts and the flash kernel

@@ -1,16 +1,24 @@
 """Crash flight recorder: one self-contained post-mortem bundle.
 
 When something dies — unhandled exception, degraded-partition exit,
-injected chaos fault — or an operator asks (``GET /debug/bundle`` on the
+injected chaos fault — when an agent that restarted workers exits, or an
+operator asks (``GET /debug/bundle`` on the
 master/agent HTTP servers), ``FlightRecorder.capture()`` writes a bundle
 directory that replays the job's last minutes without access to the live
 process:
 
     <trace_dir>/bundle_<source>_<reason>_<n>_<pid>/
-        manifest.json   reason, source, wall timestamp, span/event counts
+        manifest.json   reason, source, span/event counts, and the clocks:
+                        ``monotonic_t`` and ``wall_ts`` read together,
+                        ``trace_t0_monotonic`` the instant traces.json's
+                        zero stands for
         traces.json     chrome trace: the tracing ring (finished + live
                         spans) merged with timeline.py's "job phases" and
                         "cross-worker skew" journal tracks, on one clock
+        spans.json      the same spans as ``Span.to_dict`` gives them, raw
+                        ``time.monotonic()`` stamps: any other process of
+                        the host (a worker's ring, a profile's ``dlrover:``
+                        lines) lays beside them with no offset
         journal.json    the event journal tail (EventJournal.to_json())
         incidents.json  stitched fault→recovery Incident records for the
                         same journal tail (observability/incidents.py)
@@ -53,6 +61,7 @@ REASON_PARTITION = "partition_degraded"
 REASON_CHAOS = "chaos_fault"
 REASON_NODE_FAULT = "node_fault"
 REASON_MEMORY = "memory_pressure"
+REASON_AGENT_EXIT = "agent_exit"
 
 DEFAULT_COOLDOWN_S = 30.0
 
@@ -194,13 +203,13 @@ class FlightRecorder:
 
         # one clock for every track: when a journal is present, map raw
         # monotonic span stamps onto its job-relative zero so span slices
-        # line up under the "job phases" track in the same perfetto load
+        # line up under the "job phases" track in the same perfetto load;
+        # without one the earliest span is the zero
+        monotonic_t, wall_ts = time.monotonic(), time.time()
         if journal_dict is not None:
-            now_t = float(journal_dict.get("now_t", 0.0))
-            t0 = time.monotonic() - now_t
+            t0 = monotonic_t - float(journal_dict.get("now_t", 0.0))
         else:
-            now_t = None
-            t0 = None
+            t0 = min((sp.start_t for sp in finished + live), default=None)
         events = tracing.to_chrome_events(finished + live, t0=t0)
         from dlrover_tpu.observability.timeline import (
             serving_request_events,
@@ -223,6 +232,8 @@ class FlightRecorder:
             events.extend(device_track_events(journal_dict))
         with open(os.path.join(bundle_dir, "traces.json"), "w") as f:
             json.dump({"traceEvents": events}, f)
+        with open(os.path.join(bundle_dir, "spans.json"), "w") as f:
+            json.dump([sp.to_dict() for sp in finished + live], f)
 
         worst = None
         if self.worst_traces_fn is not None:
@@ -289,7 +300,11 @@ class FlightRecorder:
             "source": self.source,
             "seq": seq,
             "pid": os.getpid(),
-            "wall_ts": time.time(),  # reported, never compared
+            # the host's two clocks read together: what places spans.json
+            # (and the ring of any other process of the host) in wall time
+            "monotonic_t": monotonic_t,
+            "wall_ts": wall_ts,  # reported, never compared
+            "trace_t0_monotonic": t0,
             "spans_finished": len(finished),
             "spans_live": len(live),
             "spans_dropped": tracer.dropped(),

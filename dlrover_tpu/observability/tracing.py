@@ -14,6 +14,10 @@ serializing the context into whatever envelope already crosses it:
   it rides the existing ``HeartbeatResponse.action_data`` path down.
 - worker→saver: the checkpoint SAVE event dict carries it over the
   SharedQueue IPC boundary.
+- agent→worker: the spawn's context rides the worker's environment
+  (``inject_env``, ``EnvKey.TRACE_PARENT``); ``worker.init()`` hands it
+  to the tracer and the process's first ``ckpt.restore`` takes it, so a
+  resumed worker's restore joins the fault arc that started it.
 - threads: capture ``current_context()`` before spawning, ``activate()``
   it inside (thread-locals don't inherit).
 
@@ -116,14 +120,14 @@ class Span:
 
     def __init__(self, tracer: "Tracer", name: str, source: str,
                  trace_id: str, parent_id: Optional[str],
-                 attrs: Dict[str, Any]):
+                 attrs: Dict[str, Any], start_t: Optional[float] = None):
         self._tracer = tracer
         self.name = name
         self.source = source
         self.trace_id = trace_id
         self.span_id = _new_id()
         self.parent_id = parent_id
-        self.start_t = time.monotonic()
+        self.start_t = time.monotonic() if start_t is None else start_t
         self.end_t: Optional[float] = None
         self.start_wall_ts = time.time()  # reported, never compared
         self.status = "ok"
@@ -229,11 +233,15 @@ class Tracer:
         self._live: Dict[str, Span] = {}
         self._started = 0
         self._finished = 0
+        self._handed: Optional[TraceContext] = None
 
     def span(self, name: str, source: str = "",
-             parent: Optional[TraceContext] = None, **attrs: Any):
+             parent: Optional[TraceContext] = None,
+             start_t: Optional[float] = None, **attrs: Any):
         """Open a span under ``parent`` (default: the thread's current
-        context; a fresh trace when there is none)."""
+        context; a fresh trace when there is none). ``start_t`` backdates
+        it to a ``time.monotonic()`` already read (the poll that saw what
+        the span is about)."""
         if not self.enabled:
             return _NOOP
         if parent is None:
@@ -242,11 +250,23 @@ class Tracer:
             trace_id, parent_id = parent[0], parent[1]
         else:
             trace_id, parent_id = _new_id(), None
-        sp = Span(self, name, source, trace_id, parent_id, attrs)
+        sp = Span(self, name, source, trace_id, parent_id, attrs, start_t)
         with self._lock:
             self._started += 1
             self._live[sp.span_id] = sp
         return sp
+
+    def hand_over(self, ctx: Optional[TraceContext]) -> None:
+        """Keep a context another process handed this one (the agent's
+        spawn, ``extract_env``) for the one span that takes it."""
+        with self._lock:
+            self._handed = ctx
+
+    def take_handed(self) -> Optional[TraceContext]:
+        """The handed-over context, once: later calls get ``None``."""
+        with self._lock:
+            ctx, self._handed = self._handed, None
+        return ctx
 
     def _finish(self, span: Span) -> None:
         with self._lock:
@@ -339,9 +359,11 @@ def activate(ctx: Optional[TraceContext]) -> Iterator[None]:
 
 
 def span(name: str, source: str = "",
-         parent: Optional[TraceContext] = None, **attrs: Any):
+         parent: Optional[TraceContext] = None,
+         start_t: Optional[float] = None, **attrs: Any):
     """Module-level convenience for ``get_tracer().span(...)``."""
-    return get_tracer().span(name, source=source, parent=parent, **attrs)
+    return get_tracer().span(name, source=source, parent=parent,
+                             start_t=start_t, **attrs)
 
 
 def add_span_event(name: str, **attrs: Any) -> None:
@@ -387,6 +409,22 @@ def extract_wire(payload: Any) -> Optional[TraceContext]:
     if not trace_id:
         return None
     return TraceContext(str(trace_id), str(payload.get("s", "")))
+
+
+def inject_env() -> Optional[str]:
+    """The active context as one environment value, ``"<trace_id>:
+    <span_id>"`` (a spawned process's envelope), or ``None`` where
+    ``inject_wire`` gives none."""
+    wire = inject_wire()
+    return None if wire is None else f"{wire['t']}:{wire['s']}"
+
+
+def extract_env(value: Optional[str]) -> Optional[TraceContext]:
+    """Parse what ``inject_env`` wrote; ``None`` for anything else."""
+    trace_id, _, span_id = (value or "").partition(":")
+    if not trace_id or not span_id:
+        return None
+    return TraceContext(trace_id, span_id)
 
 
 # -- chrome-trace export ----------------------------------------------------

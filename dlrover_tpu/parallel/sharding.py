@@ -181,30 +181,6 @@ def head_split(mesh, n_heads: int):
     return over, chips
 
 
-def vocab_shards_gauge():
-    """``dlrover_head_vocab_shards``: set by the loss as it is traced
-    (models/llama.py ``head_nll``), read by the trainer into the
-    ``train.step`` span once its step is built."""
-    from dlrover_tpu.observability.registry import get_registry
-
-    return get_registry().gauge(
-        "dlrover_head_vocab_shards",
-        "Chips the output head's vocabulary is split over in the loss "
-        "traced last (1: every chip makes the whole logits)")
-
-
-def head_shards_gauge():
-    """``dlrover_attn_head_shards``: set by the attention block as it is
-    traced (models/llama.py ``_attention``), read by the trainer into the
-    ``train.step`` span once its step is built."""
-    from dlrover_tpu.observability.registry import get_registry
-
-    return get_registry().gauge(
-        "dlrover_attn_head_shards",
-        "Chips attention's heads are split over in the attention traced "
-        "last (1: every chip computes every head)")
-
-
 def shard_tree(mesh, state, logical_tree, rules: Optional[Dict] = None):
     """device_put a pytree according to its logical axes (with per-leaf
     divisibility validation)."""

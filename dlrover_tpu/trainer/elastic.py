@@ -25,10 +25,6 @@ from dlrover_tpu.observability import tracing
 from dlrover_tpu.observability.compile_watch import get_watcher
 from dlrover_tpu.observability.memory import get_accountant
 from dlrover_tpu.parallel.mesh import ElasticMeshManager, MeshPlan, plan_mesh
-from dlrover_tpu.parallel.sharding import (
-    head_shards_gauge,
-    vocab_shards_gauge,
-)
 
 
 class TrainStepResult(NamedTuple):  # NamedTuple ⇒ a pytree, jit can return it
@@ -250,14 +246,6 @@ class ElasticTrainer:
             # the counter is read only to fill the span's attribute
             requests = watcher.compile_requests() if traced else 0
             out = self._train_step(state, batch)
-            if built:
-                # what the model said as it was traced (models/llama.py
-                # _attention, head_nll); 0 where no such model ever was
-                for attr, gauge in (("head_shards", head_shards_gauge()),
-                                    ("vocab_shards", vocab_shards_gauge())):
-                    shards = int(gauge.value)
-                    if shards:
-                        self._span_attrs[attr] = sp.attrs[attr] = shards
             if traced:
                 compiles = watcher.compile_requests() - requests
                 if compiles:  # what the backend was asked, cached or not

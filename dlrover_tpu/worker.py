@@ -195,6 +195,10 @@ def init(initialize_jax_distributed: bool = True) -> WorkerContext:
     import jax.profiler
 
     tracing.install_bridge(jax.profiler.TraceAnnotation)
+    # the agent's spawn context, for the first restore to join (a resumed
+    # worker's restore then sits in the fault arc that started it)
+    tracing.get_tracer().hand_over(
+        tracing.extract_env(env_str(EnvKey.TRACE_PARENT)))
     # and its compile requests are counted from here on, set-up's too
     compile_watch.get_watcher()
     coordinator = os.getenv(EnvKey.COORDINATOR_ADDR, "")

@@ -573,12 +573,10 @@ class TestVocabShardedHead:
         assert "4 chips" in said[0] and "stay whole" in said[0]
 
     def test_the_step_says_how_many_chips_share_the_vocabulary(self):
-        """``dlrover_head_vocab_shards`` and the ``train.step`` span's
-        ``vocab_shards``: 4 once a step is built on ``ep`` 4, 1 on no
-        mesh."""
-        from dlrover_tpu.common.constants import SpanName
-        from dlrover_tpu.observability import tracing
-        from dlrover_tpu.observability.registry import get_registry
+        """Where the split is made: ``vocab_split`` of the step's mesh
+        gives 4 chips on ``ep`` 4 and 1 on no mesh, and after two steps
+        each chip's ``lm_head`` holds that share of the vocabulary's
+        columns (the step keeps the layout it was given)."""
         from dlrover_tpu.trainer.elastic import (
             ElasticTrainer,
             make_train_state,
@@ -587,35 +585,26 @@ class TestVocabShardedHead:
         c = _tiny(vocab_size=264)         # a signature no other test has
         optimizer = optax.sgd(0.1)
         plan = plan_mesh(8, ep=4)
-        tracing.reset_tracer()
-        try:
-            for mesh, rows, want in ((build_mesh(plan), 2, 4), (None, 1, 1)):
-                params = moe.init_params(c, jax.random.PRNGKey(0))
-                if mesh is not None:
-                    params = shard_tree(
-                        mesh, params, moe.param_logical_axes(c))
-                trainer = ElasticTrainer(
-                    loss_fn=lambda p, t, mesh=mesh: moe.next_token_loss(
-                        p, t, c, mesh),
-                    optimizer=optimizer, global_batch_size=2 * rows,
-                    micro_batch_per_replica=1)
-                trainer.configure_for_world(
-                    plan if mesh is not None else plan_mesh(1))
-                state = make_train_state(params, optimizer)
-                batch = _HEAD_TOKENS[:2 * rows, :17].reshape(2, rows, 17)
-                for _ in range(2):
-                    state, result = trainer.train_step(state, batch)
-                assert bool(jnp.isfinite(result.loss))
-                assert get_registry().gauge(
-                    "dlrover_head_vocab_shards").value == want
-                assert f"dlrover_head_vocab_shards {want}" \
-                    in get_registry().render()
-                spans = [sp for sp in tracing.get_tracer().finished_spans()
-                         if sp.name == SpanName.TRAIN_STEP][-2:]
-                assert [sp.attrs["vocab_shards"] for sp in spans] \
-                    == [want, want]
-        finally:
-            tracing.reset_tracer()
+        for mesh, rows, want in ((build_mesh(plan), 2, 4), (None, 1, 1)):
+            params = moe.init_params(c, jax.random.PRNGKey(0))
+            if mesh is not None:
+                params = shard_tree(
+                    mesh, params, moe.param_logical_axes(c))
+            trainer = ElasticTrainer(
+                loss_fn=lambda p, t, mesh=mesh: moe.next_token_loss(
+                    p, t, c, mesh),
+                optimizer=optimizer, global_batch_size=2 * rows,
+                micro_batch_per_replica=1)
+            trainer.configure_for_world(
+                plan if mesh is not None else plan_mesh(1))
+            state = make_train_state(params, optimizer)
+            batch = _HEAD_TOKENS[:2 * rows, :17].reshape(2, rows, 17)
+            for _ in range(2):
+                state, result = trainer.train_step(state, batch)
+            assert bool(jnp.isfinite(result.loss))
+            assert sharding.vocab_split(mesh, c.vocab_size)[1] == want
+            for shard in state["params"]["lm_head"].addressable_shards:
+                assert shard.data.shape[1] == c.vocab_size // want
 
 
 # -- attention's heads over ep and tp ------------------------------------------
@@ -706,13 +695,9 @@ class TestHeadsOverTheGroup:
     @pytest.mark.parametrize("mesh_id", ["ep4xfsdp2", "ep2xtp2xfsdp2",
                                          "tp2xfsdp4", "one-device"])
     def test_the_step_says_how_many_chips_split_the_heads(self, mesh_id):
-        """``dlrover_attn_head_shards`` and the ``train.step`` span's
-        ``head_shards``: ``ep x tp`` once a step is built on the mesh, 1
-        on one device."""
-        from dlrover_tpu.common.constants import SpanName
-        from dlrover_tpu.observability import tracing
-        from dlrover_tpu.observability.registry import get_registry
-
+        """Where the split is made: ``head_split`` of the step's mesh gives
+        ``ep x tp`` chips (1 on one device), and after two steps each
+        chip's ``wq`` holds that share of the query heads."""
         c = _attn_tiny(vocab_size=272)    # a signature no other test has
         axes = HEAD_MESHES.get(mesh_id)
         mesh = (build_mesh(plan_mesh(8, **axes)) if axes
@@ -720,23 +705,16 @@ class TestHeadsOverTheGroup:
         want = mesh.shape["ep"] * mesh.shape["tp"]
         params = shard_tree(mesh, moe.init_params(c, jax.random.PRNGKey(0)),
                             moe.param_logical_axes(c))
-        tracing.reset_tracer()
-        try:
-            trainer, state, tokens = _step(
-                lambda p, t: moe.next_token_loss(p, t, c, mesh), params,
-                mesh, rows=1)
-            for _ in range(2):
-                state, result = trainer.train_step(state, tokens)
-            assert bool(jnp.isfinite(result.loss))
-            assert get_registry().gauge(
-                "dlrover_attn_head_shards").value == want
-            assert f"dlrover_attn_head_shards {want}" \
-                in get_registry().render()
-            spans = [sp for sp in tracing.get_tracer().finished_spans()
-                     if sp.name == SpanName.TRAIN_STEP][-2:]
-            assert [sp.attrs["head_shards"] for sp in spans] == [want] * 2
-        finally:
-            tracing.reset_tracer()
+        trainer, state, tokens = _step(
+            lambda p, t: moe.next_token_loss(p, t, c, mesh), params,
+            mesh, rows=1)
+        for _ in range(2):
+            state, result = trainer.train_step(state, tokens)
+        assert bool(jnp.isfinite(result.loss))
+        assert sharding.head_split(mesh, c.n_heads)[1] == want
+        width = c.n_heads // want * c.head_dim
+        for shard in state["params"]["layers"]["wq"].addressable_shards:
+            assert shard.data.shape[-1] == width
 
     def test_the_expert_step_sums_no_query_key_or_value(self):
         """The step on ``ep`` 4 through the flash kernel's ``shard_map``:
@@ -810,11 +788,11 @@ class TestHeadsOverTheGroup:
         assert '{"tp"}' in now
 
     def test_heads_the_group_does_not_divide_stay_whole(self, monkeypatch):
-        """Five heads over ``ep`` 4: every chip computes every head, the
-        loss and gradients are the meshless ones, the gauge reads 1, and
-        the log says so once however often attention is traced."""
+        """Five heads over ``ep`` 4: ``head_split`` gives one chip, so
+        every chip computes every head, the loss and gradients are the
+        meshless ones, and the log says so once however often attention
+        is traced."""
         from dlrover_tpu.common.log import logger
-        from dlrover_tpu.observability.registry import get_registry
 
         c = llama.LlamaConfig(
             vocab_size=256, dim=40, n_layers=2, n_heads=5, n_kv_heads=5,
@@ -837,7 +815,6 @@ class TestHeadsOverTheGroup:
         loss, grads = jax.jit(jax.value_and_grad(
             lambda p, t: llama.next_token_loss(p, t, c, mesh)))(sharded, tok_s)
         llama.next_token_loss(sharded, tok_s[:, :17], c, mesh)  # traced anew
-        assert get_registry().gauge("dlrover_attn_head_shards").value == 1
         tol = dict(atol=2e-3, rtol=2e-3)
         np.testing.assert_allclose(float(loss), float(ref_loss), **tol)
         for name in ATTN_LEAVES:

@@ -276,8 +276,10 @@ def test_flight_recorder_bundle_contents(tmp_path):
     assert path is not None and os.path.isdir(path)
     files = sorted(os.listdir(path))
     assert files == ["config.json", "incidents.json", "journal.json",
-                     "manifest.json", "metrics.prom", "stacks.txt",
-                     "traces.json"]
+                     "manifest.json", "metrics.prom", "spans.json",
+                     "stacks.txt", "traces.json"]
+    with open(os.path.join(path, "spans.json")) as f:
+        assert SpanName.RDZV_JOIN in {sp["name"] for sp in json.load(f)}
 
     with open(os.path.join(path, "traces.json")) as f:
         traces = json.load(f)
