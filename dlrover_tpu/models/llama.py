@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.common.log import log_once
-from dlrover_tpu.ops.flash_attention import flash_attention
+from dlrover_tpu.ops.flash_attention import FLASH_RESIDUALS, flash_attention
 from dlrover_tpu.parallel.ring_attention import (
     full_causal_attention,
     ring_attention,
@@ -71,7 +71,10 @@ class LlamaConfig(AttentionConfigMixin):
     remat: bool = True
     # remat policy: "dots" saves matmul outputs and recomputes only the
     # cheap elementwise/attention-softmax work in backward (~5% FLOPs
-    # overhead vs ~33% for full per-layer remat); None = save nothing
+    # overhead vs ~33% for full per-layer remat); None = save nothing but
+    # what every policy keeps: the flash kernel's output and log-sum-exp
+    # (B·H·S·D in the model's dtype + B·H·S f32 a layer application, about
+    # one (B, S, D) activation), so the backward pass never reruns it
     remat_policy: Optional[str] = "dots"
     # long-context strategy applied when the sp mesh axis is >1:
     # None = no sequence-parallel attention;
@@ -268,12 +271,17 @@ rms_norm = _rms_norm
 
 
 def _remat_policy(config):
-    """Map the config's remat_policy name to a jax.checkpoint policy."""
+    """Map the config's remat_policy name to a jax.checkpoint policy.
+    Every policy keeps the flash kernel's output and log-sum-exp: a Pallas
+    call is no dot, and unkept its backward pass runs the kernel again."""
     name = getattr(config, "remat_policy", None)
+    policies = jax.checkpoint_policies
+    flash = policies.save_only_these_names(*FLASH_RESIDUALS)
     if name == "dots":
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        return policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable, flash)
     if name is None:
-        return None
+        return flash
     raise ValueError(f"unknown remat_policy {name!r}")
 
 

@@ -20,7 +20,10 @@ as models/moe.py does:
 Memory is what shapes the code. A microbatch keeps ``n_passes x n_layers``
 layer applications alive for the backward pass, so the layers are fully
 rematerialised by default (``remat_policy`` None: one (B, S, D) input a
-layer application), and each pass's head runs under ``jax.checkpoint`` so
+layer application, and the flash kernel's output and log-sum-exp that
+every policy keeps, about one (B, S, D) more, so that the backward pass
+does not run the forward kernel again), and each pass's head runs under
+``jax.checkpoint`` so
 that only ``h_t`` survives it, not ``n_passes`` f32 logit blocks. The
 shared weights' cotangents are summed over the passes in float32
 (``_summed_in_f32``).
@@ -39,7 +42,8 @@ from dlrover_tpu.models import llama as _llama
 @dataclass(frozen=True)
 class LoopedConfig(_llama.LlamaConfig):
     # every layer application is kept for the backward pass n_passes times
-    # over: save nothing by default ("dots" is 12x the bytes a layer)
+    # over: save nothing by default but the flash kernel's output and
+    # log-sum-exp, which every policy keeps ("dots" is 12x the bytes a layer)
     remat_policy: Optional[str] = None
     n_passes: int = 4
     # weight of the exit distribution's entropy in the loss

@@ -264,6 +264,9 @@ def _grouped_matmul(rows, w, group_sizes):
 # The backward pass needs each again (the down projection's output for
 # the gradient of the gates): unsaved they are three of twelve grouped
 # matmuls a microbatch (PERF.md §6, PR 30)
+# Every policy, None too, also keeps the flash kernel's output and
+# log-sum-exp (``llama._remat_policy``: B·H·S·D bf16 + B·H·S f32 a layer
+# application)
 _SAVED = ("moe_gate", "moe_up", "moe_down")
 
 
@@ -271,8 +274,8 @@ def _remat_policy(config):
     """The llama policy, with the experts' named projections saved
     wherever it saves dots."""
     policy = _llama._remat_policy(config)
-    if policy is None:
-        return None
+    if config.remat_policy is None:
+        return policy
     return jax.checkpoint_policies.save_from_both_policies(
         policy, jax.checkpoint_policies.save_only_these_names(*_SAVED))
 

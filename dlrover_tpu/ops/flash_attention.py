@@ -30,6 +30,13 @@ Design (MXU/VMEM-first):
   formulation: ``ds = p * (dp - delta)`` with
   ``delta = rowsum(do * o) - dlse`` (the ``dlse`` term supports cotangents
   flowing into the returned lse from the ring merge).
+- The forward rule names ``o`` and ``lse`` (``FLASH_RESIDUALS``) and every
+  remat policy of ``models/`` keeps them: a rematerialised layer's
+  backward pass reads them instead of running the forward kernel again.
+  That costs ``B·H·S·D`` in the input dtype plus ``B·H·S`` f32 a call:
+  one output a layer application; under ring attention one a ring step,
+  ``sp`` outputs of ``S / sp`` rows a layer on each device, as much as one
+  output of the whole sequence.
 
 On non-TPU backends (CPU tests) the kernels run in pallas interpret mode.
 """
@@ -39,6 +46,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -47,6 +55,9 @@ from dlrover_tpu.common.log import log_once
 
 NEG_INF = float(-1e30)  # avoid -inf arithmetic inside the kernel
 LANES = 128  # lane width for replicated row statistics
+# ``checkpoint_name``s of the forward kernel's output and log-sum-exp, the
+# backward kernels' residuals that only the forward kernel can make
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
 
 
 def _default_interpret() -> bool:
@@ -424,6 +435,11 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
         q, k, v, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, interpret=interpret,
     )
+    # named, so that a remat policy can keep the two residuals only the
+    # kernel makes (q, k, v a replay remakes from the projections); outside
+    # ``jax.checkpoint`` a name does nothing
+    o = checkpoint_name(o, FLASH_RESIDUALS[0])
+    lse = checkpoint_name(lse, FLASH_RESIDUALS[1])
     return (o, lse), (q, k, v, o, lse)
 
 

@@ -193,12 +193,10 @@ def test_expert_layer_over_ep4_runs_the_grouped_kernel(topo, monkeypatch):
 
 def test_train_step_one_layer_fits_the_chip(topo, monkeypatch):
     """``ElasticTrainer._build_step`` at 7B widths, depth 1: the flash
-    kernel is in the program forward and backward, and XLA's own memory
-    analysis stays under the chip's 16 GB."""
-    # the step picks interpret mode from the default backend, which is the
-    # CPU here: steer it, as the chip would
-    fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
-    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+    kernel is in the program forward and backward, each of the three
+    kernels once, and XLA's own memory analysis stays under the chip's
+    16 GB."""
+    _on_chip(monkeypatch)
     cfg = dataclasses.replace(
         llama.LlamaConfig.llama7b(), n_layers=1, max_seq_len=S,
         use_flash_attention=True,
@@ -226,10 +224,9 @@ def test_train_step_one_layer_fits_the_chip(topo, monkeypatch):
     # the compiled program's custom calls carry the kernels' names as
     # their own (``%flash_fwd.13 = ... custom-call(``), and a profile
     # names an op's events by that line
-    calls = [line.split()[0] for line in compiled.as_text().splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+    calls = _kernel_calls(compiled.as_text())
     for kernel in KERNEL_NAMES:
-        assert any(name.startswith(f"%{kernel}.") for name in calls), calls
+        assert _count(calls, kernel) == 1, calls
     mem = compiled.memory_analysis()
     # the donated state is aliased to the output: counted once
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
@@ -254,37 +251,47 @@ def _collectives(text):
     return found
 
 
-def test_expert_cell_step_never_holds_the_logits_whole(topo, monkeypatch):
+def _kernel_calls(text):
+    """Names of the Pallas calls of a compiled program
+    (``%flash_fwd.13 = ... custom-call(``)."""
+    return [line.split()[0] for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _count(calls, kernel):
+    return sum(name.startswith(f"%{kernel}.") for name in calls)
+
+
+def _on_chip(monkeypatch):
+    """Steer what the step picks from the default backend, which is the
+    CPU here, as the chip would: no interpret mode, the Pallas kernels."""
+    fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+EXPERT_SEQ = 4096
+
+
+@pytest.fixture(scope="module")
+def expert_step(topo):
     """The expert cell's whole step (``benchmarks/configs/mixtral-8x7b``:
     published widths, depth 1, ``ep`` 4, two microbatches of one row of
-    4,096 tokens) with the vocabulary of head and embedding spread over
-    the group: no array of the program is logits-shaped at the whole
-    vocabulary, none has a dimension of ``vocab_size`` at all, nothing
-    vocabulary-sized is gathered, and what the chips exchange is
-    all-reduces only: of hidden states (the experts' sum, the heads'
-    sum after ``wo`` and before the projections' input gradient, the
-    embedding's rows, the head's gradient), of per-token f32 statistics
-    and of scalars. None sums q, k or v: attention's heads are split
-    over the group as the projections made them."""
+    4,096 tokens), compiled once for the tests below: (compiled text,
+    the configuration's fields, the parameters' shardings)."""
     import json
-    import math
     import os
-    import re
 
     from benchmarks.families import mixtral_moe as family
     from dlrover_tpu.parallel.sharding import valid_spec_for
 
-    fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
-    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(
             root, "benchmarks", "configs", "mixtral-8x7b.json")) as f:
         fields = json.load(f)
-    seq, vocab, hidden = 4096, fields["vocab_size"], fields["hidden_size"]
     plan = plan_mesh(4, **fields["mesh"])
     mesh = build_mesh(plan, devices=list(topo.devices))
-    cfg = family.program_config(fields, seq)
+    cfg = family.program_config(fields, EXPERT_SEQ)
     optimizer = optax.adamw(3e-4)
     shapes = jax.eval_shape(
         lambda: family.init_params(cfg, jax.random.PRNGKey(0)))
@@ -294,8 +301,6 @@ def test_expert_cell_step_never_holds_the_logits_whole(topo, monkeypatch):
         lambda axes, leaf: NamedSharding(
             mesh, valid_spec_for(mesh, leaf.shape, axes)),
         family.logical_axes(cfg), shapes, is_leaf=is_axes)
-    assert on_mesh["lm_head"].shard_shape((hidden, vocab)) \
-        == (hidden, vocab // 4)
     whole = NamedSharding(mesh, P())
     state = jax.eval_shape(lambda: make_train_state(shapes, optimizer))
     # the moments lie as their parameters do, the counters on every chip
@@ -312,9 +317,31 @@ def test_expert_cell_step_never_holds_the_logits_whole(topo, monkeypatch):
         loss_fn=family.loss_fn(cfg, mesh), optimizer=optimizer,
         global_batch_size=2, micro_batch_per_replica=1)
     trainer.configure_for_world(plan)
-    tokens = _shape((2, 1, seq + 1), jnp.int32, whole)
-    text = trainer._build_step().lower(state, tokens).compile().as_text()
+    tokens = _shape((2, 1, EXPERT_SEQ + 1), jnp.int32, whole)
+    with pytest.MonkeyPatch.context() as monkeypatch:
+        _on_chip(monkeypatch)
+        text = trainer._build_step().lower(state, tokens).compile().as_text()
+    return text, fields, on_mesh
 
+
+def test_expert_cell_step_never_holds_the_logits_whole(expert_step):
+    """The expert cell's step with the vocabulary of head and embedding
+    spread over the group: no array of the program is logits-shaped at
+    the whole vocabulary, none has a dimension of ``vocab_size`` at all,
+    nothing vocabulary-sized is gathered, and what the chips exchange is
+    all-reduces only: of hidden states (the experts' sum, the heads'
+    sum after ``wo`` and before the projections' input gradient, the
+    embedding's rows, the head's gradient), of per-token f32 statistics
+    and of scalars. None sums q, k or v: attention's heads are split
+    over the group as the projections made them."""
+    import math
+    import re
+
+    text, fields, on_mesh = expert_step
+    seq, vocab = EXPERT_SEQ, fields["vocab_size"]
+    hidden = fields["hidden_size"]
+    assert on_mesh["lm_head"].shard_shape((hidden, vocab)) \
+        == (hidden, vocab // 4)
     found = _collectives(text)
     said = "collectives of the step:\n  " + "\n  ".join(found)
     logits = re.findall(rf"\w+\[(?:\d+,)*{seq},{vocab}\]", text)
@@ -338,3 +365,46 @@ def test_expert_cell_step_never_holds_the_logits_whole(topo, monkeypatch):
         sizes = [math.prod(int(d) for d in dims.split(",") if d)
                  for dims in re.findall(r"\[([\d,]*)\]", c)]
         assert max(sizes, default=1) <= 2 * seq, f"{c}\n{said}"
+
+
+def test_expert_cell_step_runs_the_forward_kernel_once(expert_step):
+    """The expert cell's layer under ``jax.checkpoint`` keeps the flash
+    kernel's output and log-sum-exp, so its backward pass runs no second
+    forward kernel: one call of each of the three a step program (the
+    microbatch loop's body holds one layer)."""
+    calls = _kernel_calls(expert_step[0])
+    for kernel in KERNEL_NAMES:
+        assert _count(calls, kernel) == 1, calls
+
+
+def test_looped_step_runs_the_forward_kernel_once(topo, monkeypatch):
+    """The looped model's step at chip-aligned widths (2 layers run 2
+    passes, heads 128 wide, full remat as ``benchmarks/configs/ouro-2.6b``
+    has it): the backward pass reads the output and log-sum-exp the
+    forward kernel made, so the program calls each kernel once, where
+    replaying the whole layer called the forward kernel twice."""
+    from dlrover_tpu.models import looped
+
+    _on_chip(monkeypatch)
+    seq = 1024
+    cfg = looped.LoopedConfig(
+        vocab_size=2048, dim=256, n_layers=2, n_heads=2, n_kv_heads=2,
+        ffn_dim=512, max_seq_len=seq, n_passes=2, use_flash_attention=True)
+    assert cfg.remat and cfg.remat_policy is None
+    plan = plan_mesh(1)
+    mesh = build_mesh(plan, devices=list(topo.devices))
+    on_mesh = NamedSharding(mesh, P())
+    optimizer = optax.adamw(3e-4)
+    trainer = ElasticTrainer(
+        loss_fn=looped.make_loss_fn(cfg, mesh), optimizer=optimizer,
+        global_batch_size=2, micro_batch_per_replica=1)
+    trainer.configure_for_world(plan)
+    state = jax.eval_shape(
+        lambda: make_train_state(
+            looped.init_params(cfg, jax.random.PRNGKey(0)), optimizer))
+    state = jax.tree.map(lambda x: _shape(x.shape, x.dtype, on_mesh), state)
+    tokens = _shape((2, 1, seq + 1), jnp.int32, on_mesh)
+    text = trainer._build_step().lower(state, tokens).compile().as_text()
+    calls = _kernel_calls(text)
+    for kernel in KERNEL_NAMES:
+        assert _count(calls, kernel) == 1, calls
