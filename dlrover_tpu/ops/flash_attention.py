@@ -15,9 +15,16 @@ Design (MXU/VMEM-first):
 - Each step is one ``(block_q, d) @ (d, block_k)`` MXU matmul in f32 plus
   VPU elementwise (exp / mask / rescale); inputs stay bf16, accumulation
   f32 (``preferred_element_type``).
-- Causal masking is block-structured: fully-future K blocks are skipped
-  under ``pl.when`` (no FLOPs), the diagonal block applies the triangular
-  mask, past blocks apply only the length mask.
+- Every grid step falls in one of three classes by its block's place
+  against the causal diagonal and the padded tails (``flash_block_plan``
+  counts them): a *skip* step lies wholly in the causal future and runs
+  no body, and its index maps point at a block the pipeline already
+  holds or fetches next, so it issues no DMA either; a *full* step lies
+  wholly on or below the diagonal and inside both true lengths, and runs
+  the score chain with no iota, compare or ``where``; a *masked* step
+  crosses the diagonal or holds padded rows or columns, and builds and
+  applies the mask. The classes follow from the program ids and the
+  static shapes only; a length mask exists only where padding does.
 - Row statistics (``m``/``l``/``lse``) are kept lane-replicated with shape
   ``(block_q, 128)`` — the VMEM-tileable layout for per-row scalars (same
   scheme as XLA's reference kernels).
@@ -42,7 +49,7 @@ On non-TPU backends (CPU tests) the kernels run in pallas interpret mode.
 """
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +59,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from dlrover_tpu.common.constants import ConfigKey, env_int
 from dlrover_tpu.common.log import log_once
+from dlrover_tpu.observability.registry import get_registry
 
 NEG_INF = float(-1e30)  # avoid -inf arithmetic inside the kernel
 LANES = 128  # lane width for replicated row statistics
@@ -90,13 +98,156 @@ def repeat_kv(k, v, rep: int):
 
 
 # ---------------------------------------------------------------------------
+# the causal block plan
+# ---------------------------------------------------------------------------
+
+
+class BlockPlan(NamedTuple):
+    """Grid steps of one (row, head) of a flash call, by what each does."""
+
+    skip: int    # wholly in the causal future: no body, no fetch
+    full: int    # wholly attended, no padding: the body without a mask
+    masked: int  # crosses the diagonal or holds padding: the masked body
+
+
+class _Blocks(NamedTuple):
+    """A call's blocking: block sizes, true lengths and the mask rule.
+    ``step`` and ``mask`` take Python ints (the plan) and program ids (the
+    kernels and their index maps) alike; a condition that the shapes
+    settle is left out, never traced."""
+
+    bq: int
+    bk: int
+    q_len: int
+    kv_len: int
+    causal: bool
+
+    @property
+    def nq(self) -> int:
+        return -(-self.q_len // self.bq)
+
+    @property
+    def nk(self) -> int:
+        return -(-self.kv_len // self.bk)
+
+    def step(self, iq, ik):
+        """``(live, full)`` of the step that pairs query block ``iq`` with
+        key block ``ik``: live where some row may attend some column of
+        it (causal is top-left, column <= row, which ``Sq != Sk`` callers
+        rely on), full where every row may attend every column and none
+        is padding. ``True`` where the shapes settle it."""
+        live = full = True
+        if self.causal:
+            live = ik * self.bk <= iq * self.bq + self.bq - 1
+            full = ik * self.bk + self.bk - 1 <= iq * self.bq
+        if self.kv_len % self.bk:
+            full = full & ((ik + 1) * self.bk <= self.kv_len)
+        if self.q_len % self.bq:
+            full = full & ((iq + 1) * self.bq <= self.q_len)
+        return live, full
+
+    def mask(self, iq, ik):
+        """The ``(bq, bk)`` mask of a masked step: one term for each
+        condition ``step`` holds."""
+        shape = (self.bq, self.bk)
+        rows = iq * self.bq + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        cols = ik * self.bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        terms = []
+        if self.causal:
+            terms.append(cols <= rows)
+        if self.kv_len % self.bk:
+            terms.append(cols < self.kv_len)
+        if self.q_len % self.bq:
+            terms.append(rows < self.q_len)
+        return functools.reduce(jnp.logical_and, terms)
+
+    def row_step(self, iq, ik):
+        """``(iq, ik)`` of the blocks that step ``(iq, ik)`` of the forward
+        and dq grids fetches: its own where live. Skip steps end a row,
+        and take the next row's first blocks, so the pipeline fetches them
+        under the row's last live step and nothing after; on the last row,
+        which no row follows, they keep the row's last live blocks."""
+        live, _ = self.step(iq, ik)
+        if live is True:
+            return iq, ik
+        last_row = iq == self.nq - 1
+        last_live = (iq * self.bq + self.bq - 1) // self.bk
+        return (jnp.where(live | last_row, iq, iq + 1),
+                jnp.where(live, ik, jnp.where(last_row, last_live, 0)))
+
+    def column_q(self, ik, iq):
+        """The query block that step ``(ik, iq)`` of the dkv kernel fetches
+        (its q, do, lse and delta): its own where live. Skip steps start a
+        key block's column, and take the column's first live block (the
+        last block, where no row attends the column): the pipeline fetches
+        it under the previous column's last step, and nothing after."""
+        live, _ = self.step(iq, ik)
+        if live is True:
+            return iq
+        first = jnp.minimum(ik * self.bk // self.bq, self.nq - 1)
+        return jnp.where(live, iq, first)
+
+    def plan(self) -> BlockPlan:
+        steps = [self.step(iq, ik)
+                 for iq in range(self.nq) for ik in range(self.nk)]
+        live = sum(live for live, _ in steps)
+        full = sum(full for _, full in steps)
+        return BlockPlan(len(steps) - live, full, live - full)
+
+
+def _blocks(Sq: int, Sk: int, block_q: int, block_k: int,
+            causal: bool) -> _Blocks:
+    """The blocking a call runs with: the requested blocks, clamped to the
+    sequence lengths rounded up to a sublane tile (8)."""
+    return _Blocks(min(block_q, _round_up(Sq, 8)),
+                   min(block_k, _round_up(Sk, 8)), Sq, Sk, causal)
+
+
+def flash_block_plan(Sq: int, Sk: int, block_q: int, block_k: int,
+                     causal: bool) -> BlockPlan:
+    """How many grid steps of one (row, head) of a flash call over ``Sq``
+    queries and ``Sk`` keys skip, run unmasked and run masked, at the
+    blocks the call is given. All three kernels follow it: a step has one
+    class whichever grid order runs it. At the cells' shapes (4,096, blocks
+    512 x 1024, causal) it is 12 skip, 12 full and 8 masked of 32."""
+    return _blocks(Sq, Sk, block_q, block_k, causal).plan()
+
+
+def _count_steps(kernel: str, plan: BlockPlan, rows_x_heads: int) -> None:
+    """Add a traced call's grid steps to the registry, by class."""
+    steps = get_registry().counter(
+        "dlrover_flash_grid_steps_total",
+        "Grid steps of the flash kernels traced in this process, by kernel "
+        "and by what the step does (skip, full, masked)",
+        labelnames=("kernel", "block"),
+    )
+    for block, n in plan._asdict().items():
+        steps.labels(kernel=kernel, block=block).inc(n * rows_x_heads)
+
+
+def _run_live(body, blocks: _Blocks, plan: BlockPlan, iq, ik) -> None:
+    """``body(mask)`` on the live steps: ``mask`` None on a full step, the
+    block's mask on a masked one, built there only; a skip step runs
+    nothing. A class the plan does not hold is not traced."""
+    live, full = blocks.step(iq, ik)
+    if full is True:  # not causal, nothing padded: every step is full
+        body(None)
+        return
+    if plan.full:
+        pl.when(full)(lambda: body(None))
+    if plan.masked:
+        pl.when(jnp.logical_and(live, jnp.logical_not(full)))(
+            lambda: body(blocks.mask(iq, ik)))
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, scale: float, causal: bool, block_q: int, block_k: int, kv_len: int,
+    *, scale: float, blocks: _Blocks, plan: BlockPlan,
 ):
     iq = pl.program_id(2)
     ik = pl.program_id(3)
@@ -108,31 +259,23 @@ def _fwd_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    rows = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    cols = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
-
-    def _attend():
+    def _attend(mask):
         q = q_ref[0, 0].astype(jnp.float32)  # (block_q, d)
         k = k_ref[0, 0].astype(jnp.float32)  # (block_k, d)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # (block_q, block_k)
-        mask = cols < kv_len
-        if causal:
-            mask = jnp.logical_and(mask, cols <= rows)
-        s = jnp.where(mask, s, NEG_INF)
+        if mask is not None:
+            s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scr[:]  # (block_q, LANES), lane-replicated
         l_prev = l_scr[:]
         m_cur = jnp.max(s, axis=-1, keepdims=True)  # (block_q, 1)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new[:, :1])
-        p = jnp.where(mask, p, 0.0)
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         m_scr[:] = m_new
@@ -142,11 +285,7 @@ def _fwd_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        # skip K blocks entirely in the future of this Q block
-        pl.when(ik * block_k <= iq * block_q + block_q - 1)(_attend)
-    else:
-        _attend()
+    _run_live(_attend, blocks, plan, iq, ik)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -163,27 +302,32 @@ def _fwd(
 ) -> Tuple[jax.Array, jax.Array]:
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    bq = min(block_q, _round_up(Sq, 8))
-    bk = min(block_k, _round_up(Sk, 8))
-    q_pad = _round_up(Sq, bq) - Sq
-    k_pad = _round_up(Sk, bk) - Sk
+    blocks = _blocks(Sq, Sk, block_q, block_k, causal)
+    bq, bk, nq, nk = blocks.bq, blocks.bk, blocks.nq, blocks.nk
+    q_pad = nq * bq - Sq
+    k_pad = nk * bk - Sk
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, q_pad), (0, 0))) if q_pad else q
     kp = jnp.pad(k, ((0, 0), (0, 0), (0, k_pad), (0, 0))) if k_pad else k
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, k_pad), (0, 0))) if k_pad else v
-    nq = qp.shape[2] // bq
-    nk = kp.shape[2] // bk
+    plan = blocks.plan()
+    _count_steps("flash_fwd", plan, B * H)
+
+    def q_map(b, h, i, j):
+        return (b, h, blocks.row_step(i, j)[0], 0)
+
+    def kv_map(b, h, i, j):
+        return (b, h, blocks.row_step(i, j)[1], 0)
 
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-        kv_len=Sk,
+        _fwd_kernel, scale=scale, blocks=blocks, plan=plan,
     )
     o, lse = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            _vmem_spec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            _vmem_spec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0)),
-            _vmem_spec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0)),
+            _vmem_spec((1, 1, bq, D), q_map),
+            _vmem_spec((1, 1, bk, D), kv_map),
+            _vmem_spec((1, 1, bk, D), kv_map),
         ],
         out_specs=[
             _vmem_spec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
@@ -211,7 +355,7 @@ def _fwd(
 
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
-    *, scale: float, causal: bool, block_q: int, block_k: int, kv_len: int,
+    *, scale: float, blocks: _Blocks, plan: BlockPlan,
 ):
     iq = pl.program_id(2)
     ik = pl.program_id(3)
@@ -221,14 +365,7 @@ def _bwd_dq_kernel(
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    rows = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    cols = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
-
-    def _accum():
+    def _accum(mask):
         q = q_ref[0, 0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
@@ -237,12 +374,11 @@ def _bwd_dq_kernel(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
-        mask = cols < kv_len
-        if causal:
-            mask = jnp.logical_and(mask, cols <= rows)
-        s = jnp.where(mask, s, NEG_INF)
+        if mask is not None:
+            s = jnp.where(mask, s, NEG_INF)
         p = jnp.exp(s - lse_ref[0, 0][:, :1])
-        p = jnp.where(mask, p, 0.0)
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -253,10 +389,7 @@ def _bwd_dq_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        pl.when(ik * block_k <= iq * block_q + block_q - 1)(_accum)
-    else:
-        _accum()
+    _run_live(_accum, blocks, plan, iq, ik)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -266,8 +399,7 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_scr, dv_scr,
-    *, scale: float, causal: bool, block_q: int, block_k: int,
-    kv_len: int, q_len: int,
+    *, scale: float, blocks: _Blocks, plan: BlockPlan,
 ):
     ik = pl.program_id(2)
     iq = pl.program_id(3)
@@ -278,14 +410,7 @@ def _bwd_dkv_kernel(
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    rows = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    cols = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
-
-    def _accum():
+    def _accum(mask):
         q = q_ref[0, 0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
@@ -294,12 +419,11 @@ def _bwd_dkv_kernel(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
-        mask = jnp.logical_and(cols < kv_len, rows < q_len)
-        if causal:
-            mask = jnp.logical_and(mask, cols <= rows)
-        s = jnp.where(mask, s, NEG_INF)
+        if mask is not None:
+            s = jnp.where(mask, s, NEG_INF)
         p = jnp.exp(s - lse_ref[0, 0][:, :1])
-        p = jnp.where(mask, p, 0.0)
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
         # dv += p^T @ do
         dv_scr[:] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
@@ -316,11 +440,7 @@ def _bwd_dkv_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        # skip Q blocks entirely before this K block (no row attends it)
-        pl.when(iq * block_q + block_q - 1 >= ik * block_k)(_accum)
-    else:
-        _accum()
+    _run_live(_accum, blocks, plan, iq, ik)
 
     @pl.when(iq == nq - 1)
     def _finish():
@@ -333,10 +453,10 @@ def _bwd(
 ):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    bq = min(block_q, _round_up(Sq, 8))
-    bk = min(block_k, _round_up(Sk, 8))
-    q_pad = _round_up(Sq, bq) - Sq
-    k_pad = _round_up(Sk, bk) - Sk
+    blocks = _blocks(Sq, Sk, block_q, block_k, causal)
+    bq, bk, nq, nk = blocks.bq, blocks.bk, blocks.nq, blocks.nk
+    q_pad = nq * bq - Sq
+    k_pad = nk * bk - Sk
 
     # delta_i = rowsum(do_i * o_i) - dlse_i  (f32, one fused
     # elementwise+reduce at the jnp level — not worth a kernel)
@@ -360,22 +480,28 @@ def _bwd(
     kp, vp = padk(k), padk(v)
     lsep = rows_to_lanes(lse, fill=NEG_INF)
     deltap = rows_to_lanes(delta)
-    nq = qp.shape[2] // bq
-    nk = kp.shape[2] // bk
+    plan = blocks.plan()
+    _count_steps("flash_bwd_dq", plan, B * H)
+    _count_steps("flash_bwd_dkv", plan, B * H)
+
+    def row_map(b, h, i, j):
+        return (b, h, blocks.row_step(i, j)[0], 0)
+
+    def kv_map(b, h, i, j):
+        return (b, h, blocks.row_step(i, j)[1], 0)
 
     dq = pl.pallas_call(
         functools.partial(
-            _bwd_dq_kernel, scale=scale, causal=causal,
-            block_q=bq, block_k=bk, kv_len=Sk,
+            _bwd_dq_kernel, scale=scale, blocks=blocks, plan=plan,
         ),
         grid=(B, H, nq, nk),
         in_specs=[
-            _vmem_spec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            _vmem_spec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0)),
-            _vmem_spec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0)),
-            _vmem_spec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            _vmem_spec((1, 1, bq, LANES), lambda b, h, i, j: (b, h, i, 0)),
-            _vmem_spec((1, 1, bq, LANES), lambda b, h, i, j: (b, h, i, 0)),
+            _vmem_spec((1, 1, bq, D), row_map),
+            _vmem_spec((1, 1, bk, D), kv_map),
+            _vmem_spec((1, 1, bk, D), kv_map),
+            _vmem_spec((1, 1, bq, D), row_map),
+            _vmem_spec((1, 1, bq, LANES), row_map),
+            _vmem_spec((1, 1, bq, LANES), row_map),
         ],
         out_specs=_vmem_spec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, nq * bq, D), q.dtype),
@@ -384,19 +510,21 @@ def _bwd(
         name="flash_bwd_dq",
     )(qp, kp, vp, dop, lsep, deltap)
 
+    def column_map(b, h, j, i):
+        return (b, h, blocks.column_q(j, i), 0)
+
     dk, dv = pl.pallas_call(
         functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal,
-            block_q=bq, block_k=bk, kv_len=Sk, q_len=Sq,
+            _bwd_dkv_kernel, scale=scale, blocks=blocks, plan=plan,
         ),
         grid=(B, H, nk, nq),
         in_specs=[
-            _vmem_spec((1, 1, bq, D), lambda b, h, j, i: (b, h, i, 0)),
+            _vmem_spec((1, 1, bq, D), column_map),
             _vmem_spec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),
             _vmem_spec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),
-            _vmem_spec((1, 1, bq, D), lambda b, h, j, i: (b, h, i, 0)),
-            _vmem_spec((1, 1, bq, LANES), lambda b, h, j, i: (b, h, i, 0)),
-            _vmem_spec((1, 1, bq, LANES), lambda b, h, j, i: (b, h, i, 0)),
+            _vmem_spec((1, 1, bq, D), column_map),
+            _vmem_spec((1, 1, bq, LANES), column_map),
+            _vmem_spec((1, 1, bq, LANES), column_map),
         ],
         out_specs=[
             _vmem_spec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),

@@ -93,6 +93,66 @@ def test_flash_attention_forward_backward(one_chip):
     assert text.count("tpu_custom_call") == 3
 
 
+def _source(text, name):
+    """The instruction that ``name`` copies, following copies (into and
+    out of the fast memory) and bitcasts back to their origin."""
+    import re
+
+    while True:
+        found = re.search(
+            rf"^\s*{re.escape(name)} = .*? (copy|copy-start|copy-done|"
+            rf"bitcast)\((?:\([^)]*\) )?(%[\w.-]+)", text, re.MULTILINE)
+        if not found:
+            return name
+        name = found.group(2)
+
+
+@pytest.mark.parametrize("heads", [16, 32])
+def test_flash_block_classes_lower_at_the_cells_shapes(one_chip, heads):
+    """The cells' attention (sequence 4,096, head dim 128, the default
+    blocks; 16 heads in the looped cell, 32 in the dense ones), forward and
+    backward: the branched bodies and the clamped index maps of all three
+    classes of step lower through Mosaic, and the program makes one call
+    of each kernel with the queries as its first operand, which is what
+    the benchmark's ``kernel_share`` reads."""
+    import re
+
+    from dlrover_tpu.observability.registry import get_registry
+    from dlrover_tpu.ops.flash_attention import BlockPlan, flash_block_plan
+
+    seq = 4096
+    plan = flash_block_plan(seq, seq, 512, 1024, True)
+    assert plan == BlockPlan(skip=12, full=12, masked=8)
+    steps = get_registry().counter(
+        "dlrover_flash_grid_steps_total", labelnames=("kernel", "block"))
+
+    def read():
+        return {(kernel, block): steps.labels(kernel=kernel,
+                                              block=block).value
+                for kernel in KERNEL_NAMES for block in BlockPlan._fields}
+
+    before = read()
+    qkv = [_shape((1, heads, seq, D), jnp.bfloat16, one_chip)] * 3
+    text = _compiled_text(
+        jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, interpret=False).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)),
+        *qkv)
+    after = read()
+    for (kernel, block), n in after.items():
+        assert n - before[(kernel, block)] == heads * getattr(plan, block)
+    (query,) = re.findall(
+        r'(%\S+) = \S+ parameter\(0\).*op_name="q"', text)
+    for kernel in KERNEL_NAMES:
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line
+                 and re.match(rf"\s*%\w*{kernel}_", line)]
+        assert len(calls) == 1, (kernel, calls)
+        first = calls[0].partition("custom-call(")[2].split(",")[0]
+        assert _source(text, first) == query, (kernel, first)
+
+
 @pytest.mark.parametrize("kv_heads,quantized", [
     pytest.param(32, False, id="bf16"),
     pytest.param(32, True, id="int8-MHA"),

@@ -327,3 +327,322 @@ class TestFlashDecode:
                 np.asarray(out), np.asarray(ref), atol=2e-5,
                 err_msg=f"pos={pos}",
             )
+
+
+# ---------------------------------------------------------------------------
+# the causal block plan: skip / full / masked grid steps
+# ---------------------------------------------------------------------------
+
+
+def _plan_by_mask(Sq, Sk, bq, bk, causal):
+    """The plan counted from the dense mask, block by block: a block no
+    pair of which may attend is skipped, one every pair of which may and
+    that holds no padding is full, any other is masked."""
+    nq, nk = -(-Sq // bq), -(-Sk // bk)
+    rows = np.arange(nq * bq)[:, None]
+    cols = np.arange(nk * bk)[None, :]
+    attend = (cols < Sk) & (rows < Sq)
+    if causal:
+        attend &= cols <= rows
+    skip = full = 0
+    for i in range(nq):
+        for j in range(nk):
+            block = attend[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk]
+            causal_part = (cols <= rows)[i * bq:(i + 1) * bq,
+                                         j * bk:(j + 1) * bk]
+            skip += bool(causal and not causal_part.any())
+            full += bool(block.all())
+    return skip, full, nq * nk - skip - full
+
+
+@pytest.mark.parametrize("shape,expected", [
+    pytest.param((4096, 4096, 512, 1024, True), (12, 12, 8), id="cells"),
+    pytest.param((4096, 4096, 512, 1024, False), (0, 32, 0),
+                 id="non-causal"),
+    pytest.param((56, 56, 32, 32, True), (1, 0, 3), id="padded"),
+    pytest.param((56, 56, 32, 32, False), (0, 1, 3),
+                 id="padded-non-causal"),
+    pytest.param((32, 64, 16, 16, True), (5, 1, 2), id="sq-lt-sk"),
+    pytest.param((64, 32, 16, 16, True), (1, 5, 2), id="sq-gt-sk"),
+    pytest.param((128, 128, 64, 32, True), (2, 2, 4), id="bq-gt-bk"),
+    pytest.param((128, 128, 32, 64, True), (2, 2, 4), id="bq-lt-bk"),
+])
+def test_flash_block_plan(shape, expected):
+    from dlrover_tpu.ops.flash_attention import BlockPlan, flash_block_plan
+
+    plan = flash_block_plan(*shape)
+    assert plan == BlockPlan(*expected)
+    Sq, Sk, block_q, block_k, causal = shape
+    bq = min(block_q, -(-Sq // 8) * 8)
+    bk = min(block_k, -(-Sk // 8) * 8)
+    assert tuple(plan) == _plan_by_mask(Sq, Sk, bq, bk, causal)
+
+
+def _dense_attention(q, k, v, causal):
+    """Output and log-sum-exp by straight-line math; causal is top-left
+    (key <= query position), as the kernels have it for Sq != Sk."""
+    scale = q.shape[-1] ** -0.5
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    if causal:
+        Sq, Sk = q.shape[2], k.shape[2]
+        mask = jnp.arange(Sk)[None, :] <= jnp.arange(Sq)[:, None]
+        s = jnp.where(mask, s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    o = jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse[..., None]), v)
+    return o, lse
+
+
+_CLASS_SHAPES = [
+    # Sq, Sk, block_q, block_k, causal
+    pytest.param(128, 128, 32, 64, True, id="32x64-causal"),
+    pytest.param(128, 128, 64, 32, True, id="64x32-causal"),
+    pytest.param(128, 128, 32, 64, False, id="32x64-full"),
+    pytest.param(128, 128, 64, 32, False, id="64x32-full"),
+    pytest.param(88, 88, 32, 64, True, id="32x64-padded-causal"),
+    pytest.param(88, 88, 64, 32, True, id="64x32-padded-causal"),
+    pytest.param(88, 88, 32, 64, False, id="32x64-padded-full"),
+    pytest.param(48, 96, 32, 64, True, id="sq-lt-sk-causal"),
+    pytest.param(96, 48, 64, 32, True, id="sq-gt-sk-causal"),
+    pytest.param(48, 96, 32, 64, False, id="sq-lt-sk-full"),
+]
+
+
+def _rand_qk_v(Sq, Sk, B=1, H=2, D=16, seed=0):
+    key = jax.random.PRNGKey(seed)
+    q = jax.random.normal(key, (B, H, Sq, D))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (B, H, Sk, D))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (B, H, Sk, D))
+    return q, k, v
+
+
+@pytest.mark.parametrize("Sq,Sk,block_q,block_k,causal", _CLASS_SHAPES)
+def test_block_classes_match_dense(Sq, Sk, block_q, block_k, causal):
+    """Forward, log-sum-exp and all three gradients against the dense
+    oracle, at unequal blocks both ways, padded, and Sq != Sk: every
+    class of step (skip, full, masked) takes part somewhere."""
+    q, k, v = _rand_qk_v(Sq, Sk)
+    key = jax.random.PRNGKey(7)
+    w_o = jax.random.normal(key, q.shape)
+    w_l = jax.random.normal(jax.random.fold_in(key, 1), q.shape[:3])
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=block_q,
+                               block_k=block_k, return_lse=True)
+
+    def loss(fn):
+        def f(q, k, v):
+            o, lse = fn(q, k, v)
+            return (o * w_o).sum() + (lse * w_l).sum()
+        return f
+
+    o, lse = flash(q, k, v)
+    o_ref, lse_ref = _dense_attention(q, k, v, causal)
+    np.testing.assert_allclose(o, o_ref, atol=2e-5)
+    np.testing.assert_allclose(lse, lse_ref, atol=2e-5)
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(lambda q, k, v: _dense_attention(q, k, v, causal)),
+                  argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", gf, gr):
+        np.testing.assert_allclose(a, b, atol=1e-4, err_msg=f"d{name}")
+
+
+def test_grid_step_counter_after_one_traced_call():
+    """Tracing one call adds its grid, rows x heads x the plan, to the
+    registry's count of steps by kernel and class: the forward's when the
+    forward is traced, all three kernels' when its gradient is."""
+    from dlrover_tpu.observability.registry import get_registry
+    from dlrover_tpu.ops.flash_attention import BlockPlan, flash_block_plan
+
+    kernels = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+    def read():
+        steps = get_registry().counter(
+            "dlrover_flash_grid_steps_total", labelnames=("kernel", "block"))
+        return {(kernel, block): steps.labels(kernel=kernel,
+                                              block=block).value
+                for kernel in kernels for block in BlockPlan._fields}
+
+    B, H, S = 2, 3, 128
+    q, k, v = _rand_qkv(B=B, H=H, S=S, D=16)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, block_q=32, block_k=64)
+
+    plan = flash_block_plan(S, S, 32, 64, True)
+    assert plan == BlockPlan(skip=2, full=2, masked=4)
+    before = read()
+    jax.jit(attend).lower(q, k, v)
+    after = read()
+    for (kernel, block), n in after.items():
+        want = B * H * getattr(plan, block) if kernel == "flash_fwd" else 0
+        assert n - before[(kernel, block)] == want, (kernel, block)
+    jax.jit(jax.grad(lambda q, k, v: attend(q, k, v).sum(),
+                     argnums=(0, 1, 2))).lower(q, k, v)
+    again = read()
+    for key, n in again.items():
+        assert n - after[key] == B * H * getattr(plan, key[1]), key
+
+
+def _masked_everywhere_fwd(q, k, v, *, causal, block_q, block_k):
+    """The forward as it was before the block classes, kept as the
+    reference: every computed step builds the mask from two iotas and
+    applies it with two ``where``s, and skipped steps still fetch their
+    K/V blocks (index maps unclamped)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    neg_inf, lanes = -1e30, 128
+    B, H, Sq, D = q.shape
+    Sk = k.shape[2]
+    bq = min(block_q, -(-Sq // 8) * 8)
+    bk = min(block_k, -(-Sk // 8) * 8)
+    nq, nk = -(-Sq // bq), -(-Sk // bk)
+    scale = D ** -0.5
+
+    def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr):
+        iq, ik = pl.program_id(2), pl.program_id(3)
+
+        @pl.when(ik == 0)
+        def _init():
+            m_scr[:] = jnp.full_like(m_scr, neg_inf)
+            l_scr[:] = jnp.zeros_like(l_scr)
+            acc_scr[:] = jnp.zeros_like(acc_scr)
+
+        rows = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        cols = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+
+        def _attend():
+            s = jax.lax.dot_general(
+                q_ref[0, 0].astype(jnp.float32),
+                k_ref[0, 0].astype(jnp.float32),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
+            mask = cols < Sk
+            if causal:
+                mask = jnp.logical_and(mask, cols <= rows)
+            s = jnp.where(mask, s, neg_inf)
+            m_prev = m_scr[:]
+            l_prev = l_scr[:]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new[:, :1]), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            m_scr[:] = m_new
+            acc_scr[:] = acc_scr[:] * alpha[:, :1] + jax.lax.dot_general(
+                p, v_ref[0, 0].astype(jnp.float32),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
+        if causal:
+            pl.when(ik * bk <= iq * bq + bq - 1)(_attend)
+        else:
+            _attend()
+
+        @pl.when(ik == nk - 1)
+        def _finish():
+            l = l_scr[:]
+            safe_l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, 0] = (acc_scr[:] / safe_l[:, :1]).astype(o_ref.dtype)
+            lse_ref[0, 0] = jnp.where(
+                l == 0.0, neg_inf, m_scr[:] + jnp.log(safe_l))
+
+    def pad(x, n):
+        return jnp.pad(x, ((0, 0), (0, 0), (0, n - x.shape[2]), (0, 0)))
+
+    def spec(rows, width, index_map):
+        return pl.BlockSpec((1, 1, rows, width), index_map,
+                            memory_space=pltpu.VMEM)
+
+    by_q = lambda b, h, i, j: (b, h, i, 0)  # noqa: E731
+    by_k = lambda b, h, i, j: (b, h, j, 0)  # noqa: E731
+    o, lse = pl.pallas_call(
+        kernel,
+        grid=(B, H, nq, nk),
+        in_specs=[spec(bq, D, by_q), spec(bk, D, by_k), spec(bk, D, by_k)],
+        out_specs=[spec(bq, D, by_q), spec(bq, lanes, by_q)],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, nq * bq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, nq * bq, lanes), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bq, lanes), jnp.float32),
+            pltpu.VMEM((bq, lanes), jnp.float32),
+            pltpu.VMEM((bq, D), jnp.float32),
+        ],
+        interpret=True,
+    )(pad(q, nq * bq), pad(k, nk * bk), pad(v, nk * bk))
+    return o[:, :, :Sq], lse[:, :, :Sq, 0]
+
+
+@pytest.mark.parametrize("Sq,Sk,block_q,block_k,causal,dtype", [
+    pytest.param(256, 256, 32, 64, True, jnp.bfloat16, id="causal-bf16"),
+    pytest.param(88, 88, 64, 32, True, jnp.float32, id="padded-causal"),
+    pytest.param(48, 96, 32, 64, False, jnp.float32, id="sq-lt-sk-full"),
+])
+def test_block_classes_are_bit_exact(Sq, Sk, block_q, block_k, causal,
+                                     dtype):
+    """Dropping an all-true mask and clamping the skip steps' index maps
+    change what is fetched and built, not what is computed: ``o`` and
+    ``lse`` equal the masked-everywhere forward's bit for bit."""
+    q, k, v = (x.astype(dtype) for x in _rand_qk_v(Sq, Sk, B=2, H=2))
+    o, lse = flash_attention(q, k, v, causal=causal, block_q=block_q,
+                             block_k=block_k, return_lse=True,
+                             interpret=True)
+    o_ref, lse_ref = _masked_everywhere_fwd(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k)
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(o_ref))
+    np.testing.assert_array_equal(np.asarray(lse), np.asarray(lse_ref))
+
+
+@pytest.mark.parametrize("Sq,Sk,block_q,block_k,causal", [
+    pytest.param(4096, 4096, 512, 1024, True, id="cells"),
+    *_CLASS_SHAPES,
+    pytest.param(32, 64, 16, 16, True, id="sq-lt-sk-whole-columns"),
+])
+def test_skip_steps_fetch_nothing(Sq, Sk, block_q, block_k, causal):
+    """Walk each grid in its order over two (row, head) sweeps and count
+    the steps at which an input's block index changes, i.e. the DMAs the
+    pipeline issues: with the skip steps in, exactly as many as the live
+    steps alone ask for, for every input of all three kernels. And each
+    such DMA inside a sweep is issued under a live step (the pipeline
+    starts step t's copies at the start of step t - 1), never under a
+    skip step with no body to hide it."""
+    import importlib
+
+    # the package's ``flash_attention`` is the function; the module by name
+    fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
+    blocks = fa._blocks(Sq, Sk, block_q, block_k, causal)
+    nq, nk = blocks.nq, blocks.nk
+
+    def fetches(steps, index):
+        seen = [(sweep, int(index(iq, ik))) for sweep, iq, ik in steps]
+        return sum(a != b for a, b in zip(seen, seen[1:])) + 1
+
+    def live(steps):
+        return [s for s in steps if blocks.step(s[1], s[2])[0]]
+
+    def exposed(steps, index):
+        seen = [(sweep, int(index(iq, ik))) for sweep, iq, ik in steps]
+        return [steps[t] for t in range(1, len(steps))
+                if seen[t] != seen[t - 1] and seen[t][0] == seen[t - 1][0]
+                and not blocks.step(*steps[t - 1][1:])[0]]
+
+    rows = [(s, iq, ik) for s in range(2) for iq in range(nq)
+            for ik in range(nk)]
+    columns = [(s, iq, ik) for s in range(2) for ik in range(nk)
+               for iq in range(nq)]
+    for side in (0, 1):  # the queries' blocks, the keys' blocks
+        index = lambda iq, ik: blocks.row_step(iq, ik)[side]  # noqa: E731
+        assert fetches(rows, index) \
+            == fetches(live(rows), lambda iq, ik: (iq, ik)[side]), side
+        assert not exposed(rows, index), side
+    index = lambda iq, ik: blocks.column_q(ik, iq)  # noqa: E731
+    assert fetches(columns, index) \
+        == fetches(live(columns), lambda iq, ik: iq)
+    assert not exposed(columns, index)
+    if blocks.plan().skip:
+        # an unclamped map, as the kernels had, fetches on skip steps
+        assert fetches(rows, lambda iq, ik: ik) \
+            > fetches(live(rows), lambda iq, ik: ik)
