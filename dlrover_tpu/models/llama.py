@@ -135,12 +135,13 @@ def dense_init(key, shape, fan_in, dtype):
             * (fan_in ** -0.5)).astype(dtype)
 
 
-def init_attention_params(config, key) -> Dict:
+def init_attention_params(config, key, n_layers=None) -> Dict:
     """Stacked (L, …) attention-block params for any config exposing
-    n_layers/dim/n_heads/n_kv_heads/head_dim/dtype."""
+    n_layers/dim/n_heads/n_kv_heads/head_dim/dtype; ``n_layers`` of them
+    where given (a stack of one kind of layer in a model of two)."""
     c = config
     keys = jax.random.split(key, 4)
-    L = c.n_layers
+    L = n_layers or c.n_layers
     q_dim = c.n_heads * c.head_dim
     kv_dim = c.n_kv_heads * c.head_dim
     return {
@@ -214,6 +215,20 @@ def _attention(x, layer, config: LlamaConfig, positions, mesh):
     q = _rope(q, positions, c.rope_theta)
     k = _rope(k, positions, c.rope_theta)
     q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # (B,H,S,D)
+    out = attend(q, k, v, c, mesh)
+    out = out.transpose(0, 2, 1, 3).reshape(B, S, c.n_heads * c.head_dim)
+    return jnp.einsum("bsh,hd->bsd", out, layer["wo"])
+
+
+def attend(q, k, v, config, mesh):
+    """Causal attention of queries (B, H, S, D) over keys (B, KV, S, D)
+    and values (B, KV, S, Dv) → (B, H, S, Dv), by the path the config and
+    the mesh choose: ring or Ulysses over ``sp``, the flash kernel (in a
+    ``shard_map`` under a mesh, heads where :func:`head_split` puts
+    them), or dense XLA math. Every attention block of ``models/`` calls
+    it (this file's and models/mla.py's)."""
+    c = config
+    B = q.shape[0]
     strategy = c.sp_strategy
     if strategy not in (None, "ring", "ulysses"):
         raise ValueError(
@@ -261,12 +276,10 @@ def _attention(x, layer, config: LlamaConfig, positions, mesh):
                 "batch=%s — dense XLA path", str(dict(mesh.shape)), B,
             )
         out = full_causal_attention(q, k, v)
-    out = out.transpose(0, 2, 1, 3).reshape(B, S, c.n_heads * c.head_dim)
-    return jnp.einsum("bsh,hd->bsd", out, layer["wo"])
+    return out
 
 
-# public names for model families composing these blocks (models/moe.py)
-attention_block = _attention
+# a public name for model families composing these blocks (models/moe.py)
 rms_norm = _rms_norm
 
 
@@ -291,46 +304,61 @@ def _mlp(x, layer):
     return jnp.einsum("bsf,fd->bsd", gate * up, layer["w2"])
 
 
-def decoder_layer(h, layer, config, positions, mesh):
-    """One decoder layer on ``h`` (B, S, D). ``positions`` None takes them
-    from ``h``'s own shape (inside a pipeline stage that is the local
-    shard). A layer tree that holds ``attn_post_norm`` / ``ffn_post_norm``
-    gets each branch normed again before it joins the residual (sandwich
-    norm, models/looped.py)."""
+def dense_ffn(x, layer, config, mesh):
+    """The SwiGLU of ``layer``'s w1, w3, w2, which reports nothing."""
+    return _mlp(x, layer), None
+
+
+def decoder_layer(h, layer, config, positions, mesh, attention=_attention,
+                  ffn=dense_ffn):
+    """One decoder layer on ``h`` (B, S, D) → (h, what ``ffn`` reports
+    beside its output). ``attention(x, layer, config, positions, mesh)``
+    and ``ffn(x, layer, config, mesh) -> (y, report)`` are the layer's two
+    branches: this file's GQA block and SwiGLU by default; models/moe.py
+    passes its expert FFN and, for latent attention, models/mla.py's
+    block. ``positions`` None takes them from ``h``'s own shape (inside a
+    pipeline stage that is the local shard). A layer tree that holds
+    ``attn_post_norm`` / ``ffn_post_norm`` gets each branch normed again
+    before it joins the residual (sandwich norm, models/looped.py)."""
     c = config
     if positions is None:
         positions = jnp.broadcast_to(
             jnp.arange(h.shape[1])[None, :], h.shape[:2]
         )
-    branch = _attention(
+    branch = attention(
         _rms_norm(h, layer["attn_norm"], c.norm_eps),
         layer, c, positions, mesh,
     )
     if "attn_post_norm" in layer:
         branch = _rms_norm(branch, layer["attn_post_norm"], c.norm_eps)
     h = h + branch
-    branch = _mlp(_rms_norm(h, layer["ffn_norm"], c.norm_eps), layer)
+    branch, report = ffn(
+        _rms_norm(h, layer["ffn_norm"], c.norm_eps), layer, c, mesh)
     if "ffn_post_norm" in layer:
         branch = _rms_norm(branch, layer["ffn_post_norm"], c.norm_eps)
-    return h + branch
+    return h + branch, report
 
 
-def decoder_stack(x, layers, config, positions, mesh):
-    """The stacked layers as one ``lax.scan``, each layer under
-    ``jax.checkpoint`` where the config asks for remat. A function of its
-    own so that a caller may run the same weights more than once
-    (models/looped.py) or stage by stage (``forward_pp``)."""
+def decoder_stack(x, layers, config, positions, mesh, layer=decoder_layer,
+                  policy=None):
+    """The stacked layers as one ``lax.scan`` → (x, the layers' reports
+    stacked on a leading layer axis), each layer under ``jax.checkpoint``
+    with ``policy`` (default :func:`_remat_policy`) where the config asks
+    for remat. ``layer`` has :func:`decoder_layer`'s signature. A function
+    of its own so that a caller may run the same weights more than once
+    (models/looped.py), stage by stage (``forward_pp``) or a stack of
+    another kind of layer after this one (models/moe.py)."""
 
-    def layer_fn(h, layer):
-        return decoder_layer(h, layer, config, positions, mesh), None
+    def layer_fn(h, params):
+        return layer(h, params, config, positions, mesh)
 
     scan_fn = layer_fn
     if config.remat:
         scan_fn = jax.checkpoint(
-            layer_fn, prevent_cse=False, policy=_remat_policy(config),
+            layer_fn, prevent_cse=False,
+            policy=policy or _remat_policy(config),
         )
-    x, _ = jax.lax.scan(scan_fn, x, layers)
-    return x
+    return jax.lax.scan(scan_fn, x, layers)
 
 
 def lm_head(x, weight):
@@ -346,7 +374,7 @@ def hidden_states(params: Dict, tokens, config: LlamaConfig, mesh=None):
     B, S = tokens.shape
     x = params["tok_embed"][tokens]
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-    x = decoder_stack(x, params["layers"], c, positions, mesh)
+    x, _ = decoder_stack(x, params["layers"], c, positions, mesh)
     return _rms_norm(x, params["final_norm"], c.norm_eps)
 
 
@@ -472,7 +500,7 @@ def hidden_states_pp(
     def stage_fn(layer_group, h):
         # positions from the *local* activation shape: inside the pipeline
         # body the batch dim is the per-(dp,fsdp)-rank shard, not B/M
-        return decoder_stack(h, layer_group, c, None, None)
+        return decoder_stack(h, layer_group, c, None, None)[0]
 
     stages = stack_stages(params["layers"], S_pp)
     ym = pipeline_apply(

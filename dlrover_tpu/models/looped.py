@@ -135,7 +135,7 @@ def loss_and_stats(params, tokens, config: LoopedConfig,
         h, log_stay, loss, plogp = carry
         w = _as_stored(shared32, shared)
         with jax.named_scope("loop_stack"):
-            h = _llama.decoder_stack(h, w["layers"], c, positions, mesh)
+            h, _ = _llama.decoder_stack(h, w["layers"], c, positions, mesh)
             h = _llama.rms_norm(h, w["final_norm"], c.norm_eps)
         nll, logit = head(h, w["lm_head"], w["exit_gate"], targets)
         # p(t) = lambda_t * prod_{j<t}(1 - lambda_j), in logs; the last

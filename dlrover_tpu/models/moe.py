@@ -28,10 +28,28 @@ Design for the MXU:
   down projections joins them. The work a chip does is the same
   whatever the routing: with whole experts on chips the group waits for
   the chip whose experts drew the most pairs (PERF.md §6, PR 33);
-- **top-k routing with renormalized gates** (Mixtral) + Switch-style
-  load-balancing auxiliary loss per routing group, both in f32;
+- **a chip's share of many experts**: the router scores
+  ``router_experts`` experts (its published width) and the chip holds
+  ``n_experts`` of them, from ``first_expert`` on. Only pairs routed to
+  a held expert are sorted into groups and computed; what the others
+  add lies on other chips of the expert-parallel group and is left out
+  here (one chip runs no exchange). ``router_experts`` None holds them
+  all;
+- **two routers**, both in f32: ``"softmax"``, Mixtral's top-k of the
+  softmax with renormalized gates and a Switch-style load-balancing loss
+  per routing group; ``"sigmoid"``, DeepSeek-V3's (arXiv:2412.19437
+  §2.1.2): sigmoid scores, the top k of scores plus a selection bias
+  ``router_bias`` that chooses and never gates, gates renormalized over
+  the k and times ``routed_scaling``, the sequence-wise balance loss per
+  routing group (one sequence by default), and the bias moved against
+  each expert's load once a step (:func:`_ffn`);
+- **a shared SwiGLU expert** (``shared_ffn_dim``) every token meets,
+  added once beside the routed experts; **leading dense layers**
+  (``n_dense_layers`` of SwiGLU ``dense_ffn_dim``) before the expert
+  layers, a stack of their own through the same ``llama.decoder_stack``;
 - attention/norms/RoPE are the Llama blocks (models/llama.py) unchanged —
-  ring/Ulysses long-context paths compose with MoE layers;
+  ring/Ulysses long-context paths compose with MoE layers — or, where
+  ``mla`` is set, latent attention (models/mla.py);
 - scanned layers, bf16 params, remat: same compile-time story as llama.
 
 Checkpoint shards fall out of the ``NamedSharding`` on each leaf — the
@@ -49,6 +67,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.models import llama as _llama
+from dlrover_tpu.models import mla as _mla
 from dlrover_tpu.parallel.sharding import DEFAULT_RULES, valid_spec_for
 
 
@@ -60,14 +79,27 @@ class MoEConfig(_llama.AttentionConfigMixin):
     n_heads: int = 32
     n_kv_heads: int = 8
     ffn_dim: int = 14336          # per-expert FFN width (Mixtral 8x7B)
-    n_experts: int = 8
+    n_experts: int = 8            # the experts this chip holds
     top_k: int = 2
+    # the router's width, where it scores more experts than are held
+    # here (None: n_experts), and the first held expert's index
+    router_experts: Optional[int] = None
+    first_expert: int = 0
+    router_score: str = "softmax"  # or "sigmoid" (module docstring)
+    routed_scaling: float = 1.0    # the sigmoid router's gate factor
+    shared_ffn_dim: int = 0        # the shared expert's width (0: none)
+    n_dense_layers: int = 0        # leading dense layers of n_layers
+    dense_ffn_dim: int = 0
+    mla: Optional[_mla.MLAShape] = None  # latent attention's widths
     capacity_factor: float = 1.25  # expert slots = g/E · top_k · this
     # routing group size (GShard num_groups dual): capacity and the
     # auxiliary loss are reckoned within fixed-size groups of tokens, so
     # what a token may be dropped for does not depend on the rest of the
     # batch. None = one sequence per group (g = S), the standard choice.
     route_group_size: Optional[int] = None
+    # the balance loss's weight: the softmax router's Switch-style term
+    # averaged over the layers, or the sigmoid router's sequence-wise
+    # term summed over them (as DeepSeek-V3 adds each layer's)
     router_aux_weight: float = 0.01
     max_seq_len: int = 4096
     rope_theta: float = 10000.0
@@ -80,6 +112,14 @@ class MoEConfig(_llama.AttentionConfigMixin):
     sp_attention: Optional[str] = None
     use_ring_attention: bool = False  # legacy alias for sp_attention="ring"
     use_flash_attention: Optional[bool] = None
+
+    @property
+    def router_width(self) -> int:
+        return self.router_experts or self.n_experts
+
+    @property
+    def n_expert_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers
 
     @staticmethod
     def mixtral8x7b() -> "MoEConfig":
@@ -96,21 +136,60 @@ class MoEConfig(_llama.AttentionConfigMixin):
         )
 
 
+def _attention_axes(config: MoEConfig) -> Dict:
+    if config.mla is None:
+        return _llama.attention_param_axes()
+    return _mla.param_axes()
+
+
 def param_logical_axes(config: MoEConfig) -> Dict:
     """Logical sharding axes per param (parallel/sharding.py rules;
     ``expert_mlp`` → the ep and tp mesh axes together)."""
-    return {
+    c = config
+    layers = {
+        **_attention_axes(c),
+        "ffn_norm": ("layers", "norm"),
+        "router": ("layers", "embed", None),
+        "w1": ("layers", None, "embed", "expert_mlp"),
+        "w3": ("layers", None, "embed", "expert_mlp"),
+        "w2": ("layers", None, "expert_mlp", "embed"),
+    }
+    if c.router_score == "sigmoid":
+        layers["router_bias"] = ("layers", None)
+    if c.shared_ffn_dim:
+        layers.update(shared_w1=("layers", "embed", "mlp"),
+                      shared_w3=("layers", "embed", "mlp"),
+                      shared_w2=("layers", "mlp", "embed"))
+    axes = {
         "tok_embed": ("vocab", "embed"),
-        "layers": {
-            **_llama.attention_param_axes(),
-            "ffn_norm": ("layers", "norm"),
-            "router": ("layers", "embed", None),
-            "w1": ("layers", None, "embed", "expert_mlp"),
-            "w3": ("layers", None, "embed", "expert_mlp"),
-            "w2": ("layers", None, "expert_mlp", "embed"),
-        },
+        "layers": layers,
         "final_norm": ("norm",),
         "lm_head": ("embed", "vocab"),
+    }
+    if c.n_dense_layers:
+        axes["dense_layers"] = {
+            **_attention_axes(c),
+            "ffn_norm": ("layers", "norm"),
+            "w1": ("layers", "embed", "mlp"),
+            "w3": ("layers", "embed", "mlp"),
+            "w2": ("layers", "mlp", "embed"),
+        }
+    return axes
+
+
+def _init_attention(config: MoEConfig, key, n_layers: int) -> Dict:
+    if config.mla is None:
+        return _llama.init_attention_params(config, key, n_layers)
+    return _mla.init_params(config, key, n_layers)
+
+
+def _swiglu_leaves(key, n_layers, dim, width, dtype, prefix=""):
+    keys = jax.random.split(key, 3)
+    dense = _llama.dense_init
+    return {
+        prefix + "w1": dense(keys[0], (n_layers, dim, width), dim, dtype),
+        prefix + "w3": dense(keys[1], (n_layers, dim, width), dim, dtype),
+        prefix + "w2": dense(keys[2], (n_layers, width, dim), width, dtype),
     }
 
 
@@ -119,23 +198,41 @@ def init_params(config: MoEConfig, key) -> Dict:
     keys = jax.random.split(key, 7)
     dt = c.dtype
     dense = _llama.dense_init
-    L, E = c.n_layers, c.n_experts
-    return {
+    L, E = c.n_expert_layers, c.n_experts
+    layers = {
+        **_init_attention(c, keys[1], L),
+        "ffn_norm": jnp.ones((L, c.dim), dtype=dt),
+        # router stays f32: tiny, and routing decisions are precision-
+        # sensitive (standard MoE practice)
+        "router": jax.random.normal(
+            keys[2], (L, c.dim, c.router_width),
+            dtype=jnp.float32) * (c.dim ** -0.5),
+        "w1": dense(keys[3], (L, E, c.dim, c.ffn_dim), c.dim, dt),
+        "w3": dense(keys[4], (L, E, c.dim, c.ffn_dim), c.dim, dt),
+        "w2": dense(keys[5], (L, E, c.ffn_dim, c.dim), c.ffn_dim, dt),
+    }
+    # the leaves only other kinds of model have draw from keys folded
+    # out of ``key``, so that a Mixtral's draw is what it always was
+    if c.router_score == "sigmoid":
+        layers["router_bias"] = jnp.zeros((L, c.router_width), jnp.float32)
+    if c.shared_ffn_dim:
+        layers.update(_swiglu_leaves(jax.random.fold_in(key, 7), L, c.dim,
+                                     c.shared_ffn_dim, dt, "shared_"))
+    params = {
         "tok_embed": dense(keys[0], (c.vocab_size, c.dim), c.dim, dt),
-        "layers": {
-            **_llama.init_attention_params(c, keys[1]),
-            "ffn_norm": jnp.ones((L, c.dim), dtype=dt),
-            # router stays f32: tiny, and routing decisions are precision-
-            # sensitive (standard MoE practice)
-            "router": jax.random.normal(
-                keys[2], (L, c.dim, E), dtype=jnp.float32) * (c.dim ** -0.5),
-            "w1": dense(keys[3], (L, E, c.dim, c.ffn_dim), c.dim, dt),
-            "w3": dense(keys[4], (L, E, c.dim, c.ffn_dim), c.dim, dt),
-            "w2": dense(keys[5], (L, E, c.ffn_dim, c.dim), c.ffn_dim, dt),
-        },
+        "layers": layers,
         "final_norm": jnp.ones((c.dim,), dtype=dt),
         "lm_head": dense(keys[6], (c.dim, c.vocab_size), c.dim, dt),
     }
+    if c.n_dense_layers:
+        n = c.n_dense_layers
+        params["dense_layers"] = {
+            **_init_attention(c, jax.random.fold_in(key, 8), n),
+            "ffn_norm": jnp.ones((n, c.dim), dtype=dt),
+            **_swiglu_leaves(jax.random.fold_in(key, 9), n, c.dim,
+                             c.dense_ffn_dim, dt),
+        }
+    return params
 
 
 def _group_size(config: MoEConfig, batch: int, seq: int) -> int:
@@ -152,29 +249,42 @@ def expert_capacity(config: MoEConfig, batch: int, seq: int) -> int:
     """Static per-expert token slots *per routing group*."""
     c = config
     g = _group_size(c, batch, seq)
-    cap = int(g * c.top_k * c.capacity_factor / c.n_experts)
+    cap = int(g * c.top_k * c.capacity_factor / c.router_width)
     return max(c.top_k, cap)
 
 
-def _route(x_grouped, router, config: MoEConfig, capacity: int):
+def _route(x_grouped, router, config: MoEConfig, capacity: int,
+           bias=None):
     """Top-k routing with capacity → the pairs' experts, gates and mask.
 
     x_grouped: (G, g, D) — G routing groups of g tokens; capacity is
     per-expert *per group*. Returns, each (G, g, k): the expert of every
-    (token, choice) pair, its renormalized f32 gate, and ``keep``, false
-    where the pair's expert was full in its group; and the aux scalar.
-    Choice-major priority within a group: every token's first choice
-    claims capacity before any token's second choice (GShard order).
+    (token, choice) pair (of the router's whole width), its f32 gate, and
+    ``keep``, false where the pair's expert was full in its group; and
+    the balance term. Choice-major priority within a group: every
+    token's first choice claims capacity before any token's second
+    choice (GShard order). ``bias`` (E,) is the sigmoid router's
+    selection bias.
     """
     c = config
     G, g = x_grouped.shape[0], x_grouped.shape[1]
-    E, k = c.n_experts, c.top_k
+    E, k = c.router_width, c.top_k
     logits = jnp.einsum(
         "gtd,de->gte", x_grouped.astype(jnp.float32), router
     )
-    probs = jax.nn.softmax(logits, axis=-1)               # (G, g, E) f32
-    topv, topi = jax.lax.top_k(probs, k)                  # (G, g, k)
-    gates = topv / jnp.clip(topv.sum(-1, keepdims=True), 1e-9)  # renorm
+    if c.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)                   # (G, g, E) f32
+        # the bias takes part in choosing, not in the gates
+        _, topi = jax.lax.top_k(scores + bias, k)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
+        gates = c.routed_scaling * topv / (
+            topv.sum(-1, keepdims=True) + 1e-20)
+        # the sequence-wise term's affinities: scores over their sum
+        probs = scores / scores.sum(-1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)           # (G, g, E) f32
+        topv, topi = jax.lax.top_k(probs, k)              # (G, g, k)
+        gates = topv / jnp.clip(topv.sum(-1, keepdims=True), 1e-9)
 
     masks = jax.nn.one_hot(topi, E, dtype=jnp.float32)    # (G, g, k, E)
     cm = masks.transpose(0, 2, 1, 3)                      # (G, k, g, E)
@@ -186,7 +296,10 @@ def _route(x_grouped, router, config: MoEConfig, capacity: int):
 
     # load-balancing loss over ALL k choices (ST-MoE/Mixtral style): a
     # router dumping second choices on one expert is penalized too.
-    # E · Σ_e (choice fraction · mean router prob), averaged over groups
+    # E · Σ_e (choice fraction · mean router prob), averaged over groups.
+    # Under the sigmoid router this is DeepSeek-V3's sequence-wise term
+    # where a group is a sequence: f_e = E / (k·T) · picks, P_e the mean
+    # normalized score
     frac = masks.mean(axis=(1, 2))                        # (G, E)
     aux = E * jnp.mean(jnp.sum(frac * probs.mean(axis=1), axis=-1))
     return topi, gates, keep, aux
@@ -344,14 +457,42 @@ def _routed_rows(x, expert, keep, gates, w1, w3, w2):
 
 
 def _moe_ffn(x, layer, config: MoEConfig, mesh=None):
-    """Sparse expert FFN. x: (B, S, D) → (B, S, D), aux scalar."""
+    """Sparse expert FFN. x: (B, S, D) → (B, S, D), balance term."""
+    out, report = _ffn(x, layer, config, mesh)
+    return out, report["aux"]
+
+
+def _ffn(x, layer, config: MoEConfig, mesh=None):
+    """The expert layer's FFN branch (``llama.decoder_layer``'s ``ffn``):
+    x (B, S, D) → (out, report). ``report["aux"]`` is the router's
+    balance term; under the sigmoid router also ``load`` (E,), the share
+    of the microbatch's pairs that chose each of the router's experts,
+    and ``pull``, a term of value zero whose gradient with respect to
+    the selection bias is ``load - 1 / E``: the optimizer moves the bias
+    against each expert's load once a step, outside the gradient of the
+    loss (DeepSeek-V3's auxiliary-loss-free balancing, by the optimizer's
+    step rather than a fixed one)."""
     c = config
     B, S, D = x.shape
     capacity = expert_capacity(c, B, S)
     g = _group_size(c, B, S)
     pairs = (B, S, c.top_k)
-    expert, gates, keep, aux = _route(
-        x.reshape(B * S // g, g, D), layer["router"], c, capacity)
+    bias = layer.get("router_bias")
+    with jax.named_scope("moe_router"):
+        expert, gates, keep, aux = _route(
+            x.reshape(B * S // g, g, D), layer["router"], c, capacity, bias)
+    report = {"aux": aux}
+    if bias is not None:
+        load = jax.nn.one_hot(expert, c.router_width).mean(axis=(0, 1, 2))
+        err = jax.lax.stop_gradient(load - 1.0 / c.router_width)
+        report.update(load=load, pull=jnp.sum(
+            (bias - jax.lax.stop_gradient(bias)) * err))
+    if c.router_width != c.n_experts:
+        # this chip's share: the pairs of other chips' experts are kept
+        # by none of its groups
+        held = expert - c.first_expert
+        keep = keep & (held >= 0) & (held < c.n_experts)
+        expert = held
     experts, terms = _routed_rows, 1
     if mesh is not None:
         # a Pallas call has no partitioning rule, so under a mesh the
@@ -385,40 +526,45 @@ def _moe_ffn(x, layer, config: MoEConfig, mesh=None):
             *(a.reshape(pairs) for a in (expert, keep, gates)),
             layer["w1"], layer["w3"], layer["w2"],
         ).sum(0)
-    return out, aux
+    if c.shared_ffn_dim:
+        with jax.named_scope("moe_shared"):
+            out = out + _llama._mlp(x, {
+                "w1": layer["shared_w1"], "w3": layer["shared_w3"],
+                "w2": layer["shared_w2"]})
+    return out, report
 
 
 def _hidden_states(params: Dict, tokens, config: MoEConfig, mesh=None):
     """tokens (B, S) int32 → (the normed last hidden states (B, S, D),
-    aux loss scalar)."""
+    the expert layers' reports stacked on a leading layer axis)."""
     c = config
     B, S = tokens.shape
     x = params["tok_embed"][tokens]
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-
-    def layer_fn(carry, layer):
-        h, aux_sum = carry
-        h = h + _llama.attention_block(
-            _llama.rms_norm(h, layer["attn_norm"], c.norm_eps),
-            layer, c, positions, mesh,
-        )
-        ffn_out, aux = _moe_ffn(
-            _llama.rms_norm(h, layer["ffn_norm"], c.norm_eps), layer, c,
-            mesh,
-        )
-        return (h + ffn_out, aux_sum + aux), None
-
-    scan_fn = layer_fn
-    if c.remat:
-        scan_fn = jax.checkpoint(
-            layer_fn, prevent_cse=False,
-            policy=_remat_policy(c),
-        )
-    (x, aux_sum), _ = jax.lax.scan(
-        scan_fn, (x, jnp.zeros((), jnp.float32)), params["layers"]
-    )
+    attention = _llama._attention if c.mla is None else _mla.attention
+    policy = _remat_policy(c)
+    if c.n_dense_layers:
+        x, _ = _llama.decoder_stack(
+            x, params["dense_layers"], c, positions, mesh,
+            layer=functools.partial(
+                _llama.decoder_layer, attention=attention),
+            policy=policy)
+    x, reports = _llama.decoder_stack(
+        x, params["layers"], c, positions, mesh,
+        layer=functools.partial(
+            _llama.decoder_layer, attention=attention, ffn=_ffn),
+        policy=policy)
     x = _llama.rms_norm(x, params["final_norm"], c.norm_eps)
-    return x, aux_sum / c.n_layers
+    return x, reports
+
+
+def _balance(reports, config: MoEConfig):
+    """The balance terms the loss adds: the softmax router's mean over
+    the layers, the sigmoid router's sum, and the bias's pull."""
+    if config.router_score != "sigmoid":
+        return config.router_aux_weight * reports["aux"].mean()
+    return (config.router_aux_weight * reports["aux"].sum()
+            + reports["pull"].sum())
 
 
 def forward(
@@ -427,20 +573,82 @@ def forward(
     config: MoEConfig,
     mesh=None,
 ) -> Tuple[Any, Any]:
-    """tokens (B, S) int32 → (logits (B, S, vocab) f32, aux loss scalar)."""
-    x, aux = _hidden_states(params, tokens, config, mesh)
-    return _llama.lm_head(x, params["lm_head"]), aux
+    """tokens (B, S) int32 → (logits (B, S, vocab) f32, the router's
+    balance term averaged over the expert layers)."""
+    x, reports = _hidden_states(params, tokens, config, mesh)
+    return _llama.lm_head(x, params["lm_head"]), reports["aux"].mean()
 
 
 @functools.partial(jax.jit, static_argnames=("config", "mesh"))
-def next_token_loss(params, tokens, config: MoEConfig, mesh=None):
-    """Causal LM loss + router load-balancing aux term. Jitted so that a
-    process traces and differentiates the model once, however many
-    programs hold the loss (a check of the gradient, then the train
-    step: a second or two of set-up with the experts' kernels)."""
-    x, aux = _hidden_states(params, tokens[:, :-1], config, mesh)
+def loss_and_stats(params, tokens, config: MoEConfig, mesh=None):
+    """(loss, stats) of ``tokens`` (B, S + 1): the causal LM loss plus
+    the router's balance terms; ``stats["expert_load"]`` (expert layers,
+    router width), each expert's share of the pairs, under the sigmoid
+    router (else no stats). Jitted so that a process traces and
+    differentiates the model once, however many programs hold the loss
+    (a check of the gradient, then the train step: a second or two of
+    set-up with the experts' kernels)."""
+    x, reports = _hidden_states(params, tokens[:, :-1], config, mesh)
     nll = _llama.head_nll(x, params["lm_head"], tokens[:, 1:], mesh)
-    return nll.mean() + config.router_aux_weight * aux
+    stats = {"expert_load": reports["load"]} if "load" in reports else {}
+    return nll.mean() + _balance(reports, config), stats
+
+
+def next_token_loss(params, tokens, config: MoEConfig, mesh=None):
+    """The scalar loss of :func:`loss_and_stats`."""
+    return loss_and_stats(params, tokens, config, mesh)[0]
+
+
+def make_loss_fn(config: MoEConfig, mesh=None):
+    """``loss_fn(params, microbatch)`` → the scalar loss, for
+    ``ElasticTrainer`` and for a check of the gradient alike. Under the
+    sigmoid router it carries ``with_stats``, the ``(loss, stats)`` form
+    the trainer's step takes instead, and ``stats_gauges``, which the
+    trainer calls once with a function that reads the last step's stats
+    back (:func:`stats_gauges`)."""
+
+    def loss_fn(params, microbatch):
+        return next_token_loss(params, microbatch, config, mesh)
+
+    if config.router_score == "sigmoid":
+        loss_fn.with_stats = lambda params, microbatch: loss_and_stats(
+            params, microbatch, config, mesh)
+        loss_fn.stats_gauges = functools.partial(stats_gauges, config=config)
+    return loss_fn
+
+
+def stats_gauges(read, config: MoEConfig, registry=None) -> None:
+    """Registry gauges computed when the registry is read, from ``read()``,
+    the last step's stats on the host (None before a step: NaN), over the
+    expert layers: ``dlrover_moe_held_pair_share``, the share of the
+    routed pairs that chose an expert this chip holds (held / router
+    width where the routing is even), and ``dlrover_moe_held_load_ratio``,
+    the hottest held expert's pairs over the mean expert's; each layer's
+    averaged over the layers. Nothing on the step path reads them."""
+    import numpy as np
+
+    from dlrover_tpu.observability.registry import get_registry
+
+    reg = registry or get_registry()
+    first, n = config.first_expert, config.n_experts
+
+    def over_layers(of):
+        def value():
+            stats = read()
+            if stats is None:
+                return float("nan")
+            load = np.asarray(stats["expert_load"], np.float64)   # (L, E)
+            return float(of(load[:, first:first + n], load).mean())
+        return value
+
+    reg.gauge("dlrover_moe_held_pair_share",
+              "Share of the routed pairs whose expert this chip holds, "
+              "mean over the expert layers, at the last step").set_function(
+        over_layers(lambda held, load: held.sum(-1)))
+    reg.gauge("dlrover_moe_held_load_ratio",
+              "The hottest held expert's pairs over the mean expert's, "
+              "mean over the expert layers, at the last step").set_function(
+        over_layers(lambda held, load: held.max(-1) / load.mean(-1)))
 
 
 def num_params(config: MoEConfig) -> Tuple[int, int]:

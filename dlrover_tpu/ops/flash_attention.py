@@ -301,7 +301,7 @@ def _fwd(
     q, k, v, *, scale, causal, block_q, block_k, interpret,
 ) -> Tuple[jax.Array, jax.Array]:
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     blocks = _blocks(Sq, Sk, block_q, block_k, causal)
     bq, bk, nq, nk = blocks.bq, blocks.bk, blocks.nq, blocks.nk
     q_pad = nq * bq - Sq
@@ -327,20 +327,20 @@ def _fwd(
         in_specs=[
             _vmem_spec((1, 1, bq, D), q_map),
             _vmem_spec((1, 1, bk, D), kv_map),
-            _vmem_spec((1, 1, bk, D), kv_map),
+            _vmem_spec((1, 1, bk, Dv), kv_map),
         ],
         out_specs=[
-            _vmem_spec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            _vmem_spec((1, 1, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
             _vmem_spec((1, 1, bq, LANES), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, nq * bq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, nq * bq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, nq * bq, LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
@@ -452,7 +452,7 @@ def _bwd(
     q, k, v, o, lse, do, dlse, *, scale, causal, block_q, block_k, interpret,
 ):
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     blocks = _blocks(Sq, Sk, block_q, block_k, causal)
     bq, bk, nq, nk = blocks.bq, blocks.bk, blocks.nq, blocks.nk
     q_pad = nq * bq - Sq
@@ -498,8 +498,8 @@ def _bwd(
         in_specs=[
             _vmem_spec((1, 1, bq, D), row_map),
             _vmem_spec((1, 1, bk, D), kv_map),
-            _vmem_spec((1, 1, bk, D), kv_map),
-            _vmem_spec((1, 1, bq, D), row_map),
+            _vmem_spec((1, 1, bk, Dv), kv_map),
+            _vmem_spec((1, 1, bq, Dv), row_map),
             _vmem_spec((1, 1, bq, LANES), row_map),
             _vmem_spec((1, 1, bq, LANES), row_map),
         ],
@@ -521,22 +521,22 @@ def _bwd(
         in_specs=[
             _vmem_spec((1, 1, bq, D), column_map),
             _vmem_spec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),
-            _vmem_spec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),
-            _vmem_spec((1, 1, bq, D), column_map),
+            _vmem_spec((1, 1, bk, Dv), lambda b, h, j, i: (b, h, j, 0)),
+            _vmem_spec((1, 1, bq, Dv), column_map),
             _vmem_spec((1, 1, bq, LANES), column_map),
             _vmem_spec((1, 1, bq, LANES), column_map),
         ],
         out_specs=[
             _vmem_spec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),
-            _vmem_spec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),
+            _vmem_spec((1, 1, bk, Dv), lambda b, h, j, i: (b, h, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, nk * bk, D), k.dtype),
-            jax.ShapeDtypeStruct((B, H, nk * bk, D), v.dtype),
+            jax.ShapeDtypeStruct((B, H, nk * bk, Dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((bk, Dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
@@ -598,8 +598,12 @@ def flash_attention(
     return_lse: bool = False,
     interpret: Optional[bool] = None,
 ):
-    """Fused blockwise attention. q/k/v: (B, H, S, D); GQA callers repeat
-    KV heads first (XLA fuses the broadcast into the block loads).
+    """Fused blockwise attention. q/k: (B, H, S, D), v: (B, H, S, Dv);
+    GQA callers repeat KV heads first (XLA fuses the broadcast into the
+    block loads). The value width may differ from the query/key width
+    (latent attention: 192-wide queries and keys over 128-wide values,
+    models/mla.py); the scale defaults to ``D ** -0.5`` and the output
+    and the value gradient follow ``Dv``.
 
     Default blocks are empirically tuned on v5e (fwd+bwd at B4 H16 S2048
     D128: 512×1024 is 3.3× the fused-dense XLA path and within 10% of the
@@ -607,7 +611,7 @@ def flash_attention(
     Blocks are clamped to the sequence length, so short-S callers are
     unaffected.
 
-    Returns ``o`` (B, H, Sq, D), plus the per-row logsumexp (B, H, Sq) f32
+    Returns ``o`` (B, H, Sq, Dv), plus the per-row logsumexp (B, H, Sq) f32
     when ``return_lse`` — the handle ring attention uses to merge partials.
     """
     if scale is None:

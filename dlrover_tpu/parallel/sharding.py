@@ -49,6 +49,9 @@ DEFAULT_RULES: Dict[str, Optional[object]] = {
     "layers": "pp",
     "norm": None,
     "head_dim": None,
+    # latent attention's compressed key/value width (models/mla.py):
+    # every chip holds it whole, as it holds the norms
+    "latent": None,
 }
 
 

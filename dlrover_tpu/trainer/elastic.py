@@ -82,15 +82,25 @@ class ElasticTrainer:
         self,
         # loss_fn(params, microbatch) -> scalar, or (scalar, stats dict);
         # a dict ``span_attrs`` on it (models/looped.py: ``passes``) rides
-        # on every train.step span
+        # on every train.step span. A scalar loss_fn may carry
+        # ``with_stats``, its (scalar, stats) form, which the step takes
+        # instead, and ``stats_gauges(read)``, called once here with a
+        # function that reads the last step's stats back, for gauges
+        # computed when the registry is read (models/moe.py
+        # make_loss_fn): nothing on the step path reads them
         loss_fn: Callable,
         optimizer,          # optax GradientTransformation
         global_batch_size: int,
         micro_batch_per_replica: int,
         mesh_manager: Optional[ElasticMeshManager] = None,
     ):
-        self._loss_fn = loss_fn
+        self._loss_fn = getattr(loss_fn, "with_stats", loss_fn)
         self._span_attrs = dict(getattr(loss_fn, "span_attrs", {}))
+        gauges = getattr(loss_fn, "stats_gauges", None)
+        self._keep_stats = gauges is not None
+        self._last_stats = None
+        if self._keep_stats:
+            gauges(self._last_stats_on_host)
         self._optimizer = optimizer
         self.global_batch_size = global_batch_size
         self.micro_batch_per_replica = micro_batch_per_replica
@@ -203,6 +213,12 @@ class ElasticTrainer:
 
         return jax.jit(step_fn, donate_argnums=(0,))
 
+    def _last_stats_on_host(self):
+        """The last step's stats read back, None before the first step."""
+        if self._last_stats is None:
+            return None
+        return jax.device_get(self._last_stats)
+
     def _register_state(self, state) -> None:
         """Claim the training state in the device-memory ledger: params,
         optimizer state, and the f32 grad accumulator the scan carries
@@ -246,6 +262,8 @@ class ElasticTrainer:
             # the counter is read only to fill the span's attribute
             requests = watcher.compile_requests() if traced else 0
             out = self._train_step(state, batch)
+            if self._keep_stats:
+                self._last_stats = out[1].stats
             if traced:
                 compiles = watcher.compile_requests() - requests
                 if compiles:  # what the backend was asked, cached or not

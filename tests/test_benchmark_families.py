@@ -20,6 +20,7 @@ CONFIGS = [c["name"] for c in BENCH["configs"]]
 SEQ = 4096
 DENSE_SCORE = 68_736_253_952   # one causal score-sized matmul, 32 heads
 LOOP_SCORE = 34_368_126_976    # 16 heads of 128 at 4,096
+MLA_SCORE = 268_500_992        # 16 heads at 4,096, a width of one
 
 
 def fields_of(name):
@@ -59,6 +60,18 @@ LITERALS = [
     ("ouro-2.6b", "train_flops_per_token", 13_891_977_216),
     ("ouro-2.6b", "flash_attention_flops",
      (4 * 8 * 2 * LOOP_SCORE, 4 * 8 * 5 * LOOP_SCORE)),
+    # one chip's share: 8 of 64 experts, an eighth of the vocabulary; the
+    # kernels at 192-wide queries and keys over 128-wide values, forward
+    # 192 + 128, backward 3 x 192 + 2 x 128, training 3 x (192 + 128)
+    ("moonlight-16b-a3b", "param_count", 668_890_432),
+    # 313,327,616 matmul parameters a token meets: attention 6 x
+    # 13,762,560, the dense SwiGLU 69,206,016, shared experts 5 x
+    # 17,301,504, the router 5 x 131,072, held experts 5 x 0.75 x
+    # 8,650,752, the head 41,943,040
+    ("moonlight-16b-a3b", "train_flops_per_token",
+     6 * 313_327_616 + 6 * 3 * 320 * 16 * 4097),
+    ("moonlight-16b-a3b", "flash_attention_flops",
+     (6 * 320 * MLA_SCORE, 6 * 832 * MLA_SCORE)),
 ]
 
 

@@ -153,6 +153,38 @@ def test_flash_block_classes_lower_at_the_cells_shapes(one_chip, heads):
         assert _source(text, first) == query, (kernel, first)
 
 
+def test_flash_kernels_lower_with_values_of_another_width(one_chip):
+    """Latent attention's kernels (``benchmarks/configs/
+    moonlight-16b-a3b``: 16 heads, 8,192 positions, queries and keys 192
+    wide over values 128 wide, the default blocks), forward and
+    backward: Mosaic takes the unequal widths, one call of each kernel,
+    the output and the value gradient 128 wide, and the forward's first
+    operand is the 192-wide queries that ``kernel_share`` reads."""
+    seq, heads = 8192, 16
+    qk = _shape((1, heads, seq, 192), jnp.bfloat16, one_chip)
+    v = _shape((1, heads, seq, 128), jnp.bfloat16, one_chip)
+    text = _compiled_text(
+        jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, interpret=False).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)),
+        qk, qk, v)
+    import re
+
+    calls = {kernel: [line for line in text.splitlines()
+                      if 'custom_call_target="tpu_custom_call"' in line
+                      and re.match(rf"\s*%\w*{kernel}_", line)]
+             for kernel in KERNEL_NAMES}
+    assert [len(c) for c in calls.values()] == [1, 1, 1], calls
+    (forward,) = calls["flash_fwd"]
+    assert forward.split()[2].startswith(f"(bf16[1,{heads},{seq},128]")
+    # the first four-dimensional array after the call's opening, as
+    # ``named_kernels.query_shape`` reads a profile's line
+    operands = forward.partition("custom-call(")[2]
+    first = re.search(r"\b[a-z]+\d+\[\d+,\d+,\d+,\d+\]", operands)
+    assert first.group(0) == f"bf16[1,{heads},{seq},192]"
+
+
 @pytest.mark.parametrize("kv_heads,quantized", [
     pytest.param(32, False, id="bf16"),
     pytest.param(32, True, id="int8-MHA"),

@@ -646,3 +646,83 @@ def test_skip_steps_fetch_nothing(Sq, Sk, block_q, block_k, causal):
         # an unclamped map, as the kernels had, fetches on skip steps
         assert fetches(rows, lambda iq, ik: ik) \
             > fetches(live(rows), lambda iq, ik: ik)
+
+
+# -- a value width of its own (latent attention: 192-wide queries and keys
+# over 128-wide values, scaled down here to 48 over 32) ----------------------
+
+_VALUE_WIDTH_SHAPES = [
+    # Sq, Sk, block_q, block_k, causal
+    pytest.param(128, 128, 32, 64, True, id="32x64-causal"),
+    pytest.param(128, 128, 64, 32, False, id="64x32-full"),
+    pytest.param(88, 88, 32, 64, True, id="32x64-padded-causal"),
+    pytest.param(48, 96, 32, 64, True, id="sq-lt-sk-causal"),
+]
+
+
+@pytest.mark.parametrize("Sq,Sk,block_q,block_k,causal", _VALUE_WIDTH_SHAPES)
+def test_values_of_another_width_match_dense(Sq, Sk, block_q, block_k,
+                                              causal):
+    """Values narrower than queries and keys (3 : 2, as 192 : 128):
+    output, log-sum-exp and all three gradients against the dense oracle,
+    at the default scale of the query/key width; the output and the value
+    gradient are as wide as the values."""
+    key = jax.random.PRNGKey(11)
+    q = jax.random.normal(key, (1, 2, Sq, 48))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (1, 2, Sk, 48))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (1, 2, Sk, 32))
+    w_o = jax.random.normal(jax.random.fold_in(key, 3), (1, 2, Sq, 32))
+    w_l = jax.random.normal(jax.random.fold_in(key, 4), (1, 2, Sq))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=block_q,
+                               block_k=block_k, return_lse=True)
+
+    def loss(fn):
+        def f(q, k, v):
+            o, lse = fn(q, k, v)
+            return (o * w_o).sum() + (lse * w_l).sum()
+        return f
+
+    o, lse = flash(q, k, v)
+    o_ref, lse_ref = _dense_attention(q, k, v, causal)
+    assert o.shape == (1, 2, Sq, 32)
+    np.testing.assert_allclose(o, o_ref, atol=2e-5)
+    np.testing.assert_allclose(lse, lse_ref, atol=2e-5)
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(lambda q, k, v: _dense_attention(q, k, v, causal)),
+                  argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", gf, gr):
+        assert a.shape == {"q": q, "k": k, "v": v}[name].shape
+        np.testing.assert_allclose(a, b, atol=1e-4, err_msg=f"d{name}")
+
+
+# sha256 of the jaxpr (kernels' bodies, grids, block and scratch shapes,
+# index maps; no source location) of the kernels' forward and backward at
+# the cells' shapes, as they stood before the value width was made a
+# width of its own. Where the widths are equal the kernels must be those,
+# call for call: a deliberate change of the kernels changes these too
+_EQUAL_WIDTH_JAXPR = {
+    32: "7ea7de6785db7196e76b24888f59be011ed557f519235d494eff2a04c0a52319",
+    16: "181edd2b3ba5b07f17ad8bcf16afdc1517aaa1861bae745e4cb2c870ef22c3a6",
+}
+
+
+@pytest.mark.parametrize("heads", sorted(_EQUAL_WIDTH_JAXPR))
+def test_equal_widths_trace_the_kernels_as_before(heads):
+    """At one width for q, k and v (the dense and looped cells' attention:
+    [1, heads, 4096, 128], the default blocks) the forward and backward
+    kernels trace to the very jaxpr they traced to before they took a
+    value width of their own."""
+    import hashlib
+
+    shape = jax.ShapeDtypeStruct((1, heads, 4096, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, interpret=False).astype(
+            jnp.float32).sum()
+
+    text = str(jax.make_jaxpr(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)))(shape, shape, shape))
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        _EQUAL_WIDTH_JAXPR[heads])
