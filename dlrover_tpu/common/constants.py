@@ -621,6 +621,11 @@ class MetricLabel:
     RESTORE_PATH_STAGED = "staged"
     RESTORE_PATH_DIRECT = "direct"
     RESTORE_PATHS = (RESTORE_PATH_STAGED, RESTORE_PATH_DIRECT)
+    # how the experts' rows move into the sorted buffer and out of it
+    # (models/moe.py dlrover_moe_permute_calls_total): the live rows
+    # alone, or every pair's row
+    PERMUTE_PATH_LIVE = "live"
+    PERMUTE_PATH_WHOLE = "whole"
     # checkpoint-commit triggers (ckpt/ckpt_saver.py → ckpt_committed
     # journal events): the cadence save, a membership-change/SIGTERM
     # breakpoint save, and the brain's predicted-failure pre-emptive save

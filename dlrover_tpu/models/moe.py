@@ -8,7 +8,9 @@ Design for the MXU:
   (token, choice) pairs of a microbatch are sorted by expert, their rows
   gathered into one buffer of static size, and each projection is one
   grouped matmul over it whose ``group_sizes`` say which rows belong to
-  which expert. Rows past the last kept pair cost no MXU time. (Until
+  which expert. Rows past the last kept pair cost no MXU time, and where
+  the chip holds a share of the router's experts only the kept pairs'
+  rows are moved into the buffer and back into their tokens. (Until
   PR 30 routing was two dense einsums against a (tokens, experts,
   capacity) one-hot, which computes every slot an expert might fill:
   four times the useful rows where no token may be dropped);
@@ -66,8 +68,10 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from dlrover_tpu.common.constants import MetricLabel
 from dlrover_tpu.models import llama as _llama
 from dlrover_tpu.models import mla as _mla
+from dlrover_tpu.observability.registry import get_registry
 from dlrover_tpu.parallel.sharding import DEFAULT_RULES, valid_spec_for
 
 
@@ -400,11 +404,16 @@ def _expert_ffn(rows, group_sizes, w1, w3, w2):
     gradient."""
     live = (jnp.arange(rows.shape[0]) < group_sizes.sum())[:, None]
     rows = jnp.where(live, rows, 0)
+    return jnp.where(live, _grouped_ffn(rows, group_sizes, w1, w3, w2), 0)
+
+
+def _grouped_ffn(rows, group_sizes, w1, w3, w2):
+    """SwiGLU's three grouped matmuls over the sorted buffer, whose dead
+    rows the caller zeroes going in and coming out."""
     gate = checkpoint_name(_grouped_matmul(rows, w1, group_sizes), _SAVED[0])
     up = checkpoint_name(_grouped_matmul(rows, w3, group_sizes), _SAVED[1])
-    down = checkpoint_name(
+    return checkpoint_name(
         _grouped_matmul(jax.nn.silu(gate) * up, w2, group_sizes), _SAVED[2])
-    return jnp.where(live, down, 0)
 
 
 @jax.custom_vjp
@@ -425,6 +434,122 @@ def _permute_bwd(inverse, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
+# the live rows of the sorted buffer move this many at a time (at most):
+# one tile of a 1-D int32 array on the chip, and a width that no layer
+# reads as its own (latent attention's 512 would put these loops' ops in
+# ``mla.latent_ms``)
+_CHUNK = 1024
+
+
+def _over_live(n, rows: int, body, init):
+    """``body(start, chunk, carry)`` over the chunks of a ``rows``-row
+    buffer that hold its first ``n`` rows: a loop of ``ceil(n / chunk)``
+    trips, so the rows past ``n`` cost none. The chunk divides ``rows``,
+    so that no chunk's slice is moved back inside the buffer."""
+    chunk = math.gcd(rows, _CHUNK)
+    trips = (n + chunk - 1) // chunk
+    return jax.lax.fori_loop(
+        0, trips, lambda c, carry: body(c * chunk, chunk, carry), init)
+
+
+def _live(n, start, chunk):
+    return (start + jnp.arange(chunk)) < n
+
+
+def _gather_live(table, order, n):
+    """The sorted buffer (M, D), M = len(order): row i < n is
+    ``table[order[i] mod T]``, every other row zero. table (T, D)."""
+    T, D = table.shape
+    M = order.shape[0]
+
+    def body(start, chunk, buf):
+        o = jax.lax.dynamic_slice(order, (start,), (chunk,))
+        rows = jnp.where(_live(n, start, chunk)[:, None], table[o % T], 0)
+        return jax.lax.dynamic_update_slice(buf, rows, (start, 0))
+
+    return _over_live(n, M, body, jnp.zeros((M, D), table.dtype))
+
+
+def _add_live(rows, order, n, T: int, scale=None):
+    """The token rows (T, D) in f32: row t the sum of the live rows
+    ``rows[i]``, i < n, whose pair ``order[i]`` is of token t (times
+    ``scale[order[i]]`` where given). What a dead row holds reaches
+    nothing."""
+    M, D = rows.shape
+
+    def body(start, chunk, y):
+        o = jax.lax.dynamic_slice(order, (start,), (chunk,))
+        r = jax.lax.dynamic_slice(rows, (start, 0), (chunk, D)).astype(
+            jnp.float32)
+        if scale is not None:
+            r = scale[o][:, None] * r
+        return y.at[o % T].add(
+            jnp.where(_live(n, start, chunk)[:, None], r, 0))
+
+    return _over_live(n, M, body, jnp.zeros((T, D), jnp.float32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _into_rows(x, order, n, T: int):
+    """The sorted buffer from the token rows x (T, D), moving the live
+    rows alone: row i < n is ``x[order[i] mod T]`` (pairs choice-major,
+    ``j·T + t``), the rest zero. Its gradient adds the live rows back
+    into their tokens."""
+    return _gather_live(x, order, n)
+
+
+def _into_rows_fwd(x, order, n, T):
+    return _gather_live(x, order, n), (order, n)
+
+
+def _into_rows_bwd(T, residuals, g):
+    order, n = residuals
+    return _add_live(g, order, n, T).astype(g.dtype), None, None
+
+
+_into_rows.defvjp(_into_rows_fwd, _into_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _out_of_rows(out, order, gates, n, T: int):
+    """What the live rows of the expert buffer out (M, D) add to their T
+    tokens, in f32: ``y[order[i] mod T] += gates[order[i]] · out[i]`` for
+    i < n, gates (M,) f32 in pair order. Its gradients are the same moves
+    the other way, over the live rows again: the buffer's, a gather of
+    the tokens' rows times the gates; the gates', each live row's dot
+    with its token's row, zero for a dead pair."""
+    return _add_live(out, order, n, T, gates)
+
+
+def _out_of_rows_fwd(out, order, gates, n, T):
+    return _add_live(out, order, n, T, gates), (out, order, gates, n)
+
+
+def _out_of_rows_bwd(T, residuals, g):
+    out, order, gates, n = residuals
+    M, D = out.shape
+
+    def body(start, chunk, carry):
+        d_out, d_gates = carry
+        o = jax.lax.dynamic_slice(order, (start,), (chunk,))
+        live = _live(n, start, chunk)
+        g_rows = g[o % T]
+        rows = jax.lax.dynamic_slice(out, (start, 0), (chunk, D))
+        d_rows = jnp.where(live[:, None], gates[o][:, None] * g_rows, 0)
+        dots = (rows.astype(jnp.float32) * g_rows).sum(-1)
+        return (jax.lax.dynamic_update_slice(
+                    d_out, d_rows.astype(out.dtype), (start, 0)),
+                d_gates.at[jnp.where(live, o, M)].set(
+                    dots, mode="drop", unique_indices=True))
+
+    d_out, d_gates = _over_live(n, M, body, (
+        jnp.zeros_like(out), jnp.zeros_like(gates)))
+    return d_out, None, d_gates, None
+
+
+_out_of_rows.defvjp(_out_of_rows_fwd, _out_of_rows_bwd)
+
+
 def _sort_by_expert(expert, keep, n_experts: int):
     """Order of the pairs by expert, the dropped ones last; and how many
     each expert keeps."""
@@ -435,12 +560,20 @@ def _sort_by_expert(expert, keep, n_experts: int):
     return order, group_sizes
 
 
-def _routed_rows(x, expert, keep, gates, w1, w3, w2):
+def _routed_rows(x, expert, keep, gates, w1, w3, w2, live_only=False):
     """What the experts add to every token, over the columns of
     ``ffn_dim`` that w1, w3, w2 hold: all of them, or under a mesh this
     chip's. x (1, B, S, D), this chip's copy of its tokens; expert, keep,
     gates (B, S, k). Returns the shape of x: this chip's term of the sum
-    over the chips that slice the columns."""
+    over the chips that slice the columns.
+
+    ``live_only`` moves only the live rows into the sorted buffer and out
+    of it, straight from and into the token rows (:func:`_into_rows`,
+    :func:`_out_of_rows`): for a chip that holds a share of the router's
+    experts, whose groups keep a small part of the ``T·k`` pairs. Without
+    it every pair's row is gathered in and out, which costs the same
+    where every pair is kept and the loop over live chunks would add its
+    trips."""
     k, D = expert.shape[-1], x.shape[-1]
     # pairs choice-major, pair j·T + t: a choice's rows are one block, so
     # no array is laid out (T, k, D) with k among the tiled dimensions
@@ -448,12 +581,40 @@ def _routed_rows(x, expert, keep, gates, w1, w3, w2):
     order, group_sizes = _sort_by_expert(
         by_choice(expert).reshape(-1), by_choice(keep).reshape(-1),
         w1.shape[0])
-    inverse = jnp.argsort(order)
-    rows = _permute(jnp.tile(x.reshape(-1, D), (k, 1)), order, inverse)
+    _count_permute(live_only)
+    if live_only:
+        xf, n = x.reshape(-1, D), group_sizes.sum()
+        with jax.named_scope("moe_permute"):
+            rows = _into_rows(xf, order, n, xf.shape[0])
+        out = _grouped_ffn(rows, group_sizes, w1, w3, w2)
+        with jax.named_scope("moe_permute"):
+            y = _out_of_rows(out, order, by_choice(gates).reshape(-1), n,
+                             xf.shape[0])
+        return y.astype(x.dtype).reshape(x.shape)
+    with jax.named_scope("moe_permute"):
+        inverse = jnp.argsort(order)
+        rows = _permute(jnp.tile(x.reshape(-1, D), (k, 1)), order, inverse)
     out = _expert_ffn(rows, group_sizes, w1, w3, w2)
-    out = _permute(out, inverse, order).reshape(k, -1, D)
-    y = (out.astype(jnp.float32) * by_choice(gates)[..., None]).sum(0)
+    with jax.named_scope("moe_permute"):
+        out = _permute(out, inverse, order).reshape(k, -1, D)
+        y = (out.astype(jnp.float32) * by_choice(gates)[..., None]).sum(0)
     return y.astype(x.dtype).reshape(x.shape)
+
+
+def _count_permute(live_only: bool) -> None:
+    """Count a traced call of the permutation by its path in the
+    registry."""
+    calls = get_registry().counter(
+        "dlrover_moe_permute_calls_total",
+        "Traced calls of the experts' permutation into the sorted buffer "
+        "and out of it, by path: live (the live rows alone, a chip that "
+        "holds a share of the router's experts) or whole (every pair's "
+        "row)",
+        labelnames=("path",),
+    )
+    path = (MetricLabel.PERMUTE_PATH_LIVE if live_only
+            else MetricLabel.PERMUTE_PATH_WHOLE)
+    calls.labels(path=path).inc()  # noqa: DLR013 — a PERMUTE_PATH_* constant
 
 
 def _moe_ffn(x, layer, config: MoEConfig, mesh=None):
@@ -487,13 +648,14 @@ def _ffn(x, layer, config: MoEConfig, mesh=None):
         err = jax.lax.stop_gradient(load - 1.0 / c.router_width)
         report.update(load=load, pull=jnp.sum(
             (bias - jax.lax.stop_gradient(bias)) * err))
-    if c.router_width != c.n_experts:
+    share = c.router_width != c.n_experts
+    if share:
         # this chip's share: the pairs of other chips' experts are kept
-        # by none of its groups
+        # by none of its groups, and only the kept pairs' rows move
         held = expert - c.first_expert
         keep = keep & (held >= 0) & (held < c.n_experts)
         expert = held
-    experts, terms = _routed_rows, 1
+    experts, terms = functools.partial(_routed_rows, live_only=share), 1
     if mesh is not None:
         # a Pallas call has no partitioning rule, so under a mesh the
         # block is manual over all of it: tokens stay where the batch and
@@ -515,7 +677,7 @@ def _ffn(x, layer, config: MoEConfig, mesh=None):
                 "columns")
         up = P(None, None, over)
         experts = jax.shard_map(
-            _routed_rows, mesh=mesh,
+            experts, mesh=mesh,
             in_specs=(P(over, *tokens), P(*tokens), P(*tokens), P(*tokens),
                       up, up, P(None, over, None)),
             out_specs=P(over, *tokens), check_vma=False,
@@ -626,8 +788,6 @@ def stats_gauges(read, config: MoEConfig, registry=None) -> None:
     the hottest held expert's pairs over the mean expert's; each layer's
     averaged over the layers. Nothing on the step path reads them."""
     import numpy as np
-
-    from dlrover_tpu.observability.registry import get_registry
 
     reg = registry or get_registry()
     first, n = config.first_expert, config.n_experts

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax.ad_checkpoint import checkpoint_name
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -157,6 +158,43 @@ def test_the_shares_of_every_chip_add_up_to_the_uncut_layer():
     assert all(float(jnp.abs(p).max()) > 0 for p in parts)
 
 
+def test_the_live_rows_path_is_the_whole_buffer_path(monkeypatch):
+    """The held share's layer moves only the live rows (the registry
+    counts its traced calls as ``live``); forced onto the whole-buffer
+    path it gives the same loss and every gradient leaf, to f32's
+    summation order."""
+    from dlrover_tpu.observability.registry import get_registry
+
+    fields = _fields()
+    config = family.program_config(fields, SEQ)
+    params = _f32_params(config)
+    tokens = _tokens(fields)
+    calls = get_registry().counter(
+        "dlrover_moe_permute_calls_total", labelnames=("path",))
+    before = {p: calls.labels(path=p).value for p in ("live", "whole")}
+
+    def value_and_grad():
+        with jax.default_matmul_precision("highest"):
+            return jax.value_and_grad(
+                lambda p: moe.loss_and_stats(p, tokens, config)[0])(params)
+
+    got, grads = value_and_grad()
+    traced = {p: calls.labels(path=p).value - before[p]
+              for p in ("live", "whole")}
+    assert traced["live"] >= 1 and traced["whole"] == 0
+    real = moe._routed_rows
+    monkeypatch.setattr(moe, "_routed_rows",
+                        lambda *a, live_only: real(*a, live_only=False))
+    monkeypatch.setattr(moe, "loss_and_stats",
+                        moe.loss_and_stats.__wrapped__)
+    want, want_grads = value_and_grad()
+    assert calls.labels(path="whole").value > before["whole"]
+    _close(got, want, rtol=1e-6)
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    for (path, g), r in zip(flat, jax.tree.leaves(want_grads)):
+        _close(g, r, jax.tree_util.keystr(path), rtol=1e-5, atol=1e-6)
+
+
 def test_the_selection_bias_chooses_but_does_not_gate():
     _, config = _expert_layer_config(held=4, first=0)
     E, k = config.router_width, config.top_k
@@ -258,10 +296,12 @@ def _old_mixtral_init(c, key):
     }
 
 
-def test_a_mixtral_draws_and_routes_as_before():
+def test_a_mixtral_draws_and_routes_as_before(monkeypatch):
     """The softmax router holding every expert: the same tree drawn bit
-    for bit from a key, no stats, and the layer's report the balance term
-    alone (its numbers are held to the one-hot dispatch in test_moe.py)."""
+    for bit from a key, no stats, the layer's report the balance term
+    alone (its numbers are held to the one-hot dispatch in test_moe.py),
+    and the layer's jaxpr, forward and backward, the whole-buffer path's
+    as it was."""
     c = dataclasses.replace(moe.MoEConfig.tiny(), n_layers=2)
     key = jax.random.PRNGKey(17)
     got, want = moe.init_params(c, key), _old_mixtral_init(c, key)
@@ -277,6 +317,67 @@ def test_a_mixtral_draws_and_routes_as_before():
     layer = jax.tree.map(lambda a: a[0], got["layers"])
     _, report = moe._ffn(x, layer, c)
     assert set(report) == {"aux"}
+
+    # the layer and its gradient trace to the jaxpr they traced to before
+    # the held share's layer moved its live rows alone
+    def traced():
+        return str(jax.make_jaxpr(jax.value_and_grad(
+            lambda x, layer: moe._ffn(x, layer, c)[0].sum(),
+            argnums=(0, 1)))(x, layer))
+
+    now = traced()
+    monkeypatch.setattr(moe, "_routed_rows", _old_routed_rows)
+    assert traced() == now
+
+
+@jax.custom_vjp
+def _permute(x, perm, inverse):
+    return x[perm]
+
+
+def _permute_fwd(x, perm, inverse):
+    return x[perm], inverse
+
+
+def _permute_bwd(inverse, g):
+    return g[inverse], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def _old_sort_by_expert(expert, keep, n_experts):
+    key = jnp.where(keep, expert, n_experts)
+    order = jnp.argsort(key, stable=True)
+    group_sizes = (key[:, None] == jnp.arange(n_experts)).sum(
+        0, dtype=jnp.int32)
+    return order, group_sizes
+
+
+def _old_routed_rows(x, expert, keep, gates, w1, w3, w2, live_only=False):
+    """``moe._routed_rows`` with its sort, permutation and SwiGLU as they
+    were before a chip's share of the experts moved its live rows alone:
+    every pair's row into the sorted buffer and out of it."""
+    assert not live_only
+    k, D = expert.shape[-1], x.shape[-1]
+    by_choice = lambda a: a.reshape(-1, k).T
+    order, group_sizes = _old_sort_by_expert(
+        by_choice(expert).reshape(-1), by_choice(keep).reshape(-1),
+        w1.shape[0])
+    inverse = jnp.argsort(order)
+    rows = _permute(jnp.tile(x.reshape(-1, D), (k, 1)), order, inverse)
+    live = (jnp.arange(rows.shape[0]) < group_sizes.sum())[:, None]
+    rows = jnp.where(live, rows, 0)
+    gate = checkpoint_name(moe._grouped_matmul(rows, w1, group_sizes),
+                           "moe_gate")
+    up = checkpoint_name(moe._grouped_matmul(rows, w3, group_sizes),
+                         "moe_up")
+    down = checkpoint_name(moe._grouped_matmul(
+        jax.nn.silu(gate) * up, w2, group_sizes), "moe_down")
+    out = jnp.where(live, down, 0)
+    out = _permute(out, inverse, order).reshape(k, -1, D)
+    y = (out.astype(jnp.float32) * by_choice(gates)[..., None]).sum(0)
+    return y.astype(x.dtype).reshape(x.shape)
 
 
 # -- the readers of the new per-layer metrics ---------------------------------

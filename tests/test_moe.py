@@ -167,6 +167,174 @@ class TestRouting:
         )
 
 
+T_LIVE, K_LIVE, E_LIVE, CHUNK = 32, 3, 4, 8     # T·k = 96 pairs, 12 chunks
+
+
+def _pairs(n_live, c, seed=0):
+    """x (1, 1, T, D) and the pairs of T tokens, k each, of which exactly
+    ``n_live`` are kept by one of E experts, and their f32 gates."""
+    rng = np.random.default_rng(seed)
+    M = T_LIVE * K_LIVE
+    kept = np.zeros(M, bool)
+    kept[rng.permutation(M)[:n_live]] = True
+    expert = rng.integers(0, E_LIVE, (1, T_LIVE, K_LIVE))
+    x = rng.standard_normal((1, 1, T_LIVE, c.dim))
+    gates = rng.uniform(0.1, 1.0, (1, T_LIVE, K_LIVE))
+    return (jnp.asarray(x, jnp.float32), jnp.asarray(expert, jnp.int32),
+            jnp.asarray(kept.reshape(1, T_LIVE, K_LIVE)),
+            jnp.asarray(gates, jnp.float32))
+
+
+def _experts_value_and_grad(c, expert, keep, live_only, probe):
+    """The routed rows' output and its gradients with respect to x, the
+    gates and the three expert leaves, through one path."""
+    def f(x, gates, w1, w3, w2):
+        y = moe._routed_rows(x, expert, keep, gates, w1, w3, w2,
+                             live_only=live_only)
+        return (y * probe).sum(), y
+    return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2, 3, 4),
+                                      has_aux=True))
+
+
+class TestLiveRows:
+    """A chip that holds a share of the router's experts moves only the
+    live rows into the sorted buffer and out of it; the result is the
+    whole-buffer path's, in the output and every gradient."""
+
+    @pytest.mark.parametrize("n_live", [0, 1, CHUNK - 1, CHUNK, CHUNK + 1,
+                                        T_LIVE * K_LIVE])
+    def test_the_live_path_equals_the_whole_buffer(self, n_live,
+                                                   monkeypatch):
+        monkeypatch.setattr(moe, "_CHUNK", CHUNK)
+        c = _tiny(n_experts=E_LIVE, top_k=K_LIVE)
+        x, expert, keep, gates = _pairs(n_live, c)
+        layer = _layer(c, jax.random.PRNGKey(7))
+        leaves = (layer["w1"], layer["w3"], layer["w2"])
+        probe = jax.random.normal(jax.random.PRNGKey(8), x.shape)
+        (_, whole), d_whole = _experts_value_and_grad(
+            c, expert, keep, False, probe)(x, gates, *leaves)
+        (_, live), d_live = _experts_value_and_grad(
+            c, expert, keep, True, probe)(x, gates, *leaves)
+        tol = dict(atol=1e-5, rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(live), np.asarray(whole),
+                                   **tol)
+        for name, a, b in zip(("x", "gates", "w1", "w3", "w2"), d_live,
+                              d_whole):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       err_msg=name, **tol)
+        # a dead pair's gate has no gradient
+        d_gates = np.asarray(d_live[1])
+        assert (d_gates[~np.asarray(keep)] == 0).all()
+        if n_live == 0:
+            assert float(jnp.abs(live).max()) == 0.0
+
+    @pytest.mark.parametrize("live_only", [False, True],
+                             ids=["whole", "live"])
+    def test_a_dead_row_reads_zero_into_and_out_of_the_experts(
+            self, live_only, monkeypatch):
+        """Tokens whose every pair is dead hold NaN, and the grouped
+        matmuls leave NaN in every dead row they return, forward and
+        backward, as the chip's kernels leave those rows unwritten: the
+        dead rows go in as zeros (else every output is NaN), those tokens
+        get zero, and no gradient holds a NaN."""
+        monkeypatch.setattr(moe, "_CHUNK", CHUNK)
+        c = _tiny(n_experts=E_LIVE, top_k=K_LIVE)
+        x, expert, keep, gates = _pairs(CHUNK * 5 + 3, c, seed=1)
+        dead = ~np.asarray(keep).any(-1)[0]                 # (T,)
+        assert 0 < dead.sum() < T_LIVE
+        x = x.at[0, 0, dead].set(jnp.nan)
+        real = moe._grouped_ffn
+
+        def live_rows(a, n):
+            return (jnp.arange(a.shape[0]) < n)[:, None]
+
+        def fwd(rows, group_sizes, w1, w3, w2):
+            # a dead row that goes in other than zero spoils every output
+            n = group_sizes.sum()
+            clean = jnp.where(live_rows(rows, n), True, rows == 0).all()
+            out = real(rows, group_sizes, w1, w3, w2)
+            out = jnp.where(clean & live_rows(out, n), out, jnp.nan)
+            return out, (rows, group_sizes, w1, w3, w2)
+
+        def bwd(residuals, g):
+            rows, group_sizes, *w = residuals
+            _, vjp = jax.vjp(
+                lambda r, *w: real(r, group_sizes, *w), rows, *w)
+            d_rows, *d_w = vjp(g)
+            n = group_sizes.sum()
+            return (jnp.where(live_rows(d_rows, n), d_rows, jnp.nan), None,
+                    *d_w)
+
+        unwritten = jax.custom_vjp(lambda *a: fwd(*a)[0])
+        unwritten.defvjp(fwd, bwd)
+        monkeypatch.setattr(moe, "_grouped_ffn", unwritten)
+        layer = _layer(c, jax.random.PRNGKey(7))
+        probe = jax.random.normal(jax.random.PRNGKey(8), x.shape)
+        (_, y), grads = _experts_value_and_grad(
+            c, expert, keep, live_only, probe)(
+                x, gates, layer["w1"], layer["w3"], layer["w2"])
+        assert bool(jnp.isfinite(y).all())
+        assert float(jnp.abs(y[0, 0, dead]).max()) == 0.0
+        assert float(jnp.abs(y[0, 0, ~dead]).min(-1).max()) > 0.0
+        d_x, d_gates, *d_w = grads
+        assert bool(jnp.isfinite(d_x).all())
+        assert float(jnp.abs(d_x[0, 0, dead]).max()) == 0.0
+        assert bool(jnp.isfinite(d_gates).all())
+        for name, g in zip(("w1", "w3", "w2"), d_w):
+            assert bool(jnp.isfinite(g).all()), name
+
+    @pytest.mark.parametrize("axes", [{"ep": 4}, {"ep": 2, "tp": 2}],
+                             ids=["ep4xfsdp2", "ep2xtp2xfsdp2"])
+    def test_the_live_path_under_a_mesh_equals_the_meshless(self, axes):
+        """A share of 4 experts of a 16-wide router, each chip of the
+        expert group at its columns: output and every gradient as on one
+        device."""
+        c = _tiny(router_experts=16, first_expert=4, capacity_factor=8.0)
+        layer = _layer(dataclasses.replace(c, n_experts=16),
+                       jax.random.PRNGKey(5))
+        layer.update({k: layer[k][4:8] for k in ("w1", "w3", "w2")})
+        x = jax.random.normal(jax.random.PRNGKey(4), (4, 32, c.dim))
+
+        def value_and_grad(x, layer, mesh):
+            return jax.jit(jax.value_and_grad(
+                lambda x, layer: moe._moe_ffn(x, layer, c, mesh)[0].sum(),
+                argnums=(0, 1)))(x, layer)
+
+        want = value_and_grad(x, layer, None)
+        mesh = build_mesh(plan_mesh(8, **axes))
+        logical = moe.param_logical_axes(c)["layers"]
+        layer = shard_tree(mesh, layer, {k: logical[k][1:] for k in LEAVES})
+        x = jax.device_put(
+            x, NamedSharding(mesh, P(("dp", "fsdp"), None, None)))
+        got = value_and_grad(x, layer, mesh)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-5, rtol=1e-5)
+
+    def test_the_counter_names_the_path_each_layer_takes(self):
+        from dlrover_tpu.observability.registry import get_registry
+
+        def read():
+            calls = get_registry().counter(
+                "dlrover_moe_permute_calls_total", labelnames=("path",))
+            return {p: calls.labels(path=p).value for p in ("live", "whole")}
+
+        x = jax.random.normal(jax.random.PRNGKey(0), (2, 32, 64))
+        for config, path in (
+                (_tiny(), "whole"),
+                (_tiny(router_experts=16, first_expert=4), "live")):
+            layer = _layer(dataclasses.replace(
+                config, n_experts=config.router_width), jax.random.PRNGKey(1))
+            layer.update({k: layer[k][:config.n_experts]
+                          for k in ("w1", "w3", "w2")})
+            before = read()
+            jax.jit(lambda x, layer: moe._ffn(x, layer, config)[0]).lower(
+                x, layer)
+            after = read()
+            assert {p: after[p] - before[p] for p in after} == {
+                p: float(p == path) for p in after}
+
+
 LEAVES = ("router", "w1", "w3", "w2")
 
 
